@@ -989,9 +989,9 @@ def build_parser() -> argparse.ArgumentParser:
                    help="JAX persistent compilation cache directory: "
                         "restarts and rolling deploys skip warm XLA "
                         "compiles for unchanged banks "
-                        "(compiler/cache.py). Falls back to the "
-                        "MIXS_JAX_COMPILE_CACHE_DIR env var; unset = "
-                        "jax's own defaulting")
+                        "(compiler/cache.py). The "
+                        "JAX_COMPILATION_CACHE_DIR env var wins when "
+                        "set; neither = <checkout>/.jax_cache")
     s.add_argument("--no-delta-compile", action="store_true",
                    help="kill switch for delta compilation: every "
                         "config publish rebuilds every shard bank "

@@ -30,10 +30,12 @@ layers, cheapest first:
      ARGUMENTS, never closure constants (compiler/ruleset.py), so a
      constant-only rule edit keeps the HLO — and therefore the cache
      key — bit-identical: only SHAPE changes (new atoms, wider
-     conjunctions, different bank sizes) recompile. Wired through
-     ServerArgs.jax_compile_cache_dir / `mixs --jax-compile-cache-dir`
-     (env fallback MIXS_JAX_COMPILE_CACHE_DIR; JAX's own
-     JAX_COMPILATION_CACHE_DIR works too, jax reads it natively).
+     conjunctions, different bank sizes) recompile. ONE function,
+     resolve_cache_dir, decides where it lives: the
+     JAX_COMPILATION_CACHE_DIR environment variable when set, else
+     ServerArgs.jax_compile_cache_dir / `mixs --jax-compile-cache-dir`,
+     else the fixed `<checkout>/.jax_cache` (the path is part of the
+     cache key, so a directory that moves never hits).
 
 Hit/miss accounting rides jax's monitoring events
 ('/jax/compilation_cache/cache_hits' / 'cache_misses') — the delta
@@ -42,6 +44,7 @@ banks, and /debug/shards surfaces the counters.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import hashlib
 import json
@@ -74,61 +77,73 @@ def manifest_digest(finder) -> str:
 
 # -- persistent XLA compilation cache ---------------------------------
 
-ENV_CACHE_DIR = "MIXS_JAX_COMPILE_CACHE_DIR"
+ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
-def resolve_cache_dir(explicit: str | None = None) -> str | None:
-    """Pick the persistent-cache directory: explicit config first
-    (ServerArgs / --jax-compile-cache-dir), then the
-    MIXS_JAX_COMPILE_CACHE_DIR env var. None = leave jax's own
-    defaulting alone (JAX_COMPILATION_CACHE_DIR is read by jax itself
-    at import, so pointing that at a directory also works without us).
-    """
-    if explicit:
-        return explicit
+def resolve_cache_dir(explicit: str | None = None) -> str:
+    """THE persistent-cache directory decision: the
+    JAX_COMPILATION_CACHE_DIR environment variable when set (whoever
+    runs the process placed the cache; nothing in code overrides it),
+    else explicit config (ServerArgs / --jax-compile-cache-dir), else
+    the fixed `<checkout>/.jax_cache`."""
     env = os.environ.get(ENV_CACHE_DIR, "").strip()
-    return env or None
+    return env or explicit or CHECKOUT_CACHE_DIR
 
 
-def configure_persistent_cache(cache_dir: str,
+def configure_persistent_cache(explicit: str | None = None,
                                min_compile_time_s: float = 0.0) -> str:
-    """Point jax's persistent compilation cache at `cache_dir`
-    (created if missing) and lower the entry thresholds so every
-    serving program is cached — bank programs at small shard sizes
-    compile in well under jax's 1s default threshold, and they are
-    exactly the artifacts a rolling deploy wants to skip. Returns the
-    directory. Safe to call repeatedly (config updates are
-    idempotent)."""
+    """Point jax's persistent compilation cache at
+    resolve_cache_dir(explicit) (created if missing) and lower the
+    entry thresholds so every serving program is cached — bank
+    programs at small shard sizes compile in well under jax's 1s
+    default threshold, and they are exactly the artifacts a rolling
+    deploy wants to skip. Returns the directory. Repeat calls with an
+    unchanged config are no-ops."""
     import jax
 
+    cache_dir = resolve_cache_dir(explicit)
+    min_s = float(min_compile_time_s)
     os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                      float(min_compile_time_s))
-    try:
-        # cache entries below 0 bytes never exist; -1 = "cache all"
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                          -1)
-    except Exception:
-        pass   # older jax: size threshold not configurable
+    cfg = jax.config
+    if (cfg.jax_compilation_cache_dir == cache_dir
+            and cfg.jax_persistent_cache_min_compile_time_secs == min_s
+            and cfg.jax_persistent_cache_min_entry_size_bytes == -1):
+        return cache_dir
+    cfg.update("jax_compilation_cache_dir", cache_dir)
+    cfg.update("jax_persistent_cache_min_compile_time_secs", min_s)
+    # cache entries below 0 bytes never exist; -1 = "cache all"
+    cfg.update("jax_persistent_cache_min_entry_size_bytes", -1)
     # jax memoizes its "is the cache used?" decision at the FIRST
     # compile of the process — a server configured after any earlier
     # compile (a long-lived test process, a REPL) would silently keep
     # the cache off forever without this reset
-    reset_backend_cache_state()
+    from jax.experimental.compilation_cache import compilation_cache
+
+    compilation_cache.reset_cache()
     return cache_dir
 
 
-def reset_backend_cache_state() -> None:
-    """Drop jax's memoized cache-enabled/initialized state so the
-    NEXT compile re-reads the current config. Also the correct thing
-    to call after RESTORING a previous cache config (the smoke gate's
-    finally) — without it the restored setting is never re-checked."""
+@contextlib.contextmanager
+def private_cache_dir(cache_dir: str, min_compile_time_s: float = 0.0):
+    """Scope for the cache-BEHAVIOUR gates only (delta_smoke,
+    test_delta_compile), which count cold misses and so need a
+    directory nothing else has written: clears
+    JAX_COMPILATION_CACHE_DIR for the scope, points jax at
+    `cache_dir`, and on exit restores the variable and the process's
+    resolved cache config."""
+    import jax
+
+    prev_env = os.environ.pop(ENV_CACHE_DIR, None)
+    prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
     try:
-        from jax._src import compilation_cache
-        compilation_cache.reset_cache()
-    except Exception:
-        pass   # fail-soft: at worst the process keeps prior behavior
+        yield configure_persistent_cache(cache_dir, min_compile_time_s)
+    finally:
+        if prev_env is not None:
+            os.environ[ENV_CACHE_DIR] = prev_env
+        configure_persistent_cache(min_compile_time_s=prev_min)
 
 
 def persistent_cache_entries(cache_dir: str) -> int:
@@ -157,18 +172,14 @@ def _on_event(event: str, **kw) -> None:
 
 def install_event_counters() -> None:
     """Register the jax monitoring listener that feeds
-    cache_event_counts(). Idempotent; a jax too old to expose
-    monitoring leaves the counters at zero (fail-soft — accounting
-    must never break serving)."""
+    cache_event_counts(). Idempotent."""
     global _EVENTS_INSTALLED
     if _EVENTS_INSTALLED:
         return
-    try:
-        from jax._src import monitoring
-        monitoring.register_event_listener(_on_event)
-        _EVENTS_INSTALLED = True
-    except Exception:
-        pass
+    import jax.monitoring
+
+    jax.monitoring.register_event_listener(_on_event)
+    _EVENTS_INSTALLED = True
 
 
 def cache_event_counts() -> dict:

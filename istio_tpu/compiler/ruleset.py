@@ -87,6 +87,19 @@ Dnf = set
 
 DEFAULT_DNF_CAP = 128
 
+# Rows the conjunction index tensors (eqc_*, lit_idx) round up to. The
+# TPU compiler unrolls the [B, F, L] gather-compare when F is not a
+# multiple of 16: compiled for a described v5e at B=2048, F=24001 took
+# 120 s and 58 MB of code, F=24064 6 s and 2.5 MB (tests/
+# test_tpu_compile.py keeps the guard). One lane-width keeps every
+# snapshot on the fast side and lets rule edits that stay inside a
+# 128-row step reuse the compiled program.
+CONJ_ALIGN = 128
+
+
+def _round_up(n: int, multiple: int) -> int:
+    return -(-n // multiple) * multiple
+
 
 class DnfBlowup(HostFallback):
     """Predicate's DNF exceeded dnf_cap conjunctions."""
@@ -679,7 +692,7 @@ def compile_ruleset(rules: Sequence[Rule], finder: AttributeDescriptorFinder,
     n_conjs = len(conj_list)
     n_rules = len(rules)
     # rule-axis padding for even mp sharding (see docstring)
-    n_rows = max(-(-max(n_rules, 1) // rule_pad) * rule_pad, 1)
+    n_rows = max(_round_up(max(n_rules, 1), rule_pad), 1)
 
     # ---- fused gather–compare fast path ----
     # Conjunctions whose EVERY literal is a tier-1 EQ/NEQ(slot, const)
@@ -723,10 +736,16 @@ def compile_ruleset(rules: Sequence[Rule], finder: AttributeDescriptorFinder,
                  ((rule_m_cols[r], rule_n_cols[r]) for r in range(n_rules))),
                 default=1) or 1
 
-    eqc_col = np.zeros((max(n_fused, 1), l_max_f), np.int32)
-    eqc_cid = np.zeros((max(n_fused, 1), l_max_f), np.int32)
-    eqc_xor = np.zeros((max(n_fused, 1), l_max_f), bool)
-    eqc_pad = np.ones((max(n_fused, 1), l_max_f), bool)
+    # Conjunction-axis alignment (CONJ_ALIGN): the index tensors round
+    # their conjunction rows up; pad rows read True (all-pad lanes /
+    # the LIT_TRUE sentinel), no rule indexes them, and run() slices
+    # the sat block back to its real width, so column numbering and
+    # verdicts are untouched.
+    f_rows = _round_up(max(n_fused, 1), CONJ_ALIGN)
+    eqc_col = np.zeros((f_rows, l_max_f), np.int32)
+    eqc_cid = np.zeros((f_rows, l_max_f), np.int32)
+    eqc_xor = np.zeros((f_rows, l_max_f), bool)
+    eqc_pad = np.ones((f_rows, l_max_f), bool)
     for j in range(n_fused):
         for s, (aidx, kind) in enumerate(sorted(conj_list[j])):
             col, cid, neg = eq_info[aidx]
@@ -769,7 +788,9 @@ def compile_ruleset(rules: Sequence[Rule], finder: AttributeDescriptorFinder,
     # legacy literal gather rows: only the conjunctions the fused
     # gather-compare path above did NOT absorb (an all-EQ snapshot
     # compiles no literal gather at all)
-    lit_idx = np.full((max(n_legacy, 1), l_max), LIT_TRUE, np.int32)
+    n_legacy_cols = max(n_legacy, 1)
+    lit_idx = np.full((_round_up(n_legacy_cols, CONJ_ALIGN), l_max),
+                      LIT_TRUE, np.int32)
     for jj, conj in enumerate(conj_list[n_fused:]):
         for s, (aidx, kind) in enumerate(sorted(conj)):
             lit_idx[jj, s] = pos_of[aidx] + (0 if kind == "m" else n_live)
@@ -808,7 +829,7 @@ def compile_ruleset(rules: Sequence[Rule], finder: AttributeDescriptorFinder,
             hit = ((iv == params["eqc_cid"][None]) ^
                    params["eqc_xor"][None]) & pv
             sat_parts.append(jnp.all(hit | params["eqc_pad"][None],
-                                     axis=2))
+                                     axis=2)[:, :n_fused])
         if use_legacy:
             parts_m, parts_n = [], []
             if eq_cols_a.size:
@@ -842,7 +863,8 @@ def compile_ruleset(rules: Sequence[Rule], finder: AttributeDescriptorFinder,
             lit = jnp.concatenate(
                 [m_all, n_all, jnp.ones((b, 1), bool)], axis=1)
             sat_parts.append(
-                jnp.all(lit[:, params["lit_idx"]], axis=2))
+                jnp.all(lit[:, params["lit_idx"]],
+                        axis=2)[:, :n_legacy_cols])
         sat = sat_parts[0] if len(sat_parts) == 1 \
             else jnp.concatenate(sat_parts, axis=1)   # [B, n_conjs]
         # sat[:, CONJ_FALSE] is the OR-identity sentinel;

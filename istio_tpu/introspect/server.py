@@ -334,10 +334,11 @@ class IntrospectServer:
         from istio_tpu.compiler import roofline
         from istio_tpu.runtime import monitor
 
-        platform = jax.devices()[0].platform
+        device = jax.devices()[0]
         payload: dict[str, Any] = {
-            "platform": platform,
-            "peaks": roofline.peaks_for(platform),
+            "platform": device.platform,
+            "device_kind": device.device_kind,
+            "peaks": roofline.peaks_for(device.device_kind),
         }
         d = self.runtime.controller.dispatcher \
             if self.runtime is not None else None

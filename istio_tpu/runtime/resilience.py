@@ -501,8 +501,8 @@ class ResilientChecker:
                 first = exc
                 if self.config.retry:
                     # one jittered retry absorbs transient device
-                    # faults (a dropped tunnel frame, a preempted
-                    # step) without involving the breaker
+                    # faults (a failed transfer, a preempted step)
+                    # without involving the breaker
                     time.sleep(self.config.retry_backoff_s +  # hotpath: sync-ok failure-path backoff only
                                random.random() *
                                self.config.retry_jitter_s)

@@ -96,8 +96,10 @@ class ServerArgs:
     # deploys skip the XLA compile for every program whose HLO is
     # unchanged (our compiled programs take index tensors as traced
     # ARGUMENTS, so constant-only config edits keep the HLO
-    # bit-identical). None → the MIXS_JAX_COMPILE_CACHE_DIR env var →
-    # jax's own defaulting (mixs exposes --jax-compile-cache-dir).
+    # bit-identical). Honoured only while JAX_COMPILATION_CACHE_DIR is
+    # unset (the environment places the cache when it speaks); None →
+    # <checkout>/.jax_cache (compiler/cache.resolve_cache_dir; mixs
+    # exposes --jax-compile-cache-dir).
     jax_compile_cache_dir: str | None = None
     max_str_len: int | None = None
     preprocess: bool = True
@@ -283,12 +285,10 @@ class RuntimeServer:
         # it BEFORE the first compile so the controller's initial
         # publish already reads/writes cached artifacts
         from istio_tpu.compiler import cache as compile_cache
-        cache_dir = compile_cache.resolve_cache_dir(
-            self.args.jax_compile_cache_dir)
-        if cache_dir:
-            compile_cache.configure_persistent_cache(cache_dir)
-            compile_cache.install_event_counters()
-        self._compile_cache_dir = cache_dir
+        self._compile_cache_dir = \
+            compile_cache.configure_persistent_cache(
+                self.args.jax_compile_cache_dir)
+        compile_cache.install_event_counters()
         # tail-latency forensics (runtime/forensics.py): arm the
         # process-wide flight recorder + event ring BEFORE the
         # controller's initial publish so the first generation's
@@ -409,11 +409,8 @@ class RuntimeServer:
         # zero-copy and the "transfer" overlaps nothing)
         overlap = self.args.overlap_h2d
         if overlap is None:
-            try:
-                import jax
-                overlap = jax.default_backend() not in ("cpu",)
-            except Exception:
-                overlap = False
+            import jax
+            overlap = jax.default_backend() != "cpu"
         self._overlap_h2d = bool(overlap)
         self.controller = Controller(
             store, default_manifest=manifest,

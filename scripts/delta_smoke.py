@@ -88,6 +88,24 @@ def main(n_rules: int | None = None, n_namespaces: int | None = None,
          shards: int | None = None, n_checks: int = 48,
          seed: int = 7) -> int:
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from istio_tpu.compiler import cache as compile_cache
+
+    # a PRIVATE cache directory: the warm-restart leg counts cold
+    # misses, so nothing else may have written it. The scope clears
+    # JAX_COMPILATION_CACHE_DIR and restores the process's own cache
+    # config on exit — BEFORE the tmpdir is deleted (later compiles in
+    # this process must not write into a missing directory)
+    cache_dir = tempfile.mkdtemp(prefix="delta_smoke_jax_cache_")
+    try:
+        with compile_cache.private_cache_dir(cache_dir):
+            return _run(cache_dir, n_rules, n_namespaces, shards,
+                        n_checks, seed)
+    finally:
+        shutil.rmtree(cache_dir, ignore_errors=True)
+
+
+def _run(cache_dir: str, n_rules: int | None, n_namespaces: int | None,
+         shards: int | None, n_checks: int, seed: int) -> int:
     import time
 
     import jax
@@ -110,9 +128,6 @@ def main(n_rules: int | None = None, n_namespaces: int | None = None,
     shards = shards or (8 if on_tpu else 4)
 
     failures: list[str] = []
-    cache_dir = tempfile.mkdtemp(prefix="delta_smoke_jax_cache_")
-    prev_cache_dir = jax.config.jax_compilation_cache_dir
-    prev_min_s = jax.config.jax_persistent_cache_min_compile_time_secs
     compile_cache.install_event_counters()
     srv = srv2 = intro = g = client = None
     try:
@@ -313,19 +328,6 @@ def main(n_rules: int | None = None, n_namespaces: int | None = None,
             except Exception:
                 pass
         tracing.shutdown()
-        # leave jax's persistent-cache config the way we found it
-        # BEFORE deleting the tmpdir (later compiles in this process
-        # must not write into a missing directory)
-        try:
-            jax.config.update("jax_compilation_cache_dir",
-                              prev_cache_dir)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs",
-                prev_min_s)
-            compile_cache.reset_backend_cache_state()
-        except Exception:
-            pass
-        shutil.rmtree(cache_dir, ignore_errors=True)
 
     for f in failures:
         print(f"FAIL: {f}", file=sys.stderr)

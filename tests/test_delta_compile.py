@@ -240,27 +240,39 @@ def test_cache_dir_resolution_and_jax_roundtrip(tmp_path, monkeypatch):
 
     from istio_tpu.compiler import cache as cc
 
-    assert cc.resolve_cache_dir("/explicit/dir") == "/explicit/dir"
+    # precedence: the environment variable wins; else explicit config;
+    # else the fixed checkout path
     monkeypatch.setenv(cc.ENV_CACHE_DIR, str(tmp_path / "envdir"))
     assert cc.resolve_cache_dir(None) == str(tmp_path / "envdir")
-    assert cc.resolve_cache_dir("/explicit/dir") == "/explicit/dir"
+    assert cc.resolve_cache_dir("/explicit/dir") == \
+        str(tmp_path / "envdir")
     monkeypatch.delenv(cc.ENV_CACHE_DIR)
-    assert cc.resolve_cache_dir(None) is None
+    assert cc.resolve_cache_dir("/explicit/dir") == "/explicit/dir"
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert cc.resolve_cache_dir(None) == os.path.join(repo, ".jax_cache")
 
-    prev_dir = jax.config.jax_compilation_cache_dir
-    prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    # with the variable set, no explicit directory reaches jax.config;
+    # the private scope (this test's kind only) clears and restores it
+    monkeypatch.setenv(cc.ENV_CACHE_DIR, str(tmp_path / "envdir"))
     try:
-        d = cc.configure_persistent_cache(str(tmp_path / "cache"),
-                                          min_compile_time_s=0.25)
-        assert os.path.isdir(d)
-        assert jax.config.jax_compilation_cache_dir == d
-        assert jax.config \
-            .jax_persistent_cache_min_compile_time_secs == 0.25
-        assert cc.persistent_cache_entries(d) == 0
+        assert cc.configure_persistent_cache("/explicit/dir") == \
+            str(tmp_path / "envdir")
+        assert jax.config.jax_compilation_cache_dir == \
+            str(tmp_path / "envdir")
+        with cc.private_cache_dir(str(tmp_path / "cache"),
+                                  min_compile_time_s=0.25) as d:
+            assert cc.ENV_CACHE_DIR not in os.environ
+            assert os.path.isdir(d)
+            assert jax.config.jax_compilation_cache_dir == d
+            assert jax.config \
+                .jax_persistent_cache_min_compile_time_secs == 0.25
+            assert cc.persistent_cache_entries(d) == 0
+        assert os.environ[cc.ENV_CACHE_DIR] == str(tmp_path / "envdir")
+        assert jax.config.jax_compilation_cache_dir == \
+            str(tmp_path / "envdir")
     finally:
-        jax.config.update("jax_compilation_cache_dir", prev_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          prev_min)
+        monkeypatch.undo()   # the run's own environment, then its cache
+        cc.configure_persistent_cache()
 
 
 def test_mixs_flags_reach_server_args():

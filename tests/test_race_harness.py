@@ -376,7 +376,7 @@ def test_batcher_holds_batches_while_transport_busy():
         with lock:   # count REAL rows (the batcher pads to buckets)
             sizes.append(sum(1 for x in bags
                              if not isinstance(x, PadBag)))
-        _time.sleep(0.12)          # a slow (tunnel-like) trip
+        _time.sleep(0.12)          # a slow device trip
         return ["ok"] * len(bags)
 
     b = CheckBatcher(run_batch, window_s=0.002, max_batch=64,

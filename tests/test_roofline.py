@@ -67,6 +67,21 @@ def test_report_names_binding_resource():
     assert rep["bound"] == "host"
 
 
+@pytest.mark.parametrize("kind,row", [
+    ("TPU v5 lite", roofline.V5E_PEAKS),
+    ("cpu", roofline.CPU_PEAKS),
+    (None, roofline.CPU_PEAKS),      # default: this run's own device
+])
+def test_peaks_are_keyed_by_device_kind(kind, row):
+    assert roofline.peaks_for(kind) is row
+
+
+def test_unknown_device_kind_has_no_peaks():
+    # no default row: a TPU that is not a v5e must not borrow its roof
+    with pytest.raises(ValueError, match="TPU v4"):
+        roofline.peaks_for("TPU v4")
+
+
 def test_bench_fields_prefixed_and_fail_soft():
     engine = workloads.make_engine(n_rules=24, with_quota=False,
                                    jit=False)

@@ -241,3 +241,97 @@ def test_staged_batches_do_not_alias_within_depth():
         np.asarray(bb.present).ctypes.data
     np.testing.assert_array_equal(np.asarray(ba.present), snapshot,
                                   err_msg="batch A mutated by batch B")
+
+
+# ---------------------------------------------------------------------------
+# staged h2d on the SERVED path (Dispatcher._stage_h2d). The server turns
+# it on by itself only where the backend is not the CPU, so nothing in
+# tier-1 ran it before chip_smoke.py did on the chip; forced on here.
+# ---------------------------------------------------------------------------
+
+def _staged_server(**kw):
+    from istio_tpu.runtime import RuntimeServer, ServerArgs
+    from istio_tpu.testing import workloads
+
+    return RuntimeServer(workloads.make_store(96), ServerArgs(
+        default_manifest=workloads.MESH_MANIFEST, buckets=(32,),
+        max_batch=32, initial_prewarm=False, overlap_h2d=True, **kw))
+
+
+def _wire_bags(srv, dicts):
+    from istio_tpu.api.wire import LazyWireBag, bag_to_compressed
+
+    return [srv.preprocess(LazyWireBag(
+        bag_to_compressed(d).SerializeToString())) for d in dicts]
+
+
+@pytest.mark.skipif(not HAVE_NATIVE, reason="native toolchain missing")
+def test_served_parity_with_staged_h2d_forced_on(monkeypatch):
+    """Exact oracle parity through Dispatcher._check_fused with the
+    overlapped h2d ON: every string plane reaches the step as a
+    committed device array staged from the zero-copy ring, across more
+    batches than the ring is deep (slot reuse under the async copy)."""
+    import jax
+
+    from istio_tpu.attribute.bag import bag_from_mapping
+    from istio_tpu.runtime.dispatcher import Dispatcher
+    from istio_tpu.testing import workloads
+
+    staged = []
+    real = Dispatcher._stage_h2d
+
+    def spy(plan, batch):
+        out = real(plan, batch)
+        staged.append(out.str_bytes)
+        return out
+
+    monkeypatch.setattr(Dispatcher, "_stage_h2d", staticmethod(spy))
+    srv = _staged_server()
+    try:
+        assert srv._overlap_h2d is True
+        disp = srv.controller.dispatcher
+        depth = disp.fused.native.staging_depth
+        for seed in range(depth + 2):
+            dicts = workloads.make_request_dicts(32, seed=100 + seed)
+            got = [r.status_code for r in
+                   srv.check_batch_preprocessed(_wire_bags(srv, dicts))]
+            want = [r.status_code for r in disp.check_host_oracle(
+                [bag_from_mapping(d) for d in dicts])]
+            assert got == want, seed
+        assert len(staged) == depth + 2
+        assert all(isinstance(s, jax.Array) for s in staged)
+    finally:
+        srv.close()
+
+
+@pytest.mark.skipif(not HAVE_NATIVE, reason="native toolchain missing")
+def test_staging_error_is_counted_not_swallowed(monkeypatch):
+    """A failing staged h2d is a device-path failure: it reaches
+    ResilientChecker (retry, then the oracle answers) and shows in the
+    resilience counters — it no longer degrades in silence."""
+    from istio_tpu.attribute.bag import bag_from_mapping
+    from istio_tpu.runtime import monitor
+    from istio_tpu.runtime.dispatcher import Dispatcher
+    from istio_tpu.testing import workloads
+
+    def boom(plan, batch):
+        raise RuntimeError("injected staging failure")
+
+    srv = _staged_server()
+    try:
+        disp = srv.controller.dispatcher
+        dicts = workloads.make_request_dicts(32, seed=7)
+        want = [r.status_code for r in disp.check_host_oracle(
+            [bag_from_mapping(d) for d in dicts])]
+        monkeypatch.setattr(Dispatcher, "_stage_h2d", staticmethod(boom))
+        base = monitor.resilience_counters()
+        got = [r.status_code for r in
+               srv.check_batch_preprocessed(_wire_bags(srv, dicts))]
+        now = monitor.resilience_counters()
+        assert got == want          # served by the oracle fallback
+        assert now["device_retries_total"] == \
+            base["device_retries_total"] + 1
+        assert now["fallback_total"] == \
+            base["fallback_total"] + len(dicts)   # counted per row
+    finally:
+        srv.close()
