@@ -32,7 +32,7 @@ from istio_tpu.api.grpc_server import MixerGrpcServer
 from istio_tpu.api.wire import LazyWireBag
 from istio_tpu.attribute.global_dict import GLOBAL_WORD_LIST
 from istio_tpu.native.build import ensure_httpd_built
-from istio_tpu.runtime import monitor
+from istio_tpu.runtime import forensics, monitor
 from istio_tpu.runtime.server import RuntimeServer
 
 log = logging.getLogger("istio_tpu.api.native")
@@ -92,11 +92,18 @@ def _load_lib() -> ctypes.CDLL:
     lib.h2srv_counters.argtypes = [ctypes.c_void_p,
                                    ctypes.POINTER(ctypes.c_int64),
                                    ctypes.POINTER(ctypes.c_int64)]
+    lib.h2srv_queue_wait.restype = None
+    lib.h2srv_queue_wait.argtypes = [ctypes.c_void_p,
+                                     ctypes.POINTER(ctypes.c_int64)]
     lib.h2srv_stop.restype = None
     lib.h2srv_stop.argtypes = [ctypes.c_void_p]
     lib.h2srv_quiesce.restype = None
     lib.h2srv_quiesce.argtypes = [ctypes.c_void_p]
     return lib
+
+
+class _PumpStopped(Exception):
+    """h2srv_take answered shutdown (or stop() set the flag)."""
 
 
 class NativeMixerServer(MixerGrpcServer):
@@ -143,6 +150,7 @@ class NativeMixerServer(MixerGrpcServer):
         self._stop_flag = threading.Event()
         self._final_counters: dict | None = None
         self._final_latency: dict | None = None
+        self._final_queue_wait = {"sum_ns": 0, "rows": 0}
         # serializes h2srv_complete against stop(): deferred quota
         # completions fire from pool-worker threads and must never
         # race the server teardown into a freed handle
@@ -210,6 +218,7 @@ class NativeMixerServer(MixerGrpcServer):
             t.join(timeout=grace + 30)
         self._final_counters = self.counters()
         self._final_latency = self.latency_raw()
+        self._final_queue_wait = self.queue_wait()
         if any(t.is_alive() for t in self._pumps):
             # a pump is wedged mid-batch (device stall): freeing the
             # handle under it would turn a stall into a segfault —
@@ -238,6 +247,18 @@ class NativeMixerServer(MixerGrpcServer):
                                   for b in range(16) if hist[b]}
         self._publish_counters(out)
         return out
+
+    def queue_wait(self) -> dict:
+        """How long rows waited in the C++ queue for a free pump:
+        {"sum_ns", "rows"}, cumulative since start — summed at the
+        moment h2srv_take hands rows over (take time − the row's
+        enqueue stamp). Delta two reads for a window's mean."""
+        with self._comp_lock:
+            if self._h is None:
+                return dict(self._final_queue_wait)
+            out = (ctypes.c_int64 * 2)()
+            self._lib.h2srv_queue_wait(self._h, out)
+        return {"sum_ns": int(out[0]), "rows": int(out[1])}
 
     # -- wire latency (the measured wire-to-verdict plane) --
 
@@ -289,9 +310,12 @@ class NativeMixerServer(MixerGrpcServer):
         """Wire-to-verdict latency quantiles — cumulative, or the
         DELTA vs a latency_raw() baseline (per-bench-window reads).
         The measurement is taken entirely in C++ (frame decode →
-        response frame write): it includes the take-queue wait, batch
-        formation, the python pump, tensorize, device step and
-        response build — everything a python-side timer misses."""
+        response frame write), so it is the one number that holds the
+        whole of a request's stay: the wait in the C++ queue for a
+        free pump (queue_wait()), then the pump's cycle from the take
+        on (spans wire_decode → the check stages → serialize → send,
+        monitor.latency_snapshot()["spans"]), then the IO thread's
+        framing. Bucketed: quantiles are within ±4.5 %."""
         raw = self.latency_raw()
         buckets = raw["buckets"]
         if since is not None:
@@ -387,23 +411,42 @@ class NativeMixerServer(MixerGrpcServer):
     # -- pump --
 
     def _pump_loop(self) -> None:
-        cap = 1 << 23          # per-thread: cap and buffer must agree
-        buf = ctypes.create_string_buffer(cap)
+        """One pump. A cycle (span `pump_cycle`) runs from the first
+        h2srv_take after the previous batch's completions went out to
+        the return of this batch's _send_completions; inside it the
+        top-level spans take_wait, wire_decode, the check stages
+        (monitor.CHECK_STAGES), serialize and send tile the wall, so
+        what no span covers is one subtraction (the benchmark's
+        pump_unaccounted_ms_per_batch)."""
+        take = [ctypes.create_string_buffer(1 << 23)]
+        try:
+            while True:
+                with monitor.span("pump_cycle"):
+                    with monitor.span("take_wait"):
+                        n = self._take(take)
+                    try:
+                        self._run_batch(take[0], n)
+                    except Exception:
+                        log.exception("native pump batch failed")
+        except _PumpStopped:
+            return
+
+    def _take(self, take: list) -> int:
+        """Block in h2srv_take until it hands this pump a batch: empty
+        time-outs and buffer growth are part of the wait. `take` holds
+        the pump's one buffer, replaced here when a batch outgrows it.
+        Returns the bytes taken into take[0]. Raises _PumpStopped at
+        shutdown, which closes the open spans unobserved."""
         while not self._stop_flag.is_set():
-            n = self._lib.h2srv_take(self._h, _TAKE_TIMEOUT_MS, buf,
-                                     cap)
+            n = self._lib.h2srv_take(self._h, _TAKE_TIMEOUT_MS,
+                                     take[0], len(take[0]))
             if n == -1:
-                return
-            if n == 0:
-                continue
+                break
+            if n > 0:
+                return n
             if n < 0:          # buffer too small: grow and retry
-                cap = -int(n) * 2
-                buf = ctypes.create_string_buffer(cap)
-                continue
-            try:
-                self._run_batch(buf.raw[:n])
-            except Exception:
-                log.exception("native pump batch failed")
+                take[0] = ctypes.create_string_buffer(-int(n) * 2)
+        raise _PumpStopped
 
     @staticmethod
     def _parse_take(blob: bytes) -> list[tuple]:
@@ -445,30 +488,49 @@ class NativeMixerServer(MixerGrpcServer):
                           traceparent))
         return items
 
-    def _run_batch(self, blob: bytes) -> None:
-        items = self._parse_take(blob)
+    def _run_batch(self, buf, n: int) -> None:
+        """One taken batch: the first `n` bytes of the pump's take
+        buffer `buf`."""
+        items: list = []
         completions: list[tuple[int, int, bytes]] = []
         deferred: set[int] = set()
         try:
-            self._run_batch_inner(items, completions, deferred)
+            with monitor.span("wire_decode") as decode:
+                items = self._parse_take(buf.raw[:n])
+                checks = [it for it in items if it[1] == 0]
+                bags = []
+                for _, _, payload, gwc, _, _, _ in checks:
+                    native = gwc in (0, len(GLOBAL_WORD_LIST))
+                    bags.append(self.runtime.preprocess(
+                        LazyWireBag(payload, gwc or None,
+                                    native_ok=native)))
+            if checks:
+                # flight-recorder pre-mark: the wire→bag decode wall
+                # joins the next batch tape on this pump thread
+                # (httpd.cpp's t_decode_ns covers the C++ side; this
+                # is the python envelope's share)
+                forensics.RECORDER.note_wire_decode(decode.seconds)
+            self._run_batch_inner(items, checks, bags, completions,
+                                  deferred)
         except Exception:
             # belt: NO failure may abandon a row — an unanswered tag
             # hangs its client until deadline AND leaks the C++
             # in_flight count (one bad request must not poison its
             # batch-mates' connections)
             log.exception("native pump batch failed")
-        done = {tag for tag, _, _ in completions} | deferred
-        for item in items:
-            if item[0] not in done:
-                completions.append(
-                    (item[0], 13, b"internal: batch processing failed"))
-        self._send_completions(completions)
+        with monitor.span("send"):
+            done = {tag for tag, _, _ in completions} | deferred
+            for item in items:
+                if item[0] not in done:
+                    completions.append(
+                        (item[0], 13,
+                         b"internal: batch processing failed"))
+            self._send_completions(completions)
 
-    def _run_batch_inner(self, items: list, completions: list,
-                         deferred: set) -> None:
+    def _run_batch_inner(self, items: list, checks: list, bags: list,
+                         completions: list, deferred: set) -> None:
         from istio_tpu.utils import tracing
 
-        checks = [it for it in items if it[1] == 0]
         reports = [it for it in items if it[1] == 1]
 
         if checks:
@@ -490,7 +552,7 @@ class NativeMixerServer(MixerGrpcServer):
                 "rpc.check", parent=parent, transport="native",
                 batch=len(checks))
             with span_ctx as span:
-                self._run_checks(checks, completions, deferred,
+                self._run_checks(checks, bags, completions, deferred,
                                  span=span)
 
         if reports:
@@ -580,8 +642,10 @@ class NativeMixerServer(MixerGrpcServer):
             span["tags"]["status"] = "ok" if first_bad == 0 \
                 else str(first_bad)
 
-    def _run_checks(self, checks: list, completions: list,
+    def _run_checks(self, checks: list, bags: list, completions: list,
                     deferred: set, span: dict | None = None) -> None:
+        """`bags`: the preprocessed LazyWireBag of each row of
+        `checks` (_run_batch built them under the wire_decode span)."""
         monitor.CHECK_REQUESTS.inc(len(checks))
         # the C++ wire carries no per-RPC deadline — apply the
         # server-side default (--default-check-deadline-ms) from the
@@ -589,21 +653,6 @@ class NativeMixerServer(MixerGrpcServer):
         # this batch can't reach in time answer DEADLINE_EXCEEDED
         # pre-tensorize instead of queueing dead device work
         deadline = self._deadline_from(None)
-        import time as _time
-
-        from istio_tpu.runtime import forensics
-        t_dec0 = _time.perf_counter()
-        bags = []
-        for _, _, payload, gwc, _, _, _ in checks:
-            native = gwc in (0, len(GLOBAL_WORD_LIST))
-            bags.append(self.runtime.preprocess(
-                LazyWireBag(payload, gwc or None,
-                            native_ok=native)))
-        # flight-recorder pre-mark: the wire→bag decode wall joins the
-        # next batch tape on this pump thread (httpd.cpp's t_decode_ns
-        # covers the C++ side; this is the python envelope's share)
-        forensics.RECORDER.note_wire_decode(
-            _time.perf_counter() - t_dec0)
         # in-step quota (ServerArgs.quota_in_step): eligible
         # single-quota rows allocate IN the check trip — no
         # pool-flush trip serialized behind it, no defer
@@ -652,6 +701,16 @@ class NativeMixerServer(MixerGrpcServer):
             # batch's wire_decode stage (no-op when a chunk consumed
             # it normally)
             forensics.RECORDER.clear_premarks()
+        # span `serialize`: everything between the dispatcher's return
+        # and the completions' send — the per-row response build /
+        # SerializeToString loop, quota deferral included
+        with monitor.span("serialize"):
+            self._serialize_rows(checks, bags, results, inres,
+                                 completions, deferred, span)
+
+    def _serialize_rows(self, checks: list, bags: list, results: list,
+                        inres: dict, completions: list, deferred: set,
+                        span: dict | None) -> None:
         # `status` tag (batch-level: ok or the first non-OK code) so
         # /debug/traces can filter failing check spans on this front
         if span is not None:

@@ -170,16 +170,69 @@ def _on_event(event: str, **kw) -> None:
             _EVENTS["misses"] += 1
 
 
+# what a jit's first call at a shape is made of, process-wide: tracing
+# python to a jaxpr, lowering it to an MLIR module, and the backend
+# compile — which is where a persistent-cache HIT is paid too
+# (deserializing the executable), so a prewarm "with every executable
+# a cache hit" still shows its trace + lower seconds here. Kept as
+# time spans, not summed durations: a jit traced inside another's
+# trace reports both, and the union counts that wall once.
+_PHASE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_s",
+}
+# per phase: the disjoint spans seen so far, sorted by time
+_PHASE_SPANS: dict[str, list] = {
+    phase: [] for phase in _PHASE_EVENTS.values()}
+
+
+def _merge_span(spans: list, start: float, end: float) -> None:
+    """Merge (start, end) into `spans`, kept disjoint and sorted. A
+    listener fires when its span closes, so spans arrive in end order
+    and a nested one before the span that holds it: the new span
+    swallows from the tail whatever it overlaps — amortized O(1). The
+    step at 1k RBAC roles fires 10 000 nested trace spans a shape;
+    anything per-event that walks the list shows up in set-up."""
+    later = []
+    while spans and spans[-1][0] > end:    # another thread's, rare
+        later.append(spans.pop())
+    while spans and spans[-1][1] >= start:
+        held_start, held_end = spans.pop()
+        start, end = min(start, held_start), max(end, held_end)
+    spans.append((start, end))
+    spans.extend(reversed(later))
+
+
+def _on_time_span(event: str, start: float, end: float, **kw) -> None:
+    phase = _PHASE_EVENTS.get(event)
+    if phase is not None:
+        with _EVENTS_LOCK:
+            _merge_span(_PHASE_SPANS[phase], start, end)
+
+
 def install_event_counters() -> None:
-    """Register the jax monitoring listener that feeds
-    cache_event_counts(). Idempotent."""
+    """Register the jax monitoring listeners that feed
+    cache_event_counts() and phase_seconds(). Idempotent."""
     global _EVENTS_INSTALLED
     if _EVENTS_INSTALLED:
         return
     import jax.monitoring
 
     jax.monitoring.register_event_listener(_on_event)
+    jax.monitoring.register_event_time_span_listener(_on_time_span)
     _EVENTS_INSTALLED = True
+
+
+def phase_seconds() -> dict:
+    """{"trace_s", "lower_s", "backend_s"}: wall seconds this process
+    has spent tracing, lowering and backend-compiling (or loading from
+    the persistent cache) since the counters were installed, nested
+    and concurrent spans of one phase counted once. Snapshot and diff
+    for a phase-scoped view."""
+    with _EVENTS_LOCK:
+        return {phase: sum(end - start for start, end in spans)
+                for phase, spans in _PHASE_SPANS.items()}
 
 
 def cache_event_counts() -> dict:

@@ -401,7 +401,11 @@ class PolicyEngine:
             rb_allow_j = params["pe_rb_allow"]
             err_rule_mask_j = params.get("pe_err_rule_mask")
             b = batch.ids.shape[0]
-            matched, not_matched, err = ruleset_run(params, batch)
+            # jax.named_scope: metadata only — the profiler's device
+            # plane names each op's scope, so a trace can group the
+            # step's device time by section
+            with jax.named_scope("match"):
+                matched, not_matched, err = ruleset_run(params, batch)
             ns_ok = (rule_ns[None, :] == default_ns) | \
                     (rule_ns[None, :] == req_ns[:, None])
             active = matched & ns_ok                      # [B, R]
@@ -417,240 +421,249 @@ class PolicyEngine:
             BIGI = jnp.iinfo(jnp.int32).max
             rule_idx = jnp.arange(active.shape[1], dtype=jnp.int32)
 
-            dmask = active & deny_mask_j[None, :]
-            d_key = jnp.where(dmask, rule_idx[None, :], BIGI)
-            d_arg = jnp.argmin(d_key, axis=1)
-            cand_rule = jnp.min(d_key, axis=1)
-            cand_status = deny_status_j[d_arg]
-            dur = jnp.min(jnp.where(dmask, deny_dur_j[None, :], _BIG), axis=1)
-            uses = jnp.min(jnp.where(dmask, deny_uses_j[None, :],
-                                     np.iinfo(np.int32).max), axis=1)
+            with jax.named_scope("deny"):
+                dmask = active & deny_mask_j[None, :]
+                d_key = jnp.where(dmask, rule_idx[None, :], BIGI)
+                d_arg = jnp.argmin(d_key, axis=1)
+                cand_rule = jnp.min(d_key, axis=1)
+                cand_status = deny_status_j[d_arg]
+                dur = jnp.min(
+                    jnp.where(dmask, deny_dur_j[None, :], _BIG), axis=1)
+                uses = jnp.min(jnp.where(dmask, deny_uses_j[None, :],
+                                         np.iinfo(np.int32).max), axis=1)
 
             if has_lists:
-                sym = batch.ids[:, list_slot_j]           # [B, L]
-                sym_ok = batch.present[:, list_slot_j]
-                member = jnp.any(
-                    sym[:, :, None] == list_ids_j[None, :, :], axis=2)
-                # und exists ONLY when regex banks do: the err
-                # scatter-max below is a [B, R]-operand scatter, and
-                # running it with an identically-False mask faulted
-                # the TPU at 50k rules (r4 regression; XLA kernel
-                # fault) while buying nothing
-                und = jnp.zeros_like(member) if rx_banks else None
-                for bank in rx_banks:
-                    # one packed DFA scan per value byte slot answers
-                    # every REGEX list over that subject. MXU one-hot
-                    # formulations win at EVERY batch size (profiled
-                    # r4/r5: the per-step [B, N] gather is latency-
-                    # bound regardless of B — it alone held the B=64
-                    # latency tier over the 1ms budget)
-                    s_data = batch.str_bytes[:, bank["bslot"]]
-                    s_lens = batch.str_lens[:, bank["bslot"]]
-                    if bank["packed"] is not None:
-                        m = bytes_ops.dfa_match_many_onehot(
-                            s_data, s_lens, bank["packed"])
-                    elif bank["packed_blk"] is not None:
-                        m = bytes_ops.dfa_match_many_onehot_blocked(
-                            s_data, s_lens, bank["packed_blk"])
-                    else:
-                        m = bytes_ops.dfa_match_many(
-                            s_data, s_lens, bank["trans"],
-                            bank["accept"])
-                    m8 = m.astype(jnp.int8)
-                    hit = lax.dot_general(
-                        m8, bank["M"], dims,
-                        preferred_element_type=jnp.int32) > 0
-                    dec = lax.dot_general(
-                        m8, bank["M_def"], dims,
-                        preferred_element_type=jnp.int32) > 0
-                    # truncation contract (= byte predicates): a $-free
-                    # prefix hit is definitive; anything else on a
-                    # truncated value is undecidable → err the rule's
-                    # row, suppress the deny (fail-open, counted)
-                    trunc = (s_lens >= max_len)[:, None]
-                    member = member.at[:, bank["pos"]].set(
-                        jnp.where(trunc, dec, hit))
-                    und = und.at[:, bank["pos"]].set(trunc & ~dec)
-                bad = None        # present-but-unusable values
-                if cidr_bank is not None:
-                    vb = batch.str_bytes[:, cidr_bank["bslots"], :16]
-                    vl = batch.str_lens[:, cidr_bank["bslots"]]
-                    mapped = jnp.zeros_like(vb)
-                    mapped = mapped.at[:, :, 10:12].set(255)
-                    mapped = mapped.at[:, :, 12:16].set(vb[:, :, 0:4])
-                    is4 = vl == 4
-                    v6m_pre = jnp.concatenate(
-                        [jnp.zeros(10, jnp.uint8),
-                         jnp.full(2, 255, jnp.uint8)])
-                    val_mapped = jnp.all(
-                        vb[:, :, :12] == v6m_pre[None, None, :], axis=2)
-                    v = jnp.where(is4[:, :, None], mapped, vb)
-                    val_ok = is4 | (vl == 16)
-                    val_v4 = is4 | ((vl == 16) & val_mapped)
-                    hit_e = jnp.all(
-                        (v[:, :, None, :] & cidr_bank["mask"][None]) ==
-                        cidr_bank["prefix"][None], axis=3)
-                    hit_e &= cidr_bank["valid"][None]
-                    hit_e &= (val_v4[:, :, None] ==
-                              cidr_bank["ent_v4"][None])
-                    member = member.at[:, cidr_bank["pos"]].set(
-                        jnp.any(hit_e, axis=2) & val_ok)
-                    # malformed present IP bytes (length not 4/16):
-                    # the host adapter raises before membership →
-                    # INTERNAL (handle_check's bytes normalization)
-                    bad = jnp.zeros_like(member).at[
-                        :, cidr_bank["pos"]].set(~val_ok)
-                # host parity for unusable values: an ACTIVE list rule
-                # whose value is absent (instance build EvalError) or
-                # malformed takes the _safe_check INTERNAL path — the
-                # device must not silently fail open
-                l_rule_act = active[:, list_rule_j]
-                l_internal = l_rule_act & ~sym_ok
-                l_eval = l_rule_act & sym_ok
-                if bad is not None:
-                    l_internal |= l_rule_act & sym_ok & bad
-                    l_eval &= ~bad
-                if und is not None:
-                    l_eval &= ~und
-                    err = err.at[:, list_rule_j].max(und)
-                l_hit = l_internal | (
-                    l_eval & (member == list_black_j[None, :]))
-                l_key = jnp.where(l_hit, list_rule_j[None, :], BIGI)
-                l_arg = jnp.argmin(l_key, axis=1)
-                l_rule = jnp.min(l_key, axis=1)
-                winner_internal = jnp.take_along_axis(
-                    l_internal, l_arg[:, None], axis=1)[:, 0]
-                take_l = l_rule < cand_rule     # strict: deny wins ties
-                cand_status = jnp.where(
-                    take_l,
-                    jnp.where(winner_internal, INTERNAL,
-                              list_code_j[l_arg]),
-                    cand_status)
-                cand_rule = jnp.minimum(cand_rule, l_rule)
-                dur = jnp.minimum(dur, jnp.min(
-                    jnp.where(l_eval, list_dur_j[None, :], _BIG), axis=1))
-                uses = jnp.minimum(uses, jnp.min(
-                    jnp.where(l_eval, list_uses_j[None, :],
-                              np.iinfo(np.int32).max), axis=1))
-                # an INTERNAL result carries the CheckResult DEFAULTS
-                # into the TTL min (host _combine parity)
-                any_internal = jnp.any(l_internal, axis=1)
-                dur = jnp.where(any_internal,
-                                jnp.minimum(dur, DEFAULT_DUR), dur)
-                uses = jnp.where(any_internal,
-                                 jnp.minimum(uses, DEFAULT_USES), uses)
+                with jax.named_scope("lists"):
+                    sym = batch.ids[:, list_slot_j]           # [B, L]
+                    sym_ok = batch.present[:, list_slot_j]
+                    member = jnp.any(
+                        sym[:, :, None] == list_ids_j[None, :, :], axis=2)
+                    # und exists ONLY when regex banks do: the err
+                    # scatter-max below is a [B, R]-operand scatter, and
+                    # running it with an identically-False mask faulted
+                    # the TPU at 50k rules (r4 regression; XLA kernel
+                    # fault) while buying nothing
+                    und = jnp.zeros_like(member) if rx_banks else None
+                    for bank in rx_banks:
+                        # one packed DFA scan per value byte slot answers
+                        # every REGEX list over that subject. MXU one-hot
+                        # formulations win at EVERY batch size (profiled
+                        # r4/r5: the per-step [B, N] gather is latency-
+                        # bound regardless of B — it alone held the B=64
+                        # latency tier over the 1ms budget)
+                        s_data = batch.str_bytes[:, bank["bslot"]]
+                        s_lens = batch.str_lens[:, bank["bslot"]]
+                        if bank["packed"] is not None:
+                            m = bytes_ops.dfa_match_many_onehot(
+                                s_data, s_lens, bank["packed"])
+                        elif bank["packed_blk"] is not None:
+                            m = bytes_ops.dfa_match_many_onehot_blocked(
+                                s_data, s_lens, bank["packed_blk"])
+                        else:
+                            m = bytes_ops.dfa_match_many(
+                                s_data, s_lens, bank["trans"],
+                                bank["accept"])
+                        m8 = m.astype(jnp.int8)
+                        hit = lax.dot_general(
+                            m8, bank["M"], dims,
+                            preferred_element_type=jnp.int32) > 0
+                        dec = lax.dot_general(
+                            m8, bank["M_def"], dims,
+                            preferred_element_type=jnp.int32) > 0
+                        # truncation contract (= byte predicates): a $-free
+                        # prefix hit is definitive; anything else on a
+                        # truncated value is undecidable → err the rule's
+                        # row, suppress the deny (fail-open, counted)
+                        trunc = (s_lens >= max_len)[:, None]
+                        member = member.at[:, bank["pos"]].set(
+                            jnp.where(trunc, dec, hit))
+                        und = und.at[:, bank["pos"]].set(trunc & ~dec)
+                    bad = None        # present-but-unusable values
+                    if cidr_bank is not None:
+                        vb = batch.str_bytes[:, cidr_bank["bslots"], :16]
+                        vl = batch.str_lens[:, cidr_bank["bslots"]]
+                        mapped = jnp.zeros_like(vb)
+                        mapped = mapped.at[:, :, 10:12].set(255)
+                        mapped = mapped.at[:, :, 12:16].set(vb[:, :, 0:4])
+                        is4 = vl == 4
+                        v6m_pre = jnp.concatenate(
+                            [jnp.zeros(10, jnp.uint8),
+                             jnp.full(2, 255, jnp.uint8)])
+                        val_mapped = jnp.all(
+                            vb[:, :, :12] == v6m_pre[None, None, :], axis=2)
+                        v = jnp.where(is4[:, :, None], mapped, vb)
+                        val_ok = is4 | (vl == 16)
+                        val_v4 = is4 | ((vl == 16) & val_mapped)
+                        hit_e = jnp.all(
+                            (v[:, :, None, :] & cidr_bank["mask"][None]) ==
+                            cidr_bank["prefix"][None], axis=3)
+                        hit_e &= cidr_bank["valid"][None]
+                        hit_e &= (val_v4[:, :, None] ==
+                                  cidr_bank["ent_v4"][None])
+                        member = member.at[:, cidr_bank["pos"]].set(
+                            jnp.any(hit_e, axis=2) & val_ok)
+                        # malformed present IP bytes (length not 4/16):
+                        # the host adapter raises before membership →
+                        # INTERNAL (handle_check's bytes normalization)
+                        bad = jnp.zeros_like(member).at[
+                            :, cidr_bank["pos"]].set(~val_ok)
+                    # host parity for unusable values: an ACTIVE list rule
+                    # whose value is absent (instance build EvalError) or
+                    # malformed takes the _safe_check INTERNAL path — the
+                    # device must not silently fail open
+                    l_rule_act = active[:, list_rule_j]
+                    l_internal = l_rule_act & ~sym_ok
+                    l_eval = l_rule_act & sym_ok
+                    if bad is not None:
+                        l_internal |= l_rule_act & sym_ok & bad
+                        l_eval &= ~bad
+                    if und is not None:
+                        l_eval &= ~und
+                        err = err.at[:, list_rule_j].max(und)
+                    l_hit = l_internal | (
+                        l_eval & (member == list_black_j[None, :]))
+                    l_key = jnp.where(l_hit, list_rule_j[None, :], BIGI)
+                    l_arg = jnp.argmin(l_key, axis=1)
+                    l_rule = jnp.min(l_key, axis=1)
+                    winner_internal = jnp.take_along_axis(
+                        l_internal, l_arg[:, None], axis=1)[:, 0]
+                    take_l = l_rule < cand_rule     # strict: deny wins ties
+                    cand_status = jnp.where(
+                        take_l,
+                        jnp.where(winner_internal, INTERNAL,
+                                  list_code_j[l_arg]),
+                        cand_status)
+                    cand_rule = jnp.minimum(cand_rule, l_rule)
+                    dur = jnp.minimum(dur, jnp.min(
+                        jnp.where(l_eval, list_dur_j[None, :], _BIG), axis=1))
+                    uses = jnp.minimum(uses, jnp.min(
+                        jnp.where(l_eval, list_uses_j[None, :],
+                                  np.iinfo(np.int32).max), axis=1))
+                    # an INTERNAL result carries the CheckResult DEFAULTS
+                    # into the TTL min (host _combine parity)
+                    any_internal = jnp.any(l_internal, axis=1)
+                    dur = jnp.where(any_internal,
+                                    jnp.minimum(dur, DEFAULT_DUR), dur)
+                    uses = jnp.where(any_internal,
+                                     jnp.minimum(uses, DEFAULT_USES), uses)
 
             if has_rbac:
-                # allowed iff ANY lowered (binding, subject, role-rule)
-                # pseudo-rule matched; guard row not definitely-true →
-                # the host instance build would have errored → INTERNAL
-                # (rbac.go:181 + dispatcher _safe_check parity)
-                m_ext = jnp.concatenate(
-                    [matched, jnp.zeros((b, 1), bool),
-                     jnp.ones((b, 1), bool)], axis=1)
-                allow = jnp.any(m_ext[:, rb_allow_j], axis=2)
-                guard_ok = m_ext[:, rb_guard_j]
-                r_active = active[:, rb_rule_j]
-                r_deny = r_active & guard_ok & ~allow
-                r_bad = r_deny | (r_active & ~guard_ok)
-                rb_key = jnp.where(r_bad, rb_rule_j[None, :], BIGI)
-                rb_arg = jnp.argmin(rb_key, axis=1)
-                rb_rule_min = jnp.min(rb_key, axis=1)
-                rb_status = jnp.where(
-                    jnp.take_along_axis(r_deny, rb_arg[:, None],
-                                        axis=1)[:, 0],
-                    PERMISSION_DENIED, INTERNAL)
-                take_rb = rb_rule_min < cand_rule   # deny/list win ties
-                cand_status = jnp.where(take_rb, rb_status, cand_status)
-                cand_rule = jnp.minimum(cand_rule, rb_rule_min)
-                # the handler returns caching_ttl on allow AND deny
-                # verdicts alike; on INTERNAL the host CheckResult
-                # carries only defaults (no-op under min) — skip it
-                dur = jnp.minimum(dur, jnp.min(
-                    jnp.where(r_active & guard_ok, rb_dur_j[None, :],
-                              _BIG), axis=1))
-            status = jnp.where(cand_rule < BIGI, cand_status, OK)
+                with jax.named_scope("rbac"):
+                    # allowed iff ANY lowered (binding, subject, role-rule)
+                    # pseudo-rule matched; guard row not definitely-true →
+                    # the host instance build would have errored → INTERNAL
+                    # (rbac.go:181 + dispatcher _safe_check parity)
+                    m_ext = jnp.concatenate(
+                        [matched, jnp.zeros((b, 1), bool),
+                         jnp.ones((b, 1), bool)], axis=1)
+                    allow = jnp.any(m_ext[:, rb_allow_j], axis=2)
+                    guard_ok = m_ext[:, rb_guard_j]
+                    r_active = active[:, rb_rule_j]
+                    r_deny = r_active & guard_ok & ~allow
+                    r_bad = r_deny | (r_active & ~guard_ok)
+                    rb_key = jnp.where(r_bad, rb_rule_j[None, :], BIGI)
+                    rb_arg = jnp.argmin(rb_key, axis=1)
+                    rb_rule_min = jnp.min(rb_key, axis=1)
+                    rb_status = jnp.where(
+                        jnp.take_along_axis(r_deny, rb_arg[:, None],
+                                            axis=1)[:, 0],
+                        PERMISSION_DENIED, INTERNAL)
+                    take_rb = rb_rule_min < cand_rule   # deny/list win ties
+                    cand_status = jnp.where(take_rb, rb_status, cand_status)
+                    cand_rule = jnp.minimum(cand_rule, rb_rule_min)
+                    # the handler returns caching_ttl on allow AND deny
+                    # verdicts alike; on INTERNAL the host CheckResult
+                    # carries only defaults (no-op under min) — skip it
+                    dur = jnp.minimum(dur, jnp.min(
+                        jnp.where(r_active & guard_ok, rb_dur_j[None, :],
+                                  _BIG), axis=1))
+            with jax.named_scope("combine"):
+                status = jnp.where(cand_rule < BIGI, cand_status, OK)
 
             if self._has_quota:
-                # bucket = stable content hash mod hash space; fixed
-                # window. Uses hash_ids, not ids: ephemeral ids vary
-                # with encounter order while the counter window
-                # persists across batches. Quota is dispatched only
-                # when the precondition check passed
-                # (grpcServer.go:188-230 runs the quota loop after a
-                # successful Check) — denied requests must not consume
-                # tokens.
-                key = batch.hash_ids[:, q_slot_j]         # [B, Q]
-                key_ok = batch.present[:, q_slot_j]
-                q_active = active[:, q_rule_j] & key_ok & \
-                    (status == OK)[:, None]               # [B, Q]
-                bucket = (key % q_nb_j[None, :]).astype(jnp.int32)
-                # sequential-within-batch grant: request i granted iff
-                # prior_count + its rank among same-bucket active peers
-                # < max. One flattened stable sort over [Q·B] composite
-                # keys ranks every quota at once (the naive [B, B, Q]
-                # pairwise compare cost 8ms/step at B=2048).
-                # composite int32 keys; the inactive sentinel INT32_MAX
-                # sorts past every real key (constructor bounds
-                # n_quotas·n_buckets < INT32_MAX — jnp has no int64
-                # without x64 mode)
-                n_q = quota_counts.shape[0]
-                qoff = jnp.arange(n_q, dtype=jnp.int32)[None, :] * \
-                    quota_counts.shape[1]
-                ckey = jnp.where(q_active, bucket + qoff,
-                                 jnp.iinfo(jnp.int32).max)
-                if b <= 256:
-                    # latency tier: the flattened sort costs ~0.2ms of
-                    # fixed latency; a strict-lower-triangle pairwise
-                    # count is B²·Q trivial compares at small static B
-                    eq = ckey[None, :, :] == ckey[:, None, :]  # [B,B,Q]
-                    lower = (jnp.arange(b)[None, :] <
-                             jnp.arange(b)[:, None])[:, :, None]
-                    rank = jnp.sum(eq & lower, axis=1,
-                                   dtype=jnp.int32)            # [B, Q]
-                else:
-                    rank = _batch_rank(
-                        ckey.T.reshape(-1)).reshape(n_q, b).T
-                prior_per_req = quota_counts[
-                    jnp.arange(n_q)[None, :], bucket]            # [B, Q]
-                granted = q_active & (prior_per_req + rank < q_max_j[None, :])
-                over = q_active & ~granted
-                # quota only runs where status is still OK (q_active
-                # gating above), so a RESOURCE_EXHAUSTED here is always
-                # the lowest-index non-OK source for that request
-                any_over = jnp.any(over, axis=1)
-                status = jnp.where(any_over, RESOURCE_EXHAUSTED, status)
-                cand_rule = jnp.where(
-                    any_over,
-                    jnp.min(jnp.where(over, q_rule_j[None, :], BIGI),
-                            axis=1),
-                    cand_rule)
-                # commit grants: scatter-add per (quota, bucket)
-                flat = bucket + jnp.arange(bucket.shape[1])[None, :] * \
-                    quota_counts.shape[1]
-                add = jnp.zeros(quota_counts.size, jnp.int32).at[
-                    flat.reshape(-1)].add(
-                        granted.astype(jnp.int32).reshape(-1))
-                quota_counts = quota_counts + add.reshape(quota_counts.shape)
+                with jax.named_scope("quota"):
+                    # bucket = stable content hash mod hash space; fixed
+                    # window. Uses hash_ids, not ids: ephemeral ids vary
+                    # with encounter order while the counter window
+                    # persists across batches. Quota is dispatched only
+                    # when the precondition check passed
+                    # (grpcServer.go:188-230 runs the quota loop after a
+                    # successful Check) — denied requests must not consume
+                    # tokens.
+                    key = batch.hash_ids[:, q_slot_j]         # [B, Q]
+                    key_ok = batch.present[:, q_slot_j]
+                    q_active = active[:, q_rule_j] & key_ok & \
+                        (status == OK)[:, None]               # [B, Q]
+                    bucket = (key % q_nb_j[None, :]).astype(jnp.int32)
+                    # sequential-within-batch grant: request i granted iff
+                    # prior_count + its rank among same-bucket active peers
+                    # < max. One flattened stable sort over [Q·B] composite
+                    # keys ranks every quota at once (the naive [B, B, Q]
+                    # pairwise compare cost 8ms/step at B=2048).
+                    # composite int32 keys; the inactive sentinel INT32_MAX
+                    # sorts past every real key (constructor bounds
+                    # n_quotas·n_buckets < INT32_MAX — jnp has no int64
+                    # without x64 mode)
+                    n_q = quota_counts.shape[0]
+                    qoff = jnp.arange(n_q, dtype=jnp.int32)[None, :] * \
+                        quota_counts.shape[1]
+                    ckey = jnp.where(q_active, bucket + qoff,
+                                     jnp.iinfo(jnp.int32).max)
+                    if b <= 256:
+                        # latency tier: the flattened sort costs ~0.2ms of
+                        # fixed latency; a strict-lower-triangle pairwise
+                        # count is B²·Q trivial compares at small static B
+                        eq = ckey[None, :, :] == ckey[:, None, :]  # [B,B,Q]
+                        lower = (jnp.arange(b)[None, :] <
+                                 jnp.arange(b)[:, None])[:, :, None]
+                        rank = jnp.sum(eq & lower, axis=1,
+                                       dtype=jnp.int32)            # [B, Q]
+                    else:
+                        rank = _batch_rank(
+                            ckey.T.reshape(-1)).reshape(n_q, b).T
+                    prior_per_req = quota_counts[
+                        jnp.arange(n_q)[None, :], bucket]            # [B, Q]
+                    granted = q_active & (
+                        prior_per_req + rank < q_max_j[None, :])
+                    over = q_active & ~granted
+                    # quota only runs where status is still OK (q_active
+                    # gating above), so a RESOURCE_EXHAUSTED here is always
+                    # the lowest-index non-OK source for that request
+                    any_over = jnp.any(over, axis=1)
+                    status = jnp.where(any_over, RESOURCE_EXHAUSTED, status)
+                    cand_rule = jnp.where(
+                        any_over,
+                        jnp.min(jnp.where(over, q_rule_j[None, :], BIGI),
+                                axis=1),
+                        cand_rule)
+                    # commit grants: scatter-add per (quota, bucket)
+                    flat = bucket + jnp.arange(bucket.shape[1])[None, :] * \
+                        quota_counts.shape[1]
+                    add = jnp.zeros(quota_counts.size, jnp.int32).at[
+                        flat.reshape(-1)].add(
+                            granted.astype(jnp.int32).reshape(-1))
+                    quota_counts = quota_counts + add.reshape(
+                        quota_counts.shape)
 
-            attr_mask = bytes_ops.unpack_bits(
-                attr_mask_bits, n_attr_cols).astype(jnp.int8)
-            referenced = lax.dot_general(
-                ns_ok.astype(jnp.int8), attr_mask, dims,
-                preferred_element_type=jnp.int32) > 0
-            verdict = CheckVerdict(status=status.astype(jnp.int32),
-                                   valid_duration_s=dur,
-                                   valid_use_count=uses,
-                                   referenced=referenced,
-                                   matched=matched, err=err,
-                                   deny_rule=jnp.where(
-                                       status == OK, BIGI, cand_rule),
-                                   err_count=jnp.sum(
-                                       ((err & ns_ok) if err_rule_mask_j
-                                        is None else
-                                        (err & ns_ok &
-                                         err_rule_mask_j[None, :]))
-                                       .astype(jnp.int32)))
+            with jax.named_scope("combine"):
+                attr_mask = bytes_ops.unpack_bits(
+                    attr_mask_bits, n_attr_cols).astype(jnp.int8)
+                referenced = lax.dot_general(
+                    ns_ok.astype(jnp.int8), attr_mask, dims,
+                    preferred_element_type=jnp.int32) > 0
+                verdict = CheckVerdict(status=status.astype(jnp.int32),
+                                       valid_duration_s=dur,
+                                       valid_use_count=uses,
+                                       referenced=referenced,
+                                       matched=matched, err=err,
+                                       deny_rule=jnp.where(
+                                           status == OK, BIGI, cand_rule),
+                                       err_count=jnp.sum(
+                                           ((err & ns_ok) if err_rule_mask_j
+                                            is None else
+                                            (err & ns_ok &
+                                             err_rule_mask_j[None, :]))
+                                           .astype(jnp.int32)))
             return verdict, quota_counts
 
         # ---- compiled-shape geometry for the roofline accounting

@@ -470,6 +470,12 @@ struct Server {
   // [7] protocol_errors [8] bytes_in [9] bytes_out
   std::atomic<int64_t> counters[10] = {};
   int64_t hist[16] = {0};
+  // C++ queue wait: at the moment take_impl hands rows to a pump, the
+  // time each row sat in `queue` since enqueue_request stamped it
+  // (t_enq_ns, microseconds after the frame-decode stamp). [0] sum of
+  // ns, [1] rows. Written under `mu` by the taking pump; read by
+  // h2srv_queue_wait.
+  std::atomic<int64_t> queue_wait[2] = {};
   // wire-to-verdict latency histogram: 192 log-spaced buckets, bucket
   // i covers latencies up to 1µs·2^(i/8) (ratio 2^(1/8) ≈ 1.09, so a
   // quantile read interpolates within ±4.5%); covers 1µs .. ~16s.
@@ -1317,8 +1323,11 @@ int64_t take_impl(Server* srv, int32_t timeout_ms, uint8_t* buf,
   out.reserve(need);
   put_u32(&out, static_cast<uint32_t>(srv->counters[2]));
   put_u32(&out, static_cast<uint32_t>(n));
+  const int64_t t_take_ns = mono_ns();
+  int64_t waited_ns = 0;
   for (int32_t i = 0; i < n; i++) {
     PendingItem& it = srv->queue.front();
+    waited_ns += t_take_ns - it.t_enq_ns;
     put_u64(&out, it.tag);
     out.push_back(static_cast<char>(it.kind));
     const std::string& payload =
@@ -1343,6 +1352,8 @@ int64_t take_impl(Server* srv, int32_t timeout_ms, uint8_t* buf,
   if (!srv->queue.empty()) srv->first_enq_ns = mono_ns();
   srv->counters[2]++;
   srv->counters[3] += n;
+  srv->queue_wait[0].fetch_add(waited_ns, std::memory_order_relaxed);
+  srv->queue_wait[1].fetch_add(n, std::memory_order_relaxed);
   int b = 0;
   while ((1 << b) < n && b < 15) b++;
   srv->hist[b]++;
@@ -1406,6 +1417,18 @@ void h2srv_counters(void* h, int64_t* out, int64_t* hist) {
     std::lock_guard<std::mutex> lk(srv->mu);
     memcpy(hist, srv->hist, sizeof(srv->hist));
   }
+  abi_exit(srv);
+}
+
+// Queue wait of the rows handed to pumps so far: out[0] = sum over
+// rows of (take time - enqueue time) in ns, out[1] = rows. Cumulative;
+// the python side reads mean wait per row from snapshot deltas.
+void h2srv_queue_wait(void* h, int64_t* out) {
+  Server* srv = static_cast<Server*>(h);
+  out[0] = out[1] = 0;
+  if (!abi_enter(srv)) return;
+  out[0] = srv->queue_wait[0].load(std::memory_order_relaxed);
+  out[1] = srv->queue_wait[1].load(std::memory_order_relaxed);
   abi_exit(srv);
 }
 

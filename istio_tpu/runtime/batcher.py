@@ -530,8 +530,9 @@ class CheckBatcher:
             if not batch:
                 return
             # flight-recorder tape (runtime/forensics.py): opened per
-            # batch on this worker thread; the monitor.observe_stage
-            # calls below and in the dispatcher feed it, and the
+            # batch on this worker thread; the queue_wait observation
+            # below and the dispatcher's monitor.stage sites feed it,
+            # and the
             # completion note captures a slow exemplar only when the
             # batch's slowest request crossed the threshold. Check
             # path only — report batches carry their own stages.

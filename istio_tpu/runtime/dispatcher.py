@@ -223,12 +223,15 @@ class Dispatcher:
         wires = [getattr(bag, "wire", None) for bag in bags]
         if plan.native is not None and all(w is not None
                                            for w in wires):
-            batch = plan.native.tensorize_wire(wires)
+            with monitor.span("tensorize.decode"):
+                batch = plan.native.tensorize_wire(wires)
             if self.overlap_h2d:
                 # h2d begins NOW — the transfer runs while
                 # _ns_ids_from_batch does its host-side decode
-                batch = self._stage_h2d(plan, batch)
-            ns_ids = self._ns_ids_from_batch(batch)
+                with monitor.span("tensorize.stage_put"):
+                    batch = self._stage_h2d(plan, batch)
+            with monitor.span("tensorize.ns_ids"):
+                ns_ids = self._ns_ids_from_batch(batch)
         else:
             batch = self.snapshot.tensorizer.tensorize(bags)
             ns_ids = self._request_ns_ids(bags)
@@ -354,23 +357,20 @@ class Dispatcher:
             empty: list[list[int]] = [[] for _ in bags]
             return empty, [[] for _ in bags]
         with monitor.resolve_timer():
-            t0 = time.perf_counter()
-            batch = snap.tensorizer.tensorize(bags)
-            t1 = time.perf_counter()
-            if observe:
-                monitor.observe_stage("tensorize", t1 - t0)
-                # chaos seam at the generic path's device boundary
-                # (check traffic only — observe gates out report/
-                # quota/APA resolves), mirroring packed_check's
-                from istio_tpu.runtime.resilience import CHAOS
-                CHAOS.device_step()
-            matched, _, err = snap.ruleset(batch)
-            # hotpath: sync-ok — the generic path's designated pull
-            matched = np.array(matched)    # hotpath: sync-ok
-            err = np.array(err)            # hotpath: sync-ok
-            if observe:
-                monitor.observe_stage("device_step",
-                                      time.perf_counter() - t1)
+            with monitor.stage("tensorize", on=observe,
+                               batch=len(bags)):
+                batch = snap.tensorizer.tensorize(bags)
+            with monitor.stage("device_step", on=observe):
+                if observe:
+                    # chaos seam at the generic path's device boundary
+                    # (check traffic only — observe gates out report/
+                    # quota/APA resolves), mirroring packed_check's
+                    from istio_tpu.runtime.resilience import CHAOS
+                    CHAOS.device_step()
+                matched, _, err = snap.ruleset(batch)
+                # hotpath: sync-ok — the generic path's designated pull
+                matched = np.array(matched)    # hotpath: sync-ok
+                err = np.array(err)            # hotpath: sync-ok
         ns_ids = self._request_ns_ids(bags)
         active, ns_ok = self._overlay_fallback(matched, err, ns_ids, bags)
         return ([list(np.nonzero(active[b])[0]) for b in range(len(bags))],
@@ -402,14 +402,11 @@ class Dispatcher:
                                      pre_tensorized=pre_tensorized,
                                      deadline=deadline)
         actives, visibles = self._resolve(bags, observe=self.observe)
-        t_respond = time.perf_counter()
-        out = []
-        for bag, rule_idxs, vis in zip(bags, actives, visibles):
-            out.append(self._check_one(bag, rule_idxs, vis))
-        self._apply_grants(bags, out)
-        if self.observe:
-            monitor.observe_stage("respond",
-                                  time.perf_counter() - t_respond)
+        with monitor.stage("respond", on=self.observe):
+            out = []
+            for bag, rule_idxs, vis in zip(bags, actives, visibles):
+                out.append(self._check_one(bag, rule_idxs, vis))
+            self._apply_grants(bags, out)
         # NO recorder tap here: the generic path's statuses include
         # host-adapter results the shadow replay (empty handlers,
         # device surface only) can never reproduce — a corpus recorded
@@ -429,30 +426,29 @@ class Dispatcher:
         lowest-rule-index-wins on both sides, so host results from a
         lower rule index override the device candidate and vice versa —
         the two paths provably pick the same rule's status."""
-        from istio_tpu.utils import tracing
+        from istio_tpu.runtime.batcher import trim_pads
 
         snap, plan = self.snapshot, self.fused
-        tr = tracing.get_tracer()
         # real (non-padding) prefix length, known BEFORE the device
         # call: the telemetry fold masks padding rows on device, and
         # every host-side pass below runs on the real prefix only
-        from istio_tpu.runtime.batcher import trim_pads
         n_real = len(trim_pads(bags))
         observe = self.observe
+        # serve.device (= h2d + device_step) and serve.overlay (= fold
+        # + respond) exist for the zipkin tracer alone: with no
+        # reporter the two grouping spans are off altogether
+        grouped = observe and monitor.zipkin_on()
         bridged = False
         with (monitor.resolve_timer() if observe
               else contextlib.nullcontext()):
             if pre_tensorized is not None:
                 batch, ns_ids = pre_tensorized
             else:
-                t_tz = time.perf_counter()
-                with tr.span("serve.tensorize", batch=len(bags)):
-                    # C++ wire→tensor decode when possible: no
-                    # per-request python work
+                # C++ wire→tensor decode when possible: no
+                # per-request python work
+                with monitor.stage("tensorize", on=observe,
+                                   batch=len(bags)):
                     batch, ns_ids = self._tensorize_for_device(bags)
-                if observe:
-                    monitor.observe_stage("tensorize",
-                                          time.perf_counter() - t_tz)
             # swap-warm oracle bridge: while a background warm is
             # still compiling this shape's program (a config swap
             # deferred the shapes live traffic was NOT serving), the
@@ -471,26 +467,22 @@ class Dispatcher:
                 # extra pull is another sync (chip_smoke.py prints
                 # device_sync_ms), and plane-by-plane conversion was
                 # six of them per batch
-                with tr.span("serve.device"):
+                with monitor.span("device", on=grouped):
                     if instep is not None:
-                        t_d = time.perf_counter()
                         q_arrays, counts, on_dispatch, on_pull = instep
-                        packed_dev, new_counts = \
-                            plan.packed_check_instep(
-                                batch, ns_ids, q_arrays, counts,
-                                n_real=n_real)
-                        # the program is IN FLIGHT: on_dispatch swaps
-                        # the pool onto the device-future counters and
-                        # drops the token, so the next trip chains
-                        # on-device while this one's pull is still
-                        # outstanding
-                        on_dispatch(new_counts)
-                        t_pull = time.perf_counter()
-                        monitor.observe_stage("h2d", t_pull - t_d)
-                        packed = np.asarray(packed_dev)   # the pull — hotpath: sync-ok
-                        monitor.observe_stage(
-                            "device_step",
-                            time.perf_counter() - t_pull)
+                        with monitor.stage("h2d"):
+                            packed_dev, new_counts = \
+                                plan.packed_check_instep(
+                                    batch, ns_ids, q_arrays, counts,
+                                    n_real=n_real)
+                            # the program is IN FLIGHT: on_dispatch
+                            # swaps the pool onto the device-future
+                            # counters and drops the token, so the
+                            # next trip chains on-device while this
+                            # one's pull is still outstanding
+                            on_dispatch(new_counts)
+                        with monitor.stage("device_step"):
+                            packed = np.asarray(packed_dev)   # the pull — hotpath: sync-ok
                         # granted/gate are the LAST two rows;
                         # everything the overlay decode reads sits
                         # before them
@@ -499,264 +491,289 @@ class Dispatcher:
                         packed = plan.packed_check(batch, ns_ids,
                                                    observe=observe,
                                                    n_real=n_real)
-                status = packed[0]
-                dur = packed[1].view(np.float32)
-                uses = packed[2]
-                deny_rule = packed[3]
         if bridged:
             return self.check_host_oracle(bags)
-        t_overlay = time.perf_counter()
+        with monitor.span("overlay", on=grouped, batch=n_real):
+            return self._fold_respond(snap, plan, packed, batch,
+                                      bags[:n_real], ns_ids[:n_real],
+                                      observe, deadline)
+
+    def _fold_respond(self, snap, plan, packed: np.ndarray, batch,
+                      bags: Sequence[Bag], ns_ids: np.ndarray,
+                      observe: bool, deadline: float | None
+                      ) -> list[CheckResponse]:
+        """The host half of the fused check after the pull: stage
+        `fold` (packed-plane decode: overlay bits, host-action
+        submits, referenced/presence signature dedup) then stage
+        `respond` (the per-row CheckResponse loop). `bags`/`ns_ids`
+        are the real prefix — bucket-padding rows carry no caller
+        (the batcher appends PadBags at the tail and zips results
+        against real requests), and at small arrival rates a
+        512-bucket batch is mostly padding: per-row python here is
+        the serving CPU budget."""
+        from istio_tpu.utils import tracing
+
+        tr = tracing.get_tracer()
+        n_real = len(bags)
+        status = packed[0]
+        dur = packed[1].view(np.float32)
+        uses = packed[2]
+        deny_rule = packed[3]
         rs = snap.ruleset
-
-        # bucket-padding rows carry no caller: every host-side pass
-        # below runs on the real prefix only (the batcher appends
-        # PadBags at the tail and zips results against real requests)
-        # — at small arrival rates a 512-bucket batch is mostly
-        # padding, and per-row python here is the serving CPU budget
-        bags = bags[:n_real]
-        ns_ids = ns_ids[:n_real]
-
-        # referenced-attribute item bits (rows 5..5+W): the device
-        # computed predicate + instance attr uses per request; the
-        # host just decodes set bits into names
-        n_words = plan.n_ref_words
-        if n_words:
-            from istio_tpu.runtime.fused import unpack_word_rows
-            ref_bits = unpack_word_rows(packed[5:5 + n_words, :n_real],
-                                        len(plan.item_names))
-
-        # Only plan.overlay_cols of the [B, R] matched plane are ever
-        # inspected host-side (the rows after the ref bits);
-        # converting the full plane (16MB/batch at B=2048, R=10k) was
-        # the original serving bottleneck. Namespace masking for the
-        # subset happens in numpy; host-fallback rules are
-        # oracle-evaluated into their subset positions
-        # (_overlay_active, shared with the fused report path).
-        active_sub, col_pos = self._overlay_active(packed, bags, ns_ids,
-                                                   observe=observe)
-        # hotpath: sync-ok x2 — tensorizer planes are host numpy
-        present_np = np.asarray(batch.present)[:n_real]        # hotpath: sync-ok
-        map_present_np = np.asarray(batch.map_present)[:n_real]  # hotpath: sync-ok
-        lay = rs.layout
-
-        ha = plan.host_rule_idx
-        ha_pos = np.asarray([col_pos[int(r)] for r in ha], np.int64)
-        qa_rules = sorted({qa[0] for qa in plan.quota_actions})
-        qa_pos = [col_pos[r] for r in qa_rules]
-
-        # adapter-executor plane (runtime/executor.py): submit every
-        # host action NOW, so adapter calls run on their handler
-        # bulkhead lanes WHILE the fold below decodes the referenced/
-        # presence planes — the response loop then claims results in
-        # rule order, bounded by the request deadline. One list per
-        # row, entries (rule idx, HostAction | final CheckResult) in
-        # exactly the order the inline loop would have executed them,
-        # so lowest-rule-index-wins merging is byte-identical.
         ex = self.executor
         host_pending: list[list] | None = None
-        if ex is not None and len(ha):
-            from istio_tpu.runtime.config import _qualify
-            from istio_tpu.runtime.executor import check_fallback
-            host_pending = []
-            for b, bag in enumerate(bags):
-                row: list = []
-                for ridx in ha[active_sub[b, ha_pos]]:
-                    ridx = int(ridx)
-                    for hc, template, inst_names in \
-                            plan.host_actions[ridx]:
-                        handler = self._handler_for(hc)
-                        if handler is None:
-                            continue
-                        hq = _qualify(hc.name, hc.namespace)
-                        for iname in inst_names:
-                            try:
-                                instance = \
-                                    snap.instances[iname].build(bag)
-                            except EvalError as exc:
-                                # instance build stays on this thread
-                                # (_safe_check parity: EvalError →
-                                # INTERNAL, counted as a dispatch
-                                # error)
-                                monitor.DISPATCH_ERRORS.inc()
-                                row.append((ridx, CheckResult(
-                                    status_code=INTERNAL,
-                                    status_message=str(exc))))
-                                continue
-                            row.append((ridx, ex.submit(
-                                hq,
-                                self._bound_check(handler, template,
-                                                  instance),
-                                check_fallback)))
-                host_pending.append(row)
-
         # Any exception from here to the claims must not leak
         # submitted-but-unclaimed actions: the conservation ledger
         # (submitted == resolved) is a smoke/bench gate, and a
         # ResilientChecker retry of this batch would re-submit
         # every action while the first generation dangled.
         try:
-            # Referenced/presence construction deduplicated across the
-            # batch: uniform traffic produces a handful of distinct
-            # (referenced bits, presence bits) signatures, and building
-            # the name tuples + presence dicts per ROW was milliseconds of
-            # python per request — seconds per 2048-batch, single-threaded
-            # in the batcher worker. Shared objects are read-only by
-            # contract (the gRPC layer only serializes them).
-            ref_of = None
-            if n_words:
-                signature = np.concatenate(
-                    [ref_bits[:, :len(plan.item_names)],
-                     present_np.astype(np.uint8),
-                     map_present_np.astype(np.uint8),
-                     active_sub.astype(np.uint8)], axis=1)
-                uniq, inverse = np.unique(signature, axis=0,
-                                          return_inverse=True)
-                names = plan.item_names
-                n_items = len(names)
-                shared: list[tuple[tuple, dict]] = []
-                for u in range(uniq.shape[0]):
-                    row = uniq[u]
-                    referenced = {names[j]
-                                  for j in np.nonzero(row[:n_items])[0]}
-                    act_row = row[n_items + present_np.shape[1] +
-                                  map_present_np.shape[1]:]
-                    for ridx, extra in plan.unmapped_instance_attrs.items():
-                        if act_row[col_pos[ridx]]:
-                            referenced |= extra
-                    pres_row = row[n_items:n_items + present_np.shape[1]]
-                    mp_row = row[n_items + present_np.shape[1]:
-                                 n_items + present_np.shape[1] +
-                                 map_present_np.shape[1]]
-                    presence: dict = {}
-                    for item in referenced:
-                        if isinstance(item, tuple):
-                            col = lay.derived_slots.get(item)
-                            if col is not None:
-                                presence[item] = bool(pres_row[col])
-                        else:
-                            col = lay.slots.get(item)
-                            if col is not None:
-                                presence[item] = bool(pres_row[col])
+            with monitor.stage("fold", on=observe):
+                # referenced-attribute item bits (rows 5..5+W): the
+                # device computed predicate + instance attr uses per
+                # request; the host just decodes set bits into names
+                n_words = plan.n_ref_words
+                if n_words:
+                    from istio_tpu.runtime.fused import unpack_word_rows
+                    ref_bits = unpack_word_rows(
+                        packed[5:5 + n_words, :n_real],
+                        len(plan.item_names))
+
+                # Only plan.overlay_cols of the [B, R] matched plane
+                # are ever inspected host-side (the rows after the ref
+                # bits); converting the full plane (16MB/batch at
+                # B=2048, R=10k) was the original serving bottleneck.
+                # Namespace masking for the subset happens in numpy;
+                # host-fallback rules are oracle-evaluated into their
+                # subset positions (_overlay_active, shared with the
+                # fused report path).
+                active_sub, col_pos = self._overlay_active(
+                    packed, bags, ns_ids, observe=observe)
+                # hotpath: sync-ok x2 — tensorizer planes are host numpy
+                present_np = np.asarray(   # hotpath: sync-ok
+                    batch.present)[:n_real]
+                map_present_np = np.asarray(   # hotpath: sync-ok
+                    batch.map_present)[:n_real]
+                lay = rs.layout
+
+                ha = plan.host_rule_idx
+                ha_pos = np.asarray([col_pos[int(r)] for r in ha],
+                                    np.int64)
+                qa_rules = sorted({qa[0] for qa in plan.quota_actions})
+                qa_pos = [col_pos[r] for r in qa_rules]
+
+                # adapter-executor plane (runtime/executor.py): submit
+                # every host action NOW, so adapter calls run on their
+                # handler bulkhead lanes WHILE the fold below decodes
+                # the referenced/presence planes — the response loop
+                # then claims results in rule order, bounded by the
+                # request deadline. One list per row, entries (rule
+                # idx, HostAction | final CheckResult) in exactly the
+                # order the inline loop would have executed them, so
+                # lowest-rule-index-wins merging is byte-identical.
+                if ex is not None and len(ha):
+                    from istio_tpu.runtime.config import _qualify
+                    from istio_tpu.runtime.executor import check_fallback
+                    host_pending = []
+                    for b, bag in enumerate(bags):
+                        row: list = []
+                        for ridx in ha[active_sub[b, ha_pos]]:
+                            ridx = int(ridx)
+                            for hc, template, inst_names in \
+                                    plan.host_actions[ridx]:
+                                handler = self._handler_for(hc)
+                                if handler is None:
+                                    continue
+                                hq = _qualify(hc.name, hc.namespace)
+                                for iname in inst_names:
+                                    try:
+                                        instance = snap.instances[
+                                            iname].build(bag)
+                                    except EvalError as exc:
+                                        # instance build stays on this
+                                        # thread (_safe_check parity:
+                                        # EvalError → INTERNAL, counted
+                                        # as a dispatch error)
+                                        monitor.DISPATCH_ERRORS.inc()
+                                        row.append((ridx, CheckResult(
+                                            status_code=INTERNAL,
+                                            status_message=str(exc))))
+                                        continue
+                                    row.append((ridx, ex.submit(
+                                        hq,
+                                        self._bound_check(
+                                            handler, template, instance),
+                                        check_fallback)))
+                        host_pending.append(row)
+
+                # Referenced/presence construction deduplicated across
+                # the batch: uniform traffic produces a handful of
+                # distinct (referenced bits, presence bits) signatures,
+                # and building the name tuples + presence dicts per ROW
+                # was milliseconds of python per request — seconds per
+                # 2048-batch, single-threaded in the batcher worker.
+                # Shared objects are read-only by contract (the gRPC
+                # layer only serializes them).
+                ref_of = None
+                if n_words:
+                    signature = np.concatenate(
+                        [ref_bits[:, :len(plan.item_names)],
+                         present_np.astype(np.uint8),
+                         map_present_np.astype(np.uint8),
+                         active_sub.astype(np.uint8)], axis=1)
+                    uniq, inverse = np.unique(signature, axis=0,
+                                              return_inverse=True)
+                    names = plan.item_names
+                    n_items = len(names)
+                    shared: list[tuple[tuple, dict]] = []
+                    for u in range(uniq.shape[0]):
+                        row = uniq[u]
+                        referenced = {
+                            names[j]
+                            for j in np.nonzero(row[:n_items])[0]}
+                        act_row = row[n_items + present_np.shape[1] +
+                                      map_present_np.shape[1]:]
+                        for ridx, extra in \
+                                plan.unmapped_instance_attrs.items():
+                            if act_row[col_pos[ridx]]:
+                                referenced |= extra
+                        pres_row = row[
+                            n_items:n_items + present_np.shape[1]]
+                        mp_row = row[n_items + present_np.shape[1]:
+                                     n_items + present_np.shape[1] +
+                                     map_present_np.shape[1]]
+                        presence: dict = {}
+                        for item in referenced:
+                            if isinstance(item, tuple):
+                                col = lay.derived_slots.get(item)
+                                if col is not None:
+                                    presence[item] = bool(pres_row[col])
                             else:
-                                mcol = lay.map_slots.get(item)
-                                if mcol is not None:
-                                    presence[item] = bool(mp_row[mcol])
-                    shared.append((tuple(sorted(referenced, key=str)),
-                                   presence))
-                ref_of = [shared[i] for i in inverse]
-            elif plan.unmapped_instance_attrs:
-                # no layout items at all, but some rules still carry
-                # instance attrs — merge them per row from the overlaid
-                # activity bits (presence is unknowable without a layout)
-                ref_of = []
-                for b in range(n_real):
-                    referenced: set = set()
-                    for ridx, extra in plan.unmapped_instance_attrs.items():
-                        if active_sub[b, col_pos[ridx]]:
-                            referenced |= extra
-                    ref_of.append((tuple(sorted(referenced, key=str)), {}))
-            # fold = packed-plane decode (overlay bits, referenced/presence
-            # signature dedup); respond = the per-row CheckResponse loop —
-            # together they are the span the serve.overlay emit reports
-            t_respond = time.perf_counter()
-            if observe:
-                monitor.observe_stage("fold", t_respond - t_overlay)
-            # decision exemplars: denied/errored rows reservoir-sample into
-            # the telemetry plane (host-side, post-fold, from the already-
-            # decoded verdict) with the batch's active span so a
-            # /debug/rulestats entry links to its RingReporter trace; the
-            # canary recorder shares the span so its samples join traces
-            tele = plan.telemetry if observe else None
-            tele_span = tr._current() \
-                if tele is not None or self.recorder is not None else None
-            # server-issued check-cache grants: one (ttl, uses) pair
-            # per distinct namespace, min-folded into every response
-            # below (allow AND deny — a delta that flips a cached
-            # DENY must revoke it too). The flight-recorder tape gets
-            # the grant decision as its own stage (a post-revocation
-            # policy stampede must be attributable).
-            t_grant = time.perf_counter()
-            grant_of = self._grants_for_rows(ns_ids)
-            if observe and self.grants is not None:
-                from istio_tpu.runtime import forensics
-                forensics.RECORDER.stage_mark(
-                    "grant", time.perf_counter() - t_grant)
-            out = []
-            for b, bag in enumerate(bags):
-                resp = CheckResponse()
-                resp.valid_duration_s = min(resp.valid_duration_s,
-                                            float(dur[b]))
-                resp.valid_use_count = min(resp.valid_use_count,
-                                           int(uses[b]))
-                dev_rule = int(deny_rule[b])
-                dev_applied = False
-                host_active = ha[active_sub[b, ha_pos]] if len(ha) else ()
-                pend = host_pending[b] if host_pending is not None else None
-                pi = 0
-                for ridx in host_active:
-                    ridx = int(ridx)
-                    # ties at ridx == dev_rule follow the rule's config
-                    # action order: if its first CHECK action is fused, the
-                    # device result applies before the host actions
-                    if not dev_applied and (
-                            ridx > dev_rule or
-                            (ridx == dev_rule and
-                             dev_rule in plan.fused_first_rules)):
+                                col = lay.slots.get(item)
+                                if col is not None:
+                                    presence[item] = bool(pres_row[col])
+                                else:
+                                    mcol = lay.map_slots.get(item)
+                                    if mcol is not None:
+                                        presence[item] = \
+                                            bool(mp_row[mcol])
+                        shared.append(
+                            (tuple(sorted(referenced, key=str)),
+                             presence))
+                    ref_of = [shared[i] for i in inverse]
+                elif plan.unmapped_instance_attrs:
+                    # no layout items at all, but some rules still
+                    # carry instance attrs — merge them per row from
+                    # the overlaid activity bits (presence is
+                    # unknowable without a layout)
+                    ref_of = []
+                    for b in range(n_real):
+                        referenced: set = set()
+                        for ridx, extra in \
+                                plan.unmapped_instance_attrs.items():
+                            if active_sub[b, col_pos[ridx]]:
+                                referenced |= extra
+                        ref_of.append(
+                            (tuple(sorted(referenced, key=str)), {}))
+            with monitor.stage("respond", on=observe):
+                # decision exemplars: denied/errored rows
+                # reservoir-sample into the telemetry plane (host-side,
+                # post-fold, from the already-decoded verdict) with the
+                # batch's active span so a /debug/rulestats entry links
+                # to its RingReporter trace; the canary recorder shares
+                # the span so its samples join traces
+                tele = plan.telemetry if observe else None
+                tele_span = tr._current() if tele is not None \
+                    or self.recorder is not None else None
+                # server-issued check-cache grants: one (ttl, uses) pair
+                # per distinct namespace, min-folded into every response
+                # below (allow AND deny — a delta that flips a cached
+                # DENY must revoke it too). The flight-recorder tape gets
+                # the grant decision as its own stage (a post-revocation
+                # policy stampede must be attributable).
+                with monitor.span("grant", tap=True, on=observe and
+                                  self.grants is not None):
+                    grant_of = self._grants_for_rows(ns_ids)
+                out = []
+                for b, bag in enumerate(bags):
+                    resp = CheckResponse()
+                    resp.valid_duration_s = min(resp.valid_duration_s,
+                                                float(dur[b]))
+                    resp.valid_use_count = min(resp.valid_use_count,
+                                               int(uses[b]))
+                    dev_rule = int(deny_rule[b])
+                    dev_applied = False
+                    host_active = ha[active_sub[b, ha_pos]] \
+                        if len(ha) else ()
+                    pend = host_pending[b] \
+                        if host_pending is not None else None
+                    pi = 0
+                    for ridx in host_active:
+                        ridx = int(ridx)
+                        # ties at ridx == dev_rule follow the rule's
+                        # config action order: if its first CHECK action
+                        # is fused, the device result applies before the
+                        # host actions
+                        if not dev_applied and (
+                                ridx > dev_rule or
+                                (ridx == dev_rule and
+                                 dev_rule in plan.fused_first_rules)):
+                            self._apply_device_status(
+                                resp, plan, dev_rule, int(status[b]))
+                            dev_applied = True
+                        if pend is not None:
+                            # executor path: CLAIM this rule's
+                            # pre-submitted results (same order the
+                            # submit pass appended them), each wait
+                            # bounded by the batch deadline — an
+                            # unresolved action folds as its fail-policy
+                            # verdict, never a held batch
+                            while pi < len(pend) \
+                                    and pend[pi][0] == ridx:
+                                item = pend[pi][1]
+                                pi += 1
+                                result = item \
+                                    if isinstance(item, CheckResult) \
+                                    else ex.resolve(item, deadline)
+                                self._combine(resp, result)
+                            continue
+                        for hc, template, inst_names in \
+                                plan.host_actions[ridx]:
+                            handler = self._handler_for(hc)
+                            if handler is None:
+                                continue
+                            for iname in inst_names:
+                                ib = snap.instances[iname]
+                                result = self._safe_check(
+                                    handler, template, ib, bag)
+                                self._combine(resp, result)
+                    if not dev_applied:
                         self._apply_device_status(resp, plan, dev_rule,
                                                   int(status[b]))
-                        dev_applied = True
-                    if pend is not None:
-                        # executor path: CLAIM this rule's pre-submitted
-                        # results (same order the submit pass appended
-                        # them), each wait bounded by the batch deadline —
-                        # an unresolved action folds as its fail-policy
-                        # verdict, never a held batch
-                        while pi < len(pend) and pend[pi][0] == ridx:
-                            item = pend[pi][1]
-                            pi += 1
-                            result = item if isinstance(item, CheckResult) \
-                                else ex.resolve(item, deadline)
-                            self._combine(resp, result)
-                        continue
-                    for hc, template, inst_names in plan.host_actions[ridx]:
-                        handler = self._handler_for(hc)
-                        if handler is None:
-                            continue
-                        for iname in inst_names:
-                            ib = snap.instances[iname]
-                            result = self._safe_check(handler, template, ib,
-                                                      bag)
-                            self._combine(resp, result)
-                if not dev_applied:
-                    self._apply_device_status(resp, plan, dev_rule,
-                                              int(status[b]))
-                if status[b] != OK:
-                    resp.deny_rule = dev_rule
-                    if tele is not None:
-                        tele.sample(dev_rule, int(status[b]), bag,
-                                    tele_span)
-                # referenced/presence: precomputed per unique signature
-                if ref_of is not None:
-                    resp.referenced, resp.referenced_presence = ref_of[b]
-                if qa_rules:
-                    resp.active_quota_rules = tuple(
-                        r for r, p in zip(qa_rules, qa_pos)
-                        if active_sub[b, p])
-                    resp.quota_context = self
-                else:
-                    resp.active_quota_rules = ()
-                if grant_of is not None:
-                    g_ttl, g_uses = grant_of[b]
-                    resp.valid_duration_s = min(resp.valid_duration_s,
-                                                g_ttl)
-                    resp.valid_use_count = min(resp.valid_use_count,
-                                               g_uses)
-                out.append(resp)
-            if observe:
-                monitor.observe_stage("respond",
-                                      time.perf_counter() - t_respond)
-                tr.emit("serve.overlay", time.perf_counter() - t_overlay,
-                        batch=len(bags))
+                    if status[b] != OK:
+                        resp.deny_rule = dev_rule
+                        if tele is not None:
+                            tele.sample(dev_rule, int(status[b]), bag,
+                                        tele_span)
+                    # referenced/presence: precomputed per unique
+                    # signature
+                    if ref_of is not None:
+                        resp.referenced, resp.referenced_presence = \
+                            ref_of[b]
+                    if qa_rules:
+                        resp.active_quota_rules = tuple(
+                            r for r, p in zip(qa_rules, qa_pos)
+                            if active_sub[b, p])
+                        resp.quota_context = self
+                    else:
+                        resp.active_quota_rules = ()
+                    if grant_of is not None:
+                        g_ttl, g_uses = grant_of[b]
+                        resp.valid_duration_s = min(
+                            resp.valid_duration_s, g_ttl)
+                        resp.valid_use_count = min(
+                            resp.valid_use_count, g_uses)
+                    out.append(resp)
             if self.recorder is not None:
                 # canary tap: bags/out are already padding-trimmed; one
                 # stride check per batch, bounded appends for sampled rows

@@ -11,11 +11,12 @@ Three legs, all bounded and lock-light:
     tensorize, h2d, device step, fold, grant decision, respond, plus
     per-handler host-action waits and the native front's wire-decode
     wall — into a bounded ring with the active trace id. The tape is
-    THREAD-LOCAL: the batch worker opens it (batch_begin), the
-    existing monitor.observe_stage calls feed it through a registered
-    tap, and the executor's resolve() adds its deadline-bounded host
-    waits, so the serving path pays one thread-local read per stage
-    observation and nothing else. Served at /debug/slow.
+    THREAD-LOCAL: the batch worker opens it (batch_begin), every
+    `with monitor.stage(...)` site feeds it through a registered tap
+    (the native pump's wire-decode wall comes from its `wire_decode`
+    span), and the executor's resolve() adds its deadline-bounded
+    host waits, so the serving path pays one thread-local read per
+    stage observation and nothing else. Served at /debug/slow.
 
   * MESH EVENT TIMELINE (EventTimeline / EVENTS): a timestamped ring
     of control-plane events — config publish generations, canary
@@ -245,7 +246,7 @@ class FlightRecorder:
 
     def stage_mark(self, stage: str, seconds: float) -> None:
         """One stage observation on this thread's open tape (the
-        monitor.observe_stage tap target). No-op off-batch."""
+        monitor.stage / observe_stage tap target). No-op off-batch."""
         tape = getattr(self._local, "tape", None)
         if tape is not None:
             tape.append((stage, seconds))
@@ -384,8 +385,8 @@ class FlightRecorder:
 RECORDER = FlightRecorder()
 EVENTS = EventTimeline()
 
-# feed the existing stage observations into the thread-local tape —
-# the serving path keeps its one observe_stage call per stage
+# feed the stage observations into the thread-local tape — the serving
+# path keeps its one `with monitor.stage(...)` site per stage
 monitor.set_stage_tap(RECORDER.stage_mark)
 
 

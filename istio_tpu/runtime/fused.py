@@ -33,6 +33,7 @@ from istio_tpu.models.policy_engine import (DenySpec, INTERNAL,
                                             ListEntrySpec, PolicyEngine,
                                             OK, PERMISSION_DENIED,
                                             RbacSpec)
+from istio_tpu.runtime import monitor
 from istio_tpu.runtime.config import Snapshot
 from istio_tpu.templates import Variety
 from istio_tpu.utils.log import scope
@@ -242,8 +243,6 @@ class FusedPlan:
         ignore. None = every row is real."""
         import jax
 
-        from istio_tpu.runtime import monitor
-
         batch = self.narrow_batch(batch)   # latency-tier byte plane
         if self._packer is None:
             self._packer = jax.jit(self._base_packer())
@@ -259,41 +258,43 @@ class FusedPlan:
             # fallback never trip the breaker.
             from istio_tpu.runtime.resilience import CHAOS
             CHAOS.device_step()
-        # h2d = host->device staging + async program dispatch;
-        # device_step = the blocking pull (execution + D2H transfer,
-        # carries the transport RTT). Together they decompose the trip
-        # the serve.device span reports as one number. `observe=False`
-        # for non-Check callers (prewarm dummy batches — a compile
-        # would dwarf every real observation — and the fused report
-        # fallback): only check trips feed the Check() decomposition.
-        t0 = time.perf_counter()
-        verdict = self.engine.check(batch, ns_ids)
-        ns_arr = np.asarray(ns_ids)        # hotpath: sync-ok (host ids)
-        if observe and self.telemetry is not None:
-            # per-rule hit/deny/err fold into the resident device
-            # accumulators — async dispatch only, the drain thread
-            # pays the pull. Check traffic only (observe gates out
-            # prewarm dummies and the fused report fallback).
-            b = ns_arr.shape[0]
-            real = np.arange(b) < (b if n_real is None else n_real)
-            self.telemetry.observe(verdict, ns_arr, real)
-        dev = self._packer(verdict, ns_arr)
-        t1 = time.perf_counter()
-        # the single host<->device sync — hotpath: sync-ok
-        out = np.asarray(dev)              # hotpath: sync-ok
-        # this (bucket, width) shape's programs are compiled now —
-        # the swap-warm oracle bridge stops routing it away
-        self._warmed_shapes.add((int(batch.ids.shape[0]),
-                                 int(batch.str_bytes.shape[2])))
-        if observe:
-            monitor.observe_stage("h2d", t1 - t0)
-            monitor.observe_stage("device_step",
-                                  time.perf_counter() - t1)
+        # stage `h2d` = three async program dispatches (engine step,
+        # rule-telemetry fold, packer) with the implicit transfer of
+        # every jit argument, each under its own dispatch.* span;
+        # stage `device_step` = the one blocking pull (waits the
+        # programs out, then D2H). `observe=False` for non-Check
+        # callers (prewarm dummy batches — a compile would dwarf every
+        # real observation — and the fused report fallback): only
+        # check trips feed the Check() decomposition.
+        with monitor.stage("h2d", on=observe):
+            with monitor.span("dispatch.step", on=observe):
+                verdict = self.engine.check(batch, ns_ids)
+            ns_arr = np.asarray(ns_ids)    # hotpath: sync-ok (host ids)
+            if observe and self.telemetry is not None:
+                # per-rule hit/deny/err fold into the resident device
+                # accumulators — async dispatch only, the drain thread
+                # pays the pull. Check traffic only (observe gates out
+                # prewarm dummies and the fused report fallback).
+                with monitor.span("dispatch.rulestats"):
+                    b = ns_arr.shape[0]
+                    real = np.arange(b) < (b if n_real is None
+                                           else n_real)
+                    self.telemetry.observe(verdict, ns_arr, real)
+            with monitor.span("dispatch.pack", on=observe):
+                dev = self._packer(verdict, ns_arr)
+        with monitor.stage("device_step", on=observe):
+            # the single host<->device sync — hotpath: sync-ok
+            out = np.asarray(dev)              # hotpath: sync-ok
+            # this (bucket, width) shape's programs are compiled now —
+            # the swap-warm oracle bridge stops routing it away
+            self._warmed_shapes.add((int(batch.ids.shape[0]),
+                                     int(batch.str_bytes.shape[2])))
         return out
 
     def _base_packer(self):
         """The pack(verdict, req_ns) closure shared by packed_check and
         packed_report (which appends report-field planes)."""
+        import jax
         import jax.numpy as jnp
         from jax import lax
 
@@ -360,7 +361,8 @@ class FusedPlan:
             return jnp.concatenate(parts, axis=0) \
                 if len(parts) > 1 else head
 
-        return pack
+        # metadata only: the profiler's device plane names the scope
+        return jax.named_scope("pack")(pack)
 
     def packed_report(self, batch, ns_ids,
                       observe: bool = True) -> np.ndarray:
@@ -499,24 +501,32 @@ class FusedPlan:
                 return jnp.concatenate([head, extra], axis=0), new_cnt
 
             self._instep_packer = jax.jit(packq)
-        verdict = self.engine.check(batch, ns_ids)
+        # the caller (Dispatcher._check_fused) holds stage `h2d` over
+        # this whole call; the dispatch.* spans split it as in
+        # packed_check. Prewarm dummies (n_real=0) observe nothing.
+        served = n_real is None or n_real > 0
+        with monitor.span("dispatch.step", on=served):
+            verdict = self.engine.check(batch, ns_ids)
         ns_arr = np.asarray(ns_ids)        # hotpath: sync-ok (host ids)
         if self.telemetry is not None:
             # in-step quota batches ARE check traffic — same per-rule
             # fold as packed_check (prewarm_instep passes n_real=0 so
             # its dummy trips fold all-masked, counting nothing)
-            b = ns_arr.shape[0]
-            real = np.arange(b) < (b if n_real is None else n_real)
-            self.telemetry.observe(verdict, ns_arr, real)
+            with monitor.span("dispatch.rulestats", on=served):
+                b = ns_arr.shape[0]
+                real = np.arange(b) < (b if n_real is None else n_real)
+                self.telemetry.observe(verdict, ns_arr, real)
         # DEVICE handles, not host arrays: the caller swaps the pool
         # onto new_counts at dispatch (the next trip chains on-device)
         # and pulls `packed` with the counter token already released
-        out = self._instep_packer(
-            verdict,
-            ns_arr,
-            counts,
-            q["buckets"], q["amounts"], q["be"], q["mx"], q["active"],
-            q["ticks"], q["lasts"], q["rolling"], q["rule_idx"])
+        with monitor.span("dispatch.pack", on=served):
+            out = self._instep_packer(
+                verdict,
+                ns_arr,
+                counts,
+                q["buckets"], q["amounts"], q["be"], q["mx"],
+                q["active"], q["ticks"], q["lasts"], q["rolling"],
+                q["rule_idx"])
         self._warmed_shapes.add((int(batch.ids.shape[0]),
                                  int(batch.str_bytes.shape[2])))
         return out

@@ -20,6 +20,7 @@ import time
 import prometheus_client
 
 from istio_tpu.utils import metrics as hostmetrics
+from istio_tpu.utils import tracing
 
 REGISTRY = prometheus_client.CollectorRegistry()
 
@@ -356,17 +357,26 @@ def identity_counters() -> dict:
 #
 # Stage semantics (one observation per BATCH per stage; e2e is one
 # observation per REQUEST, so sum-of-stage-sums <= sum-of-e2e holds
-# whenever batches carry >= 1 request):
+# whenever batches carry >= 1 request). Every stage is one
+# `with monitor.stage(name):` site:
 #   queue_wait  — oldest enqueue -> batch start (batcher) or entry ->
-#                 dispatch (pre-batched check_many fronts)
-#   tensorize   — bags -> AttributeBatch (+ns ids), host side
-#   h2d         — host->device staging + program dispatch (the
-#                 non-blocking half of the device call)
-#   device_step — the blocking device->host pull (program execution +
-#                 transfer; carries the full transport RTT)
-#   fold        — packed-plane decode: overlay bits, referenced /
-#                 presence signature dedup
-#   respond     — per-row CheckResponse construction
+#                 dispatch (check_many). The native front has no such
+#                 stage: its C++ queue wait is NativeMixerServer.
+#                 queue_wait(), its blocked pump the `take_wait` span
+#   tensorize   — wire bytes / bags -> AttributeBatch, host side, AND
+#                 (native wire path, overlap_h2d) the one explicit
+#                 device_put of the byte plane, AND the ns ids; split
+#                 by the spans tensorize.decode / .stage_put / .ns_ids
+#   h2d         — misnamed, kept for its readers: three program
+#                 DISPATCHES (engine step, rule-telemetry fold, packer)
+#                 with the implicit transfer of every jit argument;
+#                 split by the spans dispatch.step / .rulestats / .pack
+#   device_step — the blocking device->host pull of the packed verdict
+#                 (waits out the programs dispatched in `h2d`, then
+#                 the D2H copy; ~0.9 ms of sync on a local chip)
+#   fold        — packed-plane decode: overlay bits, host-action
+#                 submits, referenced / presence signature dedup
+#   respond     — per-row CheckResponse construction (grants included)
 CHECK_STAGES = ("queue_wait", "tensorize", "h2d", "device_step",
                 "fold", "respond")
 CHECK_P99_TARGET_MS = 1.0   # BASELINE north star: <1ms p99 at 10k rules
@@ -391,6 +401,23 @@ CHECK_SLO_GAUGE = hostmetrics.default_registry.gauge(
     f"empty — mask alerts on mixer_check_e2e_seconds_count), else 0")
 
 
+# Everything else on the served path that is timed but is NOT one of
+# the six stages lands here (label: span) — the native pump's cycle
+# (pump_cycle = take_wait + wire_decode + the stages + serialize +
+# send), the sub-spans of `tensorize` and `h2d`, and — only while a
+# zipkin reporter is configured (zipkin_on) — the tracer's grouping
+# spans (device = h2d + device_step, overlay = fold + respond).
+# Surfaced by latency_snapshot()["spans"], never under "stages".
+PUMP_SPAN_SECONDS = hostmetrics.default_registry.histogram(
+    "mixer_pump_span_seconds",
+    "served-path host spans outside the six check stages "
+    "(label: span)")
+GC_PAUSE_SECONDS = hostmetrics.default_registry.histogram(
+    "mixer_gc_pause_seconds",
+    "stop-the-world wall of full (generation 2) garbage collections "
+    "of the serving process")
+
+
 # forensics stage tap (runtime/forensics.py registers the flight
 # recorder's thread-local tape here at import): every check stage
 # observation ALSO lands on the open batch tape, so the recorder needs
@@ -404,9 +431,185 @@ def set_stage_tap(fn) -> None:
 
 
 def observe_stage(stage: str, seconds: float) -> None:
+    """An already-measured stage wall (the batcher's queue_wait is an
+    enqueue -> batch-start difference, not a `with` block). Code that
+    runs the stage uses stage() below."""
     CHECK_STAGE_SECONDS.observe(seconds, stage=stage)
     if _STAGE_TAP is not None:
         _STAGE_TAP(stage, seconds)
+
+
+# -- the one span API of the served Check path -------------------------
+#
+# `with monitor.stage(name):` / `with monitor.span(name):` does three
+# things over ONE interval: (1) observes the wall into a histogram
+# (stage -> mixer_check_stage_seconds, span -> mixer_pump_span_seconds;
+# always on: a perf_counter pair and one observe); (2) holds a
+# jax.profiler.TraceAnnotation "mixer/<name>", so that while a profiler
+# session runs (/debug/profile, a benchmark's traced window) the span
+# lies on the host plane of the same xplane, on the same clock, as the
+# device's `XLA Modules` line — with no session it is a TraceMe that
+# checks one flag; (3) feeds what hangs on the timers: the forensics
+# stage tap and, only when a reporter is configured, the zipkin tracer
+# (names in _ZIPKIN_NAMES, so /debug/traces keeps the three spans it
+# had). A block that raises closes its annotation and observes
+# nothing: failed batches stay out of the decomposition by design
+# (mixer_check_batch_failures_total is their trace).
+
+_ZIPKIN_NAMES = {"tensorize": "serve.tensorize",
+                 "device": "serve.device",
+                 "overlay": "serve.overlay"}
+_ANNOTATION_PREFIX = "mixer/"
+
+
+def _trace_annotation(name: str):
+    """First use: bind the profiler's TraceMe class (jax stays a lazy
+    import of this module); a rig without it gets a null context."""
+    global _trace_annotation
+    try:
+        from jax.profiler import TraceAnnotation
+        _trace_annotation = TraceAnnotation
+    except Exception:   # no profiler: spans still time and observe
+        _trace_annotation = lambda _name: _OFF   # noqa: E731
+    return _trace_annotation(name)
+
+
+class _Off:
+    """The disabled span (`on=False`): one shared, stateless object."""
+    __slots__ = ()
+    seconds = 0.0
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_OFF = _Off()
+_SPAN_META: dict = {}
+
+
+class _Span:
+    __slots__ = ("_meta", "_tags", "_ann", "_t0", "seconds")
+
+    def __init__(self, meta: tuple, tags: dict):
+        self._meta = meta
+        self._tags = tags
+        self.seconds = 0.0
+
+    def __enter__(self):
+        self._ann = _trace_annotation(self._meta[2])
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        seconds = self.seconds = time.perf_counter() - self._t0
+        self._ann.__exit__(exc_type, exc, tb)
+        hist, key, _, name, tap, zipkin = self._meta
+        if exc_type is None:
+            hist.observe_key(key, seconds)
+            if tap and _STAGE_TAP is not None:
+                _STAGE_TAP(name, seconds)
+        if zipkin is not None:
+            tracer = tracing.get_tracer()
+            if tracer.reporter is not None:
+                if exc_type is not None:
+                    self._tags["error"] = str(exc)
+                tracer.emit(zipkin, seconds, **self._tags)
+        return False
+
+
+def _span(hist, label: str, name: str, tap: bool, tags: dict) -> _Span:
+    meta = _SPAN_META.get((label, name, tap))
+    if meta is None:
+        meta = _SPAN_META[(label, name, tap)] = (
+            hist, ((label, name),), _ANNOTATION_PREFIX + name, name,
+            tap, _ZIPKIN_NAMES.get(name))
+    return _Span(meta, tags)
+
+
+def zipkin_on() -> bool:
+    """Whether a zipkin reporter is configured: the condition for a
+    span that only the tracer reads (serve.device, serve.overlay)."""
+    return tracing.get_tracer().reporter is not None
+
+
+def stage(name: str, on: bool = True, **tags):
+    """One of the six CHECK_STAGES, as a context manager (see above).
+    `on=False` (prewarm dummies, report/replay callers of shared code)
+    times and observes nothing. `tags` ride the zipkin span only."""
+    if not on:
+        return _OFF
+    return _span(CHECK_STAGE_SECONDS, "stage", name, True, tags)
+
+
+def span(name: str, on: bool = True, tap: bool = False, **tags):
+    """A served-path span that is not a stage: histogram
+    mixer_pump_span_seconds{span}, latency_snapshot()["spans"].
+    `.seconds` holds the wall after the block. `tap`: also mark the
+    flight recorder's open batch tape (the `grant` decision)."""
+    if not on:
+        return _OFF
+    return _span(PUMP_SPAN_SECONDS, "span", name, tap, tags)
+
+
+# -- full garbage collections of the serving process -------------------
+#
+# A generation-2 collection stops every thread for as long as it walks
+# the heap (0.3 s at a 10k-rule snapshot's ~800k objects): both pumps
+# and every blocked request wait it out. The hook is installed by each
+# RuntimeServer and removed at its close (refcounted: tests run
+# several servers in one process).
+
+_GC_LOCK = threading.Lock()
+_GC_USERS = 0
+_GC_OPEN: list = []     # [(annotation, t0)] of the collection running
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    if info["generation"] != 2:
+        return
+    if phase == "start":
+        ann = _trace_annotation(_ANNOTATION_PREFIX + "gc")
+        ann.__enter__()
+        _GC_OPEN.append((ann, time.perf_counter()))
+    elif _GC_OPEN:
+        ann, t0 = _GC_OPEN.pop()
+        GC_PAUSE_SECONDS.observe(time.perf_counter() - t0)
+        ann.__exit__(None, None, None)
+
+
+def install_gc_hook() -> None:
+    import gc
+
+    global _GC_USERS
+    with _GC_LOCK:
+        _GC_USERS += 1
+        if _GC_USERS == 1:
+            gc.callbacks.append(_on_gc)
+
+
+def remove_gc_hook() -> None:
+    import gc
+
+    global _GC_USERS
+    with _GC_LOCK:
+        if not _GC_USERS:
+            return
+        _GC_USERS -= 1
+        if not _GC_USERS and _on_gc in gc.callbacks:
+            gc.callbacks.remove(_on_gc)
+
+
+def gc_pause_snapshot(since: dict | None = None) -> dict:
+    """Full collections seen by the hook: {"count", "sum_s"}, or the
+    delta against an earlier snapshot `since`."""
+    _, total, n = GC_PAUSE_SECONDS.state()
+    if since is not None:
+        total, n = total - since["sum_s"], n - since["count"]
+    return {"count": n, "sum_s": total}
 
 
 def observe_check_e2e(seconds: float) -> None:
@@ -455,6 +658,9 @@ def stage_baseline() -> dict:
     token = {stage: CHECK_STAGE_SECONDS.state(stage=stage)
              for stage in CHECK_STAGES}
     token["__e2e__"] = CHECK_E2E_SECONDS.state()
+    token["__spans__"] = {
+        labels["span"]: PUMP_SPAN_SECONDS.state(**labels)
+        for labels in PUMP_SPAN_SECONDS.label_sets()}
     return token
 
 
@@ -493,11 +699,20 @@ def latency_snapshot(since: dict | None = None) -> dict:
             "p99_ms": round(quantile_from_counts(
                 h.buckets, counts, n, 0.99) * 1e3, 3),
         }
+    spans: dict[str, dict] = {}
+    span_base = since.get("__spans__", {}) if since is not None else {}
+    for labels in PUMP_SPAN_SECONDS.label_sets():
+        name = labels["span"]
+        _, total, n = _delta(PUMP_SPAN_SECONDS.state(**labels),
+                             span_base.get(name, empty))
+        if n:
+            spans[name] = {"count": n, "sum_ms": total * 1e3}
     e2e = CHECK_E2E_SECONDS.state()
     if since is not None:
         e2e = _delta(e2e, since.get("__e2e__", empty))
     return {
         "stages": stages,
+        "spans": spans,
         "e2e_count": e2e[2],
         "e2e_sum_ms": round(e2e[1] * 1e3, 3),
         "live": refresh_latency_gauges(),

@@ -164,6 +164,7 @@ class RuleTelemetry:
     @staticmethod
     def _make_delta(rule_ns: np.ndarray, default_ns: int, n_slots: int,
                     err_rows: np.ndarray):
+        import jax
         import jax.numpy as jnp
         from jax import lax
 
@@ -194,7 +195,8 @@ class RuleTelemetry:
                             axis=0)
             return hit, deny, err_d
 
-        return delta
+        # metadata only: the profiler's device plane names the scope
+        return jax.named_scope("rulestats")(delta)
 
     # ------------------------------------------------------------------
     # hot path (scripts/hotpath_lint.py HOT_SECTIONS cover these)

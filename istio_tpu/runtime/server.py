@@ -544,6 +544,12 @@ class RuntimeServer:
                 explain_window_s=self.args.audit_explain_window_s,
                 quota_every=self.args.audit_quota_every)
             self.audit.start()
+        # full garbage collections stop every pump: time them for as
+        # long as this server serves (shutdown() removes the hook).
+        # Last in the constructor: a server that failed to build has
+        # no close() to undo it.
+        from istio_tpu.runtime import monitor as _monitor
+        _monitor.install_gc_hook()
 
     # -- API surface (grpcServer.go Check/Report semantics) --
     # Preprocessing (the APA phase) happens exactly ONCE per request, in
@@ -1088,9 +1094,9 @@ class RuntimeServer:
         from istio_tpu.runtime import monitor as _monitor
 
         t0 = _time.perf_counter()
-        forensics.RECORDER.batch_begin()
-        pre = [self.preprocess(b) for b in bags]
-        _monitor.observe_stage("queue_wait", _time.perf_counter() - t0)
+        with _monitor.stage("queue_wait"):
+            forensics.RECORDER.batch_begin()
+            pre = [self.preprocess(b) for b in bags]
         out = list(self._run_check_batch(pre))
         e2e = _time.perf_counter() - t0
         for _ in bags:
@@ -1396,14 +1402,10 @@ class RuntimeServer:
         # pumps' host work AND their trips overlap on the transport
         # (measured: a token held across the pull made in-step SLOWER
         # than two serialized trips)
-        import time as _time
-
         from istio_tpu.runtime import monitor as _monitor
 
-        t_tz = _time.perf_counter()
-        pre = d._tensorize_for_device(bags)
-        _monitor.observe_stage("tensorize",
-                               _time.perf_counter() - t_tz)
+        with _monitor.stage("tensorize", batch=len(bags)):
+            pre = d._tensorize_for_device(bags)
         sess = pool.inline_begin(len(bags), rows,
                                  pool._clock()) if rows else None
         if sess is None:
@@ -1462,8 +1464,10 @@ class RuntimeServer:
             return
         self._shutdown_done = True
         from istio_tpu.runtime import forensics
+        from istio_tpu.runtime import monitor as _monitor
         forensics.record_event("shutdown",
                                deadline_s=deadline)
+        _monitor.remove_gc_hook()
         # flip every background-warm stop flag FIRST (flag-only, no
         # joins): bank prewarms poll _stopping between shapes, and
         # begin_close() stops the controller admitting new rebuilds

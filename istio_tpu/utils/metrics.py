@@ -102,7 +102,12 @@ class Histogram:
         self._lock = threading.Lock()
 
     def observe(self, value: float, **labels: str) -> None:
-        key = _label_key(labels)
+        self.observe_key(_label_key(labels), value)
+
+    def observe_key(self, key: tuple, value: float) -> None:
+        """observe() for a caller that keeps its label key (the
+        sorted (name, value) pairs) — per-batch span sites must not
+        rebuild and sort it on every observation."""
         idx = bisect.bisect_left(self._buckets, value)
         with self._lock:
             if key not in self._counts:
