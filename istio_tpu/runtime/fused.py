@@ -98,6 +98,30 @@ def unpack_word_rows(rows: np.ndarray, n_bits: int) -> np.ndarray:
         bitorder="little")[:, :n_bits].astype(bool)
 
 
+def dedup_bit_rows(planes) -> tuple[np.ndarray, np.ndarray]:
+    """Exact dedup of the rows of bool planes [B, w_i] laid side by
+    side (any w_i may be 0) → (first [U]: one representative row per
+    distinct row, inverse [B]: each row's class, so that row b equals
+    row first[inverse[b]] in every plane). Rows are bit-packed into
+    uint64 words, ceil(Σw / 64) a row, and sorted as integers: the key
+    is an injective image of the row, nothing is hashed."""
+    n = planes[0].shape[0]
+    width = sum(p.shape[1] for p in planes)
+    bits = np.zeros((n, max(64, -(-width // 64) * 64)), bool)
+    at = 0
+    for p in planes:
+        bits[:, at:at + p.shape[1]] = p
+        at += p.shape[1]
+    words = np.packbits(bits, axis=1).view(np.uint64)
+    order = np.lexsort(words.T)
+    ranked = words[order]
+    new = np.ones(n, bool)
+    new[1:] = (ranked[1:] != ranked[:-1]).any(axis=1)
+    inverse = np.empty(n, np.intp)
+    inverse[order] = np.cumsum(new) - 1
+    return order[new], inverse
+
+
 @dataclasses.dataclass
 class FusedPlan:
     """Per-snapshot serving plan: device engine + host overlay map."""

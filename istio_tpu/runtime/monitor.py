@@ -376,6 +376,7 @@ def identity_counters() -> dict:
 #                 the D2H copy; ~0.9 ms of sync on a local chip)
 #   fold        — packed-plane decode: overlay bits, host-action
 #                 submits, referenced / presence signature dedup
+#                 (the span fold.signature)
 #   respond     — per-row CheckResponse construction (grants included)
 CHECK_STAGES = ("queue_wait", "tensorize", "h2d", "device_step",
                 "fold", "respond")
@@ -451,14 +452,16 @@ def observe_stage(stage: str, seconds: float) -> None:
 # device's `XLA Modules` line — with no session it is a TraceMe that
 # checks one flag; (3) feeds what hangs on the timers: the forensics
 # stage tap and, only when a reporter is configured, the zipkin tracer
-# (names in _ZIPKIN_NAMES, so /debug/traces keeps the three spans it
-# had). A block that raises closes its annotation and observes
-# nothing: failed batches stay out of the decomposition by design
+# (names in _ZIPKIN_NAMES: the three spans /debug/traces had, and
+# fold.signature, whose `distinct` tag only the tracer carries). A
+# block that raises closes its annotation and observes nothing:
+# failed batches stay out of the decomposition by design
 # (mixer_check_batch_failures_total is their trace).
 
 _ZIPKIN_NAMES = {"tensorize": "serve.tensorize",
                  "device": "serve.device",
-                 "overlay": "serve.overlay"}
+                 "overlay": "serve.overlay",
+                 "fold.signature": "serve.fold.signature"}
 _ANNOTATION_PREFIX = "mixer/"
 
 
@@ -485,6 +488,9 @@ class _Off:
     def __exit__(self, *exc) -> bool:
         return False
 
+    def tag(self, **tags) -> None:
+        pass
+
 
 _OFF = _Off()
 _SPAN_META: dict = {}
@@ -503,6 +509,10 @@ class _Span:
         self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
+
+    def tag(self, **tags) -> None:
+        """Tags the block itself computes (a count known at its end)."""
+        self._tags.update(tags)
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         seconds = self.seconds = time.perf_counter() - self._t0

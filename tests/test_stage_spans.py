@@ -238,7 +238,8 @@ def test_served_burst_tiles_the_pump_cycle(front):
     for parts, whole in ((("tensorize.decode", "tensorize.ns_ids"),
                           stages["tensorize"]),
                          (("dispatch.step", "dispatch.rulestats",
-                           "dispatch.pack"), stages["h2d"])):
+                           "dispatch.pack"), stages["h2d"]),
+                         (("fold.signature",), stages["fold"])):
         assert all(spans[p]["count"] == cycles for p in parts), spans
         assert sum(spans[p]["sum_ms"] for p in parts) <= \
             whole["sum_ms"] + 1e-3
@@ -260,6 +261,12 @@ def test_zipkin_groups_exist_only_under_a_reporter(front, taps):
         stages["fold"]["sum_ms"] + stages["respond"]["sum_ms"] - 1e-3
     assert {"serve.tensorize", "serve.device", "serve.overlay"} <= \
         {s["name"] for s in mem.spans}
+    # the dedup's span says how many (referenced, presence) objects
+    # its batch shares
+    keyed = [s["tags"] for s in mem.spans
+             if s["name"] == "serve.fold.signature"]
+    assert keyed and all(1 <= int(t["distinct"]) <= int(t["batch"])
+                         for t in keyed), keyed
 
 
 def test_queue_wait_counts_every_row_handed_to_a_pump(front):
