@@ -242,9 +242,11 @@ def model_check_step(engine, batch: int, plan: Any = None,
             bytes=_param_nbytes(rs.params, "lit_idx")
             + b * n_legacy * l_l + b * n_legacy,
             vec_ops=b * n_legacy * l_l))
-        if g.get("n_dfa_atoms", 0) or g.get("n_gen_atoms", 0):
+        if g.get("n_dfa_atoms", 0) or g.get("n_prefix_atoms", 0) \
+                or g.get("n_gen_atoms", 0):
             notes.append(
                 f"{g.get('n_dfa_atoms', 0)} dfa-group + "
+                f"{g.get('n_prefix_atoms', 0)} prefix-group + "
                 f"{g.get('n_gen_atoms', 0)} generic tensor atoms are "
                 "not sized (compiled closures); model understates")
 

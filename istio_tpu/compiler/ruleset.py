@@ -583,6 +583,9 @@ def compile_ruleset(rules: Sequence[Rule], finder: AttributeDescriptorFinder,
         # multi-DFA scan per subject instead of one scan per atom
         # (tensor_expr.compile_dfa_group)
         dfa_groups: dict[str, dict] = {}
+        # constant-prefix startsWith atoms grouped by subject likewise
+        # (tensor_expr.compile_prefix_group)
+        prefix_groups: dict[str, dict] = {}
         gen_fns: list[Callable] = []
         gen_atom_idx: list[int] = []
         unlowerable: set[int] = set()
@@ -638,6 +641,25 @@ def compile_ruleset(rules: Sequence[Rule], finder: AttributeDescriptorFinder,
                     g["patterns"].append(pattern)
                     g["dfas"].append(dfa)
                     done = True
+            if not done and f is not None and f.name == "startsWith" \
+                    and f.target is not None \
+                    and f.args[0].const_ is not None:
+                prefix = f.args[0].const_.value
+                try:
+                    # probe as above; a prefix past the byte-slot cap
+                    # is the generic path's HostFallback
+                    tensor_expr._compile_bytes(f.target, ctx)
+                    fits = len(prefix.encode("utf-8")) \
+                        <= layout.max_str_len
+                except Exception:
+                    fits = False
+                if fits:
+                    g = prefix_groups.setdefault(
+                        str(f.target), {"subject": f.target,
+                                        "atoms": [], "prefixes": []})
+                    g["atoms"].append(aidx)
+                    g["prefixes"].append(prefix)
+                    done = True
             if not done:
                 try:
                     gen_fns.append(tensor_expr._compile_node(ast, ctx))
@@ -661,6 +683,12 @@ def compile_ruleset(rules: Sequence[Rule], finder: AttributeDescriptorFinder,
         g["subject"], g["patterns"], g["dfas"], ctx)
         for g in dfa_groups.values()]
     dfa_atom_idx = [a for g in dfa_groups.values() for a in g["atoms"]]
+    # the prefix groups ride behind the dfa groups: both return
+    # (val [B, k], ee [B, k]) and run() treats them alike
+    dfa_group_fns += [tensor_expr.compile_prefix_group(
+        g["subject"], g["prefixes"], ctx) for g in prefix_groups.values()]
+    prefix_atom_idx = [a for g in prefix_groups.values()
+                       for a in g["atoms"]]
 
     n_atoms = len(atoms.asts)
     ss_a_a = np.asarray(ss_a, np.int32)
@@ -765,7 +793,8 @@ def compile_ruleset(rules: Sequence[Rule], finder: AttributeDescriptorFinder,
     eq_keep = [i for i, aidx in enumerate(eq_atom_idx)
                if aidx in legacy_atom_set]
     eq_live_idx = [eq_atom_idx[i] for i in eq_keep]
-    order = eq_live_idx + ss_atom_idx + dfa_atom_idx + gen_atom_idx
+    order = eq_live_idx + ss_atom_idx + dfa_atom_idx \
+        + prefix_atom_idx + gen_atom_idx
     n_live = max(len(order), 1)   # width of the m/n literal blocks
     # inverse permutation: position of atom i in the concatenated output
     pos_of = np.full(max(n_atoms, 1), 0, dtype=np.int32)
@@ -910,6 +939,7 @@ def compile_ruleset(rules: Sequence[Rule], finder: AttributeDescriptorFinder,
     atom_tier = {aidx: "id-eq" for aidx in eq_atom_idx}
     atom_tier.update({aidx: "slot-eq" for aidx in ss_atom_idx})
     atom_tier.update({aidx: "dfa-pack" for aidx in dfa_atom_idx})
+    atom_tier.update({aidx: "prefix-pack" for aidx in prefix_atom_idx})
     atom_tier.update({aidx: "tensor" for aidx in gen_atom_idx})
 
     geometry = {
@@ -920,8 +950,10 @@ def compile_ruleset(rules: Sequence[Rule], finder: AttributeDescriptorFinder,
         "n_eq_atoms_total": len(eq_atom_idx),
         "n_ss_atoms": len(ss_atom_idx),
         "n_dfa_atoms": len(dfa_atom_idx),
+        "n_prefix_atoms": len(prefix_atom_idx),
         "n_gen_atoms": len(gen_atom_idx),
-        "n_dfa_groups": len(dfa_group_fns),
+        "n_dfa_groups": len(dfa_groups),
+        "n_prefix_groups": len(prefix_groups),
         "n_live": n_live,
         "n_conjs": n_conjs,
         "n_fused_conjs": n_fused,

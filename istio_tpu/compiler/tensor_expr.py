@@ -621,6 +621,32 @@ def compile_dfa_group(subject_ast: Expression, patterns: list[str],
     return fn
 
 
+def compile_prefix_group(subject_ast: Expression, prefixes: list[str],
+                         ctx: "_Ctx") -> Callable:
+    """ALL constant-prefix `startsWith` atoms over ONE subject, in one
+    compare per byte position (ops/bytes_ops.prefix_match_many).
+
+    A mesh's ServiceRoles name their services by prefix (`svc7.*`), so
+    a snapshot holds a distinct constant per role over one subject:
+    1 000 atoms each traced, lowered and compiled as its own slice,
+    compare and reduction were 59 000 StableHLO lines a step shape and
+    most of a five-minute cold start (PERF.md §6, PR 29).
+
+    Returns fn(batch) → (val [B, k], ee [B, k]) with exactly
+    _compile_byte_pred's semantics per column: subject absence/error
+    masks the row; a prefix check reads the head only, so truncation
+    never makes it undecidable."""
+    fsub = _compile_bytes(subject_ast, ctx)
+    raw = [p.encode("utf-8") for p in prefixes]
+
+    def fn(batch: AttributeBatch):
+        s = fsub(batch)
+        m = bytes_ops.prefix_match_many(s.data, s.lens, raw)
+        ee = (s.err | ~s.ok)[:, None] & jnp.ones_like(m)
+        return m & ~ee, ee
+    return fn
+
+
 def _compile_dyn_byte_pred(f: FunctionCall, ctx: _Ctx) -> NodeFn:
     """Byte predicates whose PATTERN is itself a runtime string
     (`as.startsWith(as2)`, `match(as, as2)`): both operands ride byte

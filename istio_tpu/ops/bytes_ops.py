@@ -36,6 +36,27 @@ def prefix_match(data: jnp.ndarray, lens: jnp.ndarray,
     return eq & (lens >= k)
 
 
+def prefix_match_many(data: jnp.ndarray, lens: jnp.ndarray,
+                      prefixes: list[bytes]) -> jnp.ndarray:
+    """startsWith for k constants at once: [B, L] → bool [B, k]; column
+    i is prefix_match(data, lens, prefixes[i]). One [B, k] compare per
+    byte position of the longest prefix, where k prefix_match calls are
+    k slices, compares and reductions to trace, lower and compile."""
+    plen = np.array([len(p) for p in prefixes], np.int32)
+    width = min(int(plen.max(initial=0)), data.shape[1])
+    table = np.zeros((len(prefixes), width), np.uint8)
+    for i, p in enumerate(prefixes):
+        head = np.frombuffer(p[:width], dtype=np.uint8)
+        table[i, :len(head)] = head
+    # a prefix wider than the plane matches no row (prefix_match)
+    hit = (lens[:, None] >= plen[None, :]) \
+        & jnp.asarray(plen <= data.shape[1])[None, :]
+    for j in range(width):
+        same = data[:, j, None] == table[None, :, j]
+        hit = hit & (same | jnp.asarray(plen <= j)[None, :])
+    return hit
+
+
 def suffix_match(data: jnp.ndarray, lens: jnp.ndarray,
                  suffix: bytes) -> jnp.ndarray:
     """endsWith(const): compare a window ending at each row's length."""

@@ -562,6 +562,10 @@ class Dispatcher:
                                     np.int64)
                 qa_rules = sorted({qa[0] for qa in plan.quota_actions})
                 qa_pos = [col_pos[r] for r in qa_rules]
+                if observe:
+                    monitor.note_check_decided(plan.rows_by_section(
+                        deny_rule[:n_real],
+                        active_sub[:, ha_pos].any(axis=1)))
 
                 # adapter-executor plane (runtime/executor.py): submit
                 # every host action NOW, so adapter calls run on their
@@ -626,6 +630,8 @@ class Dispatcher:
                             (ref_bits, present_np, map_present_np,
                              active_sub))
                         keyed.tag(distinct=len(first))
+                    if observe:
+                        monitor.FOLD_SIGNATURE_CLASSES.inc(len(first))
                     names = plan.item_names
                     shared: list[tuple[tuple, dict]] = []
                     for b in first:

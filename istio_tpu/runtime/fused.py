@@ -49,6 +49,9 @@ _FUSABLE_LIST_TYPES = ("STRINGS", "REGEX", "IP_ADDRESSES")
 STR_TIER_MIN = 32
 
 
+_BY = {name: i for i, name in enumerate(monitor.CHECK_DECIDED_BY)}
+
+
 def str_tiers(layout, interner=None) -> tuple[int, ...]:
     """Byte-plane length tiers for a snapshot: (STR_TIER_MIN, L) when
     the layout carries real byte slots wider than the small tier, else
@@ -138,6 +141,12 @@ class FusedPlan:
     # rules whose rbac action is fused (device pseudo-rule NFA,
     # compiler/rbac_lower.py) — for status messages + diagnostics
     rbac_rules: frozenset = frozenset()
+    # rule idx → index into monitor.CHECK_DECIDED_BY of the section
+    # whose verdict a deny_rule of that index is (a rule with several
+    # fused actions: the step's own tie order, deny → list → rbac); one
+    # entry more than rule rows, `ok`, for the rows no rule denied
+    decided_section: np.ndarray = dataclasses.field(
+        default_factory=lambda: np.zeros(1, np.int8))
     # why list actions stayed host-side, e.g. "CASE_INSENSITIVE_STRINGS",
     # "provider-refreshed", "REGEX:unsupported-pattern" (bench
     # enumeration of the unfusable envelope)
@@ -775,6 +784,18 @@ class FusedPlan:
         # only a COMPLETED warm counts (not stopped)
         self._instep_warmed.add(key)
 
+    def rows_by_section(self, deny_rule: np.ndarray,
+                        host_active: np.ndarray) -> np.ndarray:
+        """One batch's rows per monitor.CHECK_DECIDED_BY entry, from
+        the pulled deny_rule plane [B] and whether a host-overlay
+        action was active on each row [B]. A row the device left OK
+        carries INT32_MAX, which reads the lookup's last entry: `ok`,
+        or `host` when host adapters then had the word."""
+        by = self.decided_section[np.minimum(
+            deny_rule, len(self.decided_section) - 1)]
+        by = np.where((by == _BY["ok"]) & host_active, _BY["host"], by)
+        return np.bincount(by, minlength=len(_BY))
+
     def message_for(self, rule_idx: int, status: int) -> str:
         """Best-effort status message for a device-produced denial."""
         info = self.deny_info.get(rule_idx)
@@ -1008,8 +1029,15 @@ def build_fused_plan(snapshot: Snapshot,
         except Exception:
             log.exception("rule telemetry unavailable; serving "
                           "without per-rule accumulators")
+    decided_section = np.full(n_rows + 1, _BY["deny"], np.int8)
+    decided_section[-1] = _BY["ok"]
+    for section, members in (("rbac", rbac_rules), ("list", list_rules),
+                             ("deny", deny_info)):
+        decided_section[np.asarray(sorted(members), np.intp)] = \
+            _BY[section]
     return FusedPlan(engine=engine, native=native,
                      telemetry=telemetry,
+                     decided_section=decided_section,
                      # AFTER every compile above (engine, report
                      # lowering): the interner's constant-length max
                      # is grow-only and now complete for this snapshot

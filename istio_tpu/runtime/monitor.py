@@ -134,6 +134,42 @@ def resilience_counters() -> dict:
     }
 
 
+# -- what decided each served verdict (Dispatcher._fold_respond, once a
+# batch, from the pulled numpy planes). `by`: the section of the device
+# step whose rule gave the row its status (deny / list / rbac; ok when
+# none did), or host when the device left the row OK and a
+# host-overlay action was active on it, so host adapters had the word.
+CHECK_DECIDED_BY = ("ok", "deny", "list", "rbac", "host")
+CHECK_DECIDED = prometheus_client.Counter(
+    "mixer_check_decided_total",
+    "served check rows by the section that decided the verdict",
+    ["by"], registry=REGISTRY)
+FOLD_SIGNATURE_CLASSES = prometheus_client.Counter(
+    "mixer_fold_signature_classes_total",
+    "distinct referenced/presence signatures over the served batches "
+    "(/ the count of span fold.signature = mean classes a batch)",
+    registry=REGISTRY)
+for _r in CHECK_DECIDED_BY:
+    CHECK_DECIDED.labels(by=_r)
+
+
+def note_check_decided(rows_by_section) -> None:
+    """One batch's rows per CHECK_DECIDED_BY entry, in that order."""
+    for by, rows in zip(CHECK_DECIDED_BY, rows_by_section):
+        if rows:
+            CHECK_DECIDED.labels(by=by).inc(int(rows))
+
+
+def check_decided_counters() -> dict:
+    """{by: rows} and the signature-class sum, as one JSON-able dict."""
+    return {
+        "decided": {by: int(CHECK_DECIDED.labels(by=by)._value.get())
+                    for by in CHECK_DECIDED_BY},
+        "signature_classes_total":
+            int(FOLD_SIGNATURE_CLASSES._value.get()),
+    }
+
+
 # -- adapter-executor plane (runtime/executor.py) --------------------
 #
 # Conservation invariant (the report plane's doctrine applied to host

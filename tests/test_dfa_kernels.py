@@ -47,3 +47,28 @@ def test_all_dfa_tiers_match_host_oracle():
     blocked = np.asarray(bytes_ops.dfa_match_many_onehot_blocked(
         data, lens, pack_dfas_onehot_blocked(dfas, classes)))
     np.testing.assert_array_equal(blocked, want)
+
+
+PREFIXES = [b"", b"/", b"/api/v3/", b"/api/v9/x", b"/x", b"/xx", b"aac",
+            b"foobazz", b"z" * 32, b"/api/v3/items/77" + b"0" * 17]
+
+
+def test_prefix_group_columns_are_the_single_prefix_match():
+    # an empty prefix, one as long as its subject, one as wide as the
+    # plane and one wider
+    data, lens = _tensors()
+    assert max(map(len, PREFIXES)) > data.shape[1]
+    many = np.asarray(bytes_ops.prefix_match_many(data, lens, PREFIXES))
+    want = np.asarray([[s.startswith(p) for p in PREFIXES]
+                       for s in SUBJECTS])
+    np.testing.assert_array_equal(many, want)
+    for i, p in enumerate(PREFIXES):
+        np.testing.assert_array_equal(
+            many[:, i], np.asarray(bytes_ops.prefix_match(data, lens, p)))
+    assert many.sum() > len(SUBJECTS)      # more than the empty prefix
+    # a narrowed plane (fused.narrow_batch) keeps the true lengths
+    short = lens <= 8
+    narrow = np.asarray(bytes_ops.prefix_match_many(
+        data[short, :8], lens[short], PREFIXES))
+    np.testing.assert_array_equal(narrow, want[short])
+    assert 0 < short.sum() < len(SUBJECTS)

@@ -41,7 +41,7 @@ def test_disassemble_contents():
     # atom table with (canonical) source text and tier annotations
     assert 'EQ($destination.service, "reviews.default.svc")' in text
     assert "[id-eq]" in text
-    assert "[tensor]" in text      # the startsWith byte predicate
+    assert "[prefix-pack]" in text  # the startsWith byte predicate
     # per-rule DNFs in both polarities
     assert "M: " in text and "N: " in text
     assert "∧" in text and "∨" in text

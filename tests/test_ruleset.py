@@ -111,6 +111,45 @@ def test_short_circuit_error_suppression():
     assert (bool(m[0, 3]), bool(e[0, 3])) == (False, True)
 
 
+PREFIXES = ["", "p", "pre", "prefix", "prefix-and-more", "q" * 200]
+PREFIX_INPUTS = [{"as": "prefix"}, {"as": "pre"}, {"as": ""}, {},
+                 {"as": "prefix-and-more" + "!" * 300}, {"as": "q" * 300},
+                 {"as": "xprefix", "as2": "prefix"}]
+
+
+@pytest.mark.parametrize("subject", ["as", '(as | "pre-set")',
+                                     '(as | as2)'])
+def test_constant_prefixes_over_one_subject_ride_one_group(subject):
+    """startsWith(const) atoms over one subject evaluate as ONE group
+    (tensor_expr.compile_prefix_group), each column with the single
+    atom's semantics: an absent subject is an error, a truncated one
+    still decidable, a prefix past the byte-slot cap the host's."""
+    rules = [Rule(name=f"r{i}", match=f'{subject}.startsWith("{p}")')
+             for i, p in enumerate(PREFIXES)]
+    rules.append(Rule(name="both", match=(
+        f'{subject}.startsWith("prefix") && {subject}.startsWith("p")')))
+    rules.append(Rule(name="either", match=(
+        f'{subject}.startsWith("prefix-and-more") || '
+        f'{subject}.startsWith("")')))
+    prog = compile_ruleset(rules, FINDER)
+    g = prog.geometry
+    assert (g["n_prefix_groups"], g["n_prefix_atoms"]) == (1, 5)
+    assert g["n_gen_atoms"] == 0
+    assert sorted(prog.host_fallback) == [5]      # the 200-byte prefix
+    tiers = set(prog.atom_tier.values())
+    assert tiers == {"prefix-pack"}
+    bags = [bag_from_mapping(inp) for inp in PREFIX_INPUTS]
+    m, n, e = eval_ruleset(prog, bags)
+    seen = set()
+    for ridx, rule in enumerate(rules):
+        for b, bag in enumerate(bags):
+            want = oracle_verdict(rule.match, bag)
+            got = (bool(m[b, ridx]), bool(n[b, ridx]), bool(e[b, ridx]))
+            assert got == want, (rule.match, PREFIX_INPUTS[b])
+            seen.add(want)
+    assert len(seen) >= 2
+
+
 def test_namespace_masking():
     rules = [Rule(name="default", match="", namespace=""),
              Rule(name="ns1", match="", namespace="ns1"),
