@@ -118,6 +118,9 @@ class MixerGrpcServer:
     # -- lifecycle --
 
     def start(self) -> int:
+        # whatever was built since the runtime's constructor outlives
+        # every request: out of the collector's way (settle_heap)
+        monitor.settle_heap("start")
         self._server.start()
         log.info("mixer grpc server on port %d", self.port)
         return self.port
@@ -813,6 +816,7 @@ class MixerAioGrpcServer(MixerGrpcServer):
             self._stopped.set()
 
     def start(self) -> int:
+        monitor.settle_heap("start")
         self._thread.start()
         if not self._ready.wait(timeout=30):
             raise RuntimeError("aio grpc server failed to start")

@@ -163,6 +163,9 @@ class NativeMixerServer(MixerGrpcServer):
     # -- lifecycle --
 
     def start(self) -> int:
+        # whatever was built since the runtime's constructor (a
+        # blocking prewarm, this front) outlives every request
+        monitor.settle_heap("start")
         for t in self._pumps:
             t.start()
         if self._tls_certs is not None:

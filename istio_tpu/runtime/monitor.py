@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import gc
 import threading
 import time
 
@@ -453,6 +454,16 @@ GC_PAUSE_SECONDS = hostmetrics.default_registry.histogram(
     "mixer_gc_pause_seconds",
     "stop-the-world wall of full (generation 2) garbage collections "
     "of the serving process")
+GC_FROZEN_OBJECTS = hostmetrics.default_registry.gauge(
+    "mixer_gc_frozen_objects",
+    "objects in the collector's permanent generation after the last "
+    "settle_heap at a named site (0: the heap is not frozen)")
+HEAP_SETTLES = hostmetrics.default_registry.counter(
+    "mixer_heap_settles_total",
+    "settle_heap calls that froze the heap (label: at)")
+HEAP_SETTLE_SITES = ("init", "start", "publish", "gc")
+for _at in HEAP_SETTLE_SITES:   # zero-series before the first settle
+    HEAP_SETTLES.inc(0, at=_at)
 
 
 # forensics stage tap (runtime/forensics.py registers the flight
@@ -604,14 +615,34 @@ def span(name: str, on: bool = True, tap: bool = False, **tags):
 # -- full garbage collections of the serving process -------------------
 #
 # A generation-2 collection stops every thread for as long as it walks
-# the heap (0.3 s at a 10k-rule snapshot's ~800k objects): both pumps
-# and every blocked request wait it out. The hook is installed by each
-# RuntimeServer and removed at its close (refcounted: tests run
-# several servers in one process).
+# the heap: both pumps and every blocked request wait it out. At a
+# 10k-rule snapshot the heap is ~800k objects (0.3 s a walk), almost
+# all of them the snapshot, its handlers, the traced step programs the
+# jit cache keeps alive and imported modules: nothing that can become
+# garbage before the next config publish. settle_heap() puts them in
+# the collector's permanent generation, which no collection walks.
+#
+# Freezing most of it buys nothing: CPython runs a full collection
+# once a quarter of the last one's survivors has been promoted, so the
+# walks get shorter and as much more frequent, and their share of the
+# wall stays what it was (12 % with 580k objects frozen and 207k not:
+# PERF.md, PR 30).
+# It falls only when the unfrozen survivors are fewer than the rows in
+# flight. So besides the named sites the hook settles again after any
+# full collection that took RESETTLE_PAUSE_S: what it walked had
+# survived, whoever built it (the host oracle's per-rule programs,
+# compiled as fallback traffic first touches each rule; caches filled
+# by the first requests; the embedding process's own data).
+#
+# Hook and frozen heap live as long as a RuntimeServer does
+# (refcounted: tests run several servers in one process).
 
 _GC_LOCK = threading.Lock()
 _GC_USERS = 0
 _GC_OPEN: list = []     # [(annotation, t0)] of the collection running
+# the rows in flight alone cost a full collection 2-5 ms; at about two
+# collections a second this bounds what is left at ~2 % of the wall
+RESETTLE_PAUSE_S = 0.010
 
 
 def _on_gc(phase: str, info: dict) -> None:
@@ -623,13 +654,19 @@ def _on_gc(phase: str, info: dict) -> None:
         _GC_OPEN.append((ann, time.perf_counter()))
     elif _GC_OPEN:
         ann, t0 = _GC_OPEN.pop()
-        GC_PAUSE_SECONDS.observe(time.perf_counter() - t0)
+        seconds = time.perf_counter() - t0
+        GC_PAUSE_SECONDS.observe(seconds)
         ann.__exit__(None, None, None)
+        # not behind a settle in progress: its own reclaim walk ends
+        # here too, with the lock held by this very thread
+        if seconds >= RESETTLE_PAUSE_S and _GC_LOCK.acquire(blocking=False):
+            try:
+                _settle_locked("gc", count=False)
+            finally:
+                _GC_LOCK.release()
 
 
 def install_gc_hook() -> None:
-    import gc
-
     global _GC_USERS
     with _GC_LOCK:
         _GC_USERS += 1
@@ -638,24 +675,73 @@ def install_gc_hook() -> None:
 
 
 def remove_gc_hook() -> None:
-    import gc
-
+    """The last server's close also unfreezes: a process that goes on
+    without a server collects as it did before the first one."""
     global _GC_USERS
     with _GC_LOCK:
         if not _GC_USERS:
             return
         _GC_USERS -= 1
-        if not _GC_USERS and _on_gc in gc.callbacks:
+        if _GC_USERS:
+            return
+        if _on_gc in gc.callbacks:
             gc.callbacks.remove(_on_gc)
+        gc.unfreeze()
+        GC_FROZEN_OBJECTS.set(0)
+
+
+def settle_heap(at: str, reclaim: bool = False) -> None:
+    """Freeze everything tracked so far into the permanent generation,
+    so that full collections walk only what was allocated since. Called
+    where the long-lived heap has just grown or been swapped (`at`, one
+    of HEAP_SETTLE_SITES; "gc" is the hook's own, see above), never per
+    batch. A no-op while no server holds the gc hook.
+
+    Plain: gc.freeze(), three list merges, from any thread. Frozen
+    objects are still freed by reference count; only cyclic garbage
+    among them waits for the next reclaim. Young and full collections
+    keep running over everything allocated after the settle.
+
+    `reclaim`: unfreeze, one full collection, freeze again: one walk
+    of the whole heap (it stops every thread, so never on a pump
+    thread) that returns what earlier settles caught and has since
+    died in a cycle: set-up garbage, and after a publish the outgoing
+    snapshot, its handlers and traced programs. What an in-flight
+    batch or an ORPHAN_DRAIN_S timer still references then is frozen
+    again and goes at the next publish: one generation at most."""
+    with _GC_LOCK:
+        _settle_locked(at, reclaim)
+
+
+def _settle_locked(at: str, reclaim: bool = False,
+                   count: bool = True) -> None:
+    if not _GC_USERS:
+        return
+    if reclaim:
+        gc.unfreeze()
+        gc.collect()
+    gc.freeze()
+    if count:
+        # a walk of the permanent generation's list (35 ms at 0.7 M
+        # objects on the chip's host): not for the hook, which may be
+        # on a pump thread
+        GC_FROZEN_OBJECTS.set(gc.get_freeze_count())
+    HEAP_SETTLES.inc(at=at)
 
 
 def gc_pause_snapshot(since: dict | None = None) -> dict:
-    """Full collections seen by the hook: {"count", "sum_s"}, or the
-    delta against an earlier snapshot `since`."""
+    """Full collections seen by the hook ({"count", "sum_s"}, or the
+    delta against an earlier snapshot `since`), and the heap as the
+    last settle at a named site left it: "frozen" (objects in the
+    permanent generation, 0 when not frozen; the hook's own settles
+    add to them uncounted) and "settles" ({at: calls})."""
     _, total, n = GC_PAUSE_SECONDS.state()
     if since is not None:
         total, n = total - since["sum_s"], n - since["count"]
-    return {"count": n, "sum_s": total}
+    return {"count": n, "sum_s": total,
+            "frozen": int(GC_FROZEN_OBJECTS.value()),
+            "settles": {at: int(HEAP_SETTLES.value(at=at))
+                        for at in HEAP_SETTLE_SITES}}
 
 
 def observe_check_e2e(seconds: float) -> None:

@@ -550,6 +550,10 @@ class RuntimeServer:
         # no close() to undo it.
         from istio_tpu.runtime import monitor as _monitor
         _monitor.install_gc_hook()
+        # store, snapshot, handlers and the host rule compile are in
+        # place and cannot die before the next publish: out of the
+        # collector's way, set-up's garbage returned first
+        _monitor.settle_heap("init", reclaim=True)
 
     # -- API surface (grpcServer.go Check/Report semantics) --
     # Preprocessing (the APA phase) happens exactly ONCE per request, in
@@ -641,6 +645,14 @@ class RuntimeServer:
             import logging
             logging.getLogger("istio_tpu.runtime.server").exception(
                 "in-step quota prewarm failed")
+        # the outgoing snapshot, its handlers and traced programs were
+        # frozen and have just lost their last owner: one full walk
+        # here, on the rebuild thread, returns them and freezes the
+        # incoming generation (the initial publish fires inside the
+        # constructor, which settles at its own end)
+        if getattr(self, "controller", None) is not None:
+            from istio_tpu.runtime import monitor as _monitor
+            _monitor.settle_heap("publish", reclaim=True)
 
     def _bound_staging_depth(self, dispatcher) -> None:
         """Keep the wire decoder's staging ring deeper than the
