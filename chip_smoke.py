@@ -17,7 +17,11 @@ conservation ledger, the resilience counters).
                                                # comparison, nothing else
 
 Every phase prints one JSON line. Rates are a SMOKE (did it serve, with
-what errors), never a measurement. The last line of stdout is
+what errors), never a measurement: `benchmark/run.py` measures. This
+script stays beside it because no benchmark cell sends a quota or a
+Report yet: the quota on every fourth Check and the native Report
+phase here are the only rehearsal of those two paths on the chip
+(ROADMAP Design 1). The last line of stdout is
 
     {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
 

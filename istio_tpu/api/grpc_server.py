@@ -634,7 +634,7 @@ class MixerAioGrpcServer(MixerGrpcServer):
                              ) -> "pb.CheckResponse":
         import asyncio
         d = self.runtime.controller.dispatcher
-        if self.runtime.args.preprocess and d.has_apa:
+        if d.has_apa:
             # preprocess runs an APA device round-trip — off the loop
             bag = await loop.run_in_executor(None, self._check_bag,
                                              request, identity)

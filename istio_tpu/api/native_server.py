@@ -24,7 +24,6 @@ import ctypes
 import logging
 import struct
 import threading
-from typing import Any
 
 from istio_tpu.adapters.sdk import QuotaArgs
 from istio_tpu.api import mixer_pb2 as pb
@@ -57,17 +56,6 @@ class _RowRequest:
 # must mirror Server::kLatBuckets in httpd.cpp: the wire latency
 # histogram's log-bucket count (bucket i covers ≤ 1µs·2^(i/8))
 _LAT_BUCKETS = 192
-
-
-def start_echo_server(max_batch: int = 1024) -> tuple[int, Any]:
-    """Wire-ceiling mode: the C++ server answers every Check with a
-    fixed OK CheckResponse, no engine — (port, stop_fn). Single home
-    of the h2srv C ABI for bench/scripts (with _load_lib below)."""
-    lib = _load_lib()
-    h = lib.h2srv_start(0, max_batch, 256, 2000, 1, 1, 0)
-    if not h:
-        raise RuntimeError("h2srv_start failed (echo)")
-    return lib.h2srv_port(h), lambda: lib.h2srv_stop(h)
 
 
 def _load_lib() -> ctypes.CDLL:
@@ -311,7 +299,7 @@ class NativeMixerServer(MixerGrpcServer):
 
     def latency_snapshot(self, since: dict | None = None) -> dict:
         """Wire-to-verdict latency quantiles — cumulative, or the
-        DELTA vs a latency_raw() baseline (per-bench-window reads).
+        DELTA vs a latency_raw() baseline (per-window reads).
         The measurement is taken entirely in C++ (frame decode →
         response frame write), so it is the one number that holds the
         whole of a request's stay: the wait in the C++ queue for a

@@ -26,8 +26,8 @@ on the same hardware:
             keeps serving), `warn` publishes but records the report.
 
 Surfaces: /debug/canary (introspect), `mixer_canary_*` metric
-families, kube/admission.register_canary_admission, the `canary` CLI
-subcommand, and bench.py `canary_*` keys.
+families, kube/admission.register_canary_admission and the `canary`
+CLI subcommand.
 """
 from istio_tpu.canary.differ import (CanaryReport, Divergence,
                                      diff_decisions, oracle_decision)

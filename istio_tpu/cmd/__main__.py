@@ -156,7 +156,7 @@ def cmd_mixs(args: argparse.Namespace) -> int:
         from istio_tpu.introspect import IntrospectServer
         # trace ring OFF unless asked: enabling it flips the global
         # tracer to recording, and span construction (2x uuid per
-        # span) is hot-path work the bench-certified p99 never pays
+        # span) is hot-path work no measured p99 pays
         intro = IntrospectServer(runtime=runtime,
                                  port=args.monitoring_port,
                                  host=args.monitoring_host,

@@ -27,9 +27,9 @@ folds) is a documented first-order model: each plane counted once per
 read/write at its dtype width, no cache modeling — good enough to name
 the binding resource, which is the job.
 
-Consumers: bench.py (per-section `*_fraction_of_roof` / `*_bound`
-fields for the headline, capacity, rbac and full_mesh sections) and
-the introspect server's /debug/roofline view.
+Consumers: the introspect server's /debug/roofline view and the
+roofline smoke. No benchmark cell reads it yet: a measured step is
+judged against this model nowhere on the chip.
 """
 from __future__ import annotations
 
@@ -198,8 +198,8 @@ def model_check_step(engine, batch: int, plan: Any = None,
                      str_len: int | None = None) -> StepModel:
     """Build the per-step cost model for a compiled PolicyEngine at
     batch size `batch`. `plan` (a runtime FusedPlan) additionally
-    models the packed-pull packer + D2H rows — bench's raw-step
-    sections pass None. `str_len`: byte-plane width actually served
+    models the packed-pull packer + D2H rows; None models the raw
+    engine step. `str_len`: byte-plane width actually served
     (a narrowed latency tier); None = layout.max_str_len."""
     rs = engine.ruleset
     lay = rs.layout
@@ -381,70 +381,10 @@ def packed_pull_rows(plan) -> int:
     return 5 + plan.n_ref_words + plan.n_overlay_words
 
 
-def latency_floor(engine, batch: int, plan: Any = None, *,
-                  frame_ms: float = 0.05,
-                  pcie_gbps: float = 12.0,
-                  dispatch_ms: float = 0.05,
-                  str_len: int | None = None,
-                  peaks: dict | None = None) -> dict:
-    """The IRREDUCIBLE wire-to-verdict latency floor for one
-    latency-tier batch — what remains when every software overhead is
-    gone, so a measured p99 can be judged as "X ms above physics"
-    instead of against an aspiration:
-
-        frame — per-request wire framing cost (caller supplies the
-                measured echo-server per-request wall; the default is
-                a placeholder)
-        h2d   — the batch's EXACT plane bytes over the host↔device
-                link (PCIe model) + one dispatch overhead
-        step  — the compiled step's roofline time: max(bytes/HBM_peak,
-                mxu_ops/MXU_peak) from the program's own shapes
-        d2h   — the packed pull's exact bytes back + one dispatch
-
-    Everything above this floor is queueing, batching policy, python,
-    or response build — attackable; the floor itself moves only with
-    hardware or a smaller compiled program."""
-    if peaks is None:
-        peaks = peaks_for()
-    model = model_check_step(engine, batch, plan=plan,
-                             str_len=str_len)
-    h2d_bytes = batch_plane_bytes(engine.ruleset.layout, batch,
-                                  str_len=str_len)
-    h2d_ms = h2d_bytes / (pcie_gbps * 1e9) * 1e3 + dispatch_ms
-    step_ms = max(model.bytes_per_step / (peaks["hbm_gbps"] * 1e9),
-                  model.mxu_ops_per_step
-                  / (peaks["mxu_tops"] * 1e12)) * 1e3
-    d2h = model.component("d2h_packed")
-    d2h_bytes = d2h.bytes if d2h is not None else batch * 4
-    d2h_ms = d2h_bytes / (pcie_gbps * 1e9) * 1e3 + dispatch_ms
-    floor = frame_ms + h2d_ms + step_ms + d2h_ms
-    return {
-        "floor_ms": round(floor, 4),
-        "breakdown": {
-            "frame_ms": round(frame_ms, 4),
-            "h2d_ms": round(h2d_ms, 4),
-            "device_step_ms": round(step_ms, 4),
-            "d2h_ms": round(d2h_ms, 4),
-        },
-        "batch": batch,
-        "h2d_bytes": int(h2d_bytes),
-        "d2h_bytes": int(d2h_bytes),
-        "pcie_gbps": pcie_gbps,
-        "roof_platform": peaks["label"],
-        "derivation": (
-            "frame (measured echo per-request wire cost) + h2d "
-            "(exact batch plane bytes / PCIe + dispatch) + device "
-            "step (compiled-shape roofline: max(bytes/HBM, ops/MXU)) "
-            "+ d2h (exact packed-pull bytes / PCIe + dispatch) — "
-            "the irreducible floor; measured p99 minus this is the "
-            "attackable software gap"),
-    }
-
-
 def bench_fields(engine, batch: int, step_s: float, prefix: str,
                  plan: Any = None,
                  str_len: int | None = None) -> dict:
-    """BENCH-artifact fields for one perf section: the model summary +
+    """Flat `<prefix>*` fields for one engine: the model summary +
     the measured step judged against the platform roof. Fail-soft by
     contract — a modeling error must never take a section's measured
     numbers down."""

@@ -560,7 +560,7 @@ class IntrospectServer:
             payload["replicas"].append(entry)
         # cross-lane routing aggregate (rows per shard / occupancy /
         # misroutes): ReplicaRouter.routing_stats is the single home
-        # shared with the fleet bench and the shard smoke
+        # shared with the shard smoke
         routing = rr.routing_stats()
         payload["rows_per_shard"] = routing["rows_per_shard"]
         payload["occupancy"] = routing["occupancy"]

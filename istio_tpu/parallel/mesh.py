@@ -77,10 +77,10 @@ def param_shardings(mesh: Mesh, engine) -> dict:
 
 def mesh_stage_probe(mesh: Mesh, engine, batch, req_ns,
                      steps: int = 3, reps: int = 2) -> dict:
-    """Per-stage timers for the sharded check step (the mesh bench's
-    honesty satellite): on a 1-core host the end-to-end scaling ratio
-    is time-slicing noise, but the STAGES still attribute where the
-    sharding machinery spends —
+    """Per-stage timers for the sharded check step (read by
+    `__graft_entry__.dryrun_multichip`): on a 1-core host the
+    end-to-end scaling ratio is time-slicing noise, but the STAGES
+    still attribute where the sharding machinery spends —
 
       shard_dispatch_ms    host→device placement of the batch under
                            the dp sharding (per step)

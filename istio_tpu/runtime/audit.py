@@ -171,7 +171,8 @@ class InjectionLedger:
         if kind in ("device", "oracle"):
             rc = monitor.resilience_counters()
             return {"fallback_total": rc["fallback_total"],
-                    "batch_failures_total": rc["batch_failures_total"]}
+                    "batch_failures_total": rc["batch_failures_total"],
+                    "device_retries_total": rc["device_retries_total"]}
         if kind == "discovery":
             # note() fires INSIDE publish, before the generation bump —
             # the baseline is the generation the delayed push started
@@ -274,6 +275,13 @@ class InjectionLedger:
             ev = event(("breaker",), name="device")
             if ev is not None:
                 return "event:breaker device"
+            # a single failure may be absorbed by the checker's one
+            # jittered retry: no fallback, no breaker verdict, the
+            # retry counter alone moves (the last of a burst, once the
+            # auditor has matched the burst's first record)
+            if rc["device_retries_total"] > \
+                    base.get("device_retries_total", 0):
+                return "counter:device_retries_total"
             return ""
         if kind == "oracle":
             if rc["batch_failures_total"] > \

@@ -38,9 +38,9 @@ Three legs, all bounded and lock-light:
 Overflow on either ring is bounded AND typed:
 mixer_forensics_dropped_total{ring=} in runtime/monitor.py,
 zero-shaped before the first drop per the promtext doctrine. The
-recorder's clean-traffic overhead is pinned by bench.py's
-forensics_overhead_pct (≤2% gate in the smoke) — the fast path is a
-threshold compare per batch, not per-request work.
+recorder's clean-traffic overhead is gated by the forensics smoke
+(≤2 %, CPU) — the fast path is a threshold compare per batch, not
+per-request work.
 """
 from __future__ import annotations
 
@@ -77,15 +77,6 @@ class EventTimeline:
         self._lock = threading.Lock()
         self._buf: collections.deque = collections.deque(
             maxlen=max(int(capacity), 8))
-
-    def configure(self, capacity: int | None = None) -> None:
-        if capacity is None:
-            return
-        capacity = max(int(capacity), 8)
-        with self._lock:
-            if capacity != self._buf.maxlen:
-                self._buf = collections.deque(self._buf,
-                                              maxlen=capacity)
 
     @staticmethod
     def _mergeable(a: dict, b: dict) -> bool:

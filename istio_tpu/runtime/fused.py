@@ -471,8 +471,7 @@ class FusedPlan:
         """packed_check's rows PLUS an IN-STEP quota allocation in the
         SAME device program — the quota-carrying batch pays ONE trip
         instead of check-trip + pool-flush-trip serialized on the
-        transport (the bench's no-quota windows measure ~2x the mixed
-        rate for exactly this reason).
+        transport.
 
         Narrowed to the batch's byte tier like packed_check.
 

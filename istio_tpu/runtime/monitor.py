@@ -116,8 +116,8 @@ for _s in ("closed", "half_open", "open"):
 
 def resilience_counters() -> dict:
     """Resilience counter snapshot as one JSON-able dict — read by
-    /debug/resilience, the chaos smoke and bench.py (per served
-    scenario, so overload behavior lands in the BENCH artifact)."""
+    /debug/resilience, the chaos smoke and the benchmark's `correct`
+    (its fallback counters must not move inside a window)."""
     shed = {r: int(CHECK_SHED.labels(reason=r)._value.get())
             for r in CHECK_SHED_REASONS}
     fb = {r: int(CHECK_FALLBACK.labels(reason=r)._value.get())
@@ -243,7 +243,7 @@ def note_host_action_retry(handler: str) -> None:
 
 def host_action_counters() -> dict:
     """Executor-plane counter snapshot as one JSON-able dict — read by
-    /debug/executor, the executor smoke and bench.py. `exact` is the
+    /debug/executor and the executor smoke. `exact` is the
     conservation check (True whenever nothing is in flight)."""
     by_handler: dict[str, dict] = {}
     submitted_total = 0
@@ -328,8 +328,7 @@ def note_forensics_drop(ring: str) -> None:
 
 def forensics_counters() -> dict:
     """Forensics counter snapshot as one JSON-able dict — read by
-    /debug/slow, the forensics smoke and bench.py (per served
-    scenario: tail_* keys delta against a baseline of this)."""
+    /debug/slow and the forensics smoke."""
     return {
         "slow_captured": int(FORENSICS_SLOW._value.get()),
         "events_recorded": int(FORENSICS_EVENTS._value.get()),
@@ -375,8 +374,8 @@ def note_identity(event: str, outcome: str) -> None:
 
 
 def identity_counters() -> dict:
-    """Secure-plane counter snapshot — /debug/identity, the mtls
-    smoke and bench.py secure_* keys read this."""
+    """Secure-plane counter snapshot — /debug/identity and the mtls
+    smoke read this."""
     events = {e: {o: int(IDENTITY_EVENTS.labels(
         event=e, outcome=o)._value.get())
         for o in IDENTITY_OUTCOMES} for e in IDENTITY_EVENTS_KINDS}
@@ -755,7 +754,7 @@ def observe_check_e2e(seconds: float) -> None:
 def refresh_latency_gauges() -> dict:
     """Recompute the sliding-window percentile gauges + SLO gauge from
     the current window. Called by scrape-rate readers (the introspect
-    /metrics handler, bench, the smoke script) — never per request."""
+    /metrics handler, the SLO evaluator) — never per request."""
     p50, p95, p99 = CHECK_WINDOW.quantiles((0.50, 0.95, 0.99))
     p50_ms, p95_ms, p99_ms = p50 * 1e3, p95 * 1e3, p99 * 1e3
     CHECK_P50_MS.set(p50_ms)
@@ -774,7 +773,7 @@ def refresh_latency_gauges() -> dict:
 
 
 def reset_latency_window() -> None:
-    """Drop windowed observations (bench scenario boundaries — a
+    """Drop windowed observations (scenario boundaries — a
     saturation phase's queueing tail must not pollute the light
     phase's live p99). Histograms keep accumulating; only the
     sliding-window gauges reset."""
@@ -785,7 +784,7 @@ def stage_baseline() -> dict:
     """Subtraction token for latency_snapshot(since=...): the stage +
     e2e histogram states at a window's start. The histograms are
     process-lifetime cumulative (prometheus semantics); per-SCENARIO
-    readings (bench phases) must delta against a baseline or the
+    readings (a benchmark window) must delta against a baseline or the
     previous phase's ~10k batches drown the window's few hundred."""
     token = {stage: CHECK_STAGE_SECONDS.state(stage=stage)
              for stage in CHECK_STAGES}
@@ -807,8 +806,8 @@ def _delta(state, base):
 
 def latency_snapshot(since: dict | None = None) -> dict:
     """Stage decomposition + live percentiles as one JSON-able dict —
-    what bench.py appends to the BENCH artifact after each served
-    scenario and /debug/queues consumers read. `since`: a
+    what /debug/queues serves and the benchmark's per-layer readers
+    (benchmark/spans.py) reduce. `since`: a
     stage_baseline() token; readings then cover only the window after
     it (quantiles computed from delta bucket counts)."""
     from istio_tpu.utils.metrics import quantile_from_counts
@@ -852,8 +851,8 @@ def latency_snapshot(since: dict | None = None) -> dict:
 
 
 def serving_counters() -> dict:
-    """Snapshot of the serving-path counters as a plain dict (emitted
-    into bench artifacts on success AND failure)."""
+    """Snapshot of the serving-path counters as a plain dict (read by
+    the auditor and the soak gates)."""
     hist: dict[str, int] = {}
     for i, b in enumerate(CHECK_BATCH_SIZE._upper_bounds):
         # prometheus_client stores per-bucket (non-cumulative) counts
@@ -1050,7 +1049,7 @@ def report_conservation(since: dict | None = None) -> dict:
     True only when the plane is fully drained — the form the smoke
     gate and shutdown assertions check. `since`: a previous
     report_conservation() reading — the counters are process-lifetime
-    cumulative, so per-scenario checks (bench phases, tests sharing a
+    cumulative, so per-scenario checks (soak phases, tests sharing a
     process) must delta against their own baseline."""
     accepted = int(REPORT_RECORDS_ACCEPTED._value.get())
     exported = int(REPORT_RECORDS_EXPORTED._value.get())
@@ -1082,8 +1081,8 @@ def report_stage_baseline() -> dict:
 
 def report_latency_snapshot(since: dict | None = None) -> dict:
     """Six-stage report pipeline decomposition (p50/p95/p99 per stage)
-    as one JSON-able dict — what /debug/report serves and bench.py
-    scrapes into the BENCH artifact per served scenario."""
+    as one JSON-able dict — what /debug/report serves and the report
+    smoke gates."""
     from istio_tpu.utils.metrics import quantile_from_counts
 
     empty = ([], 0.0, 0)
@@ -1110,7 +1109,7 @@ def report_latency_snapshot(since: dict | None = None) -> dict:
 
 
 def report_counters() -> dict:
-    """Ingestion-plane snapshot for /debug/report and bench artifacts:
+    """Ingestion-plane snapshot for /debug/report and the report smoke:
     conservation + per-template record counts + per-exporter stats +
     recent drop reasons. Always JSON-able; zero-shaped before the
     first record (the view must serve on an idle server)."""
@@ -1174,16 +1173,14 @@ def observe_replica_batch(replica: int, seconds: float,
 
 def shard_stage_baseline() -> dict:
     """Subtraction token for shard_latency_snapshot(since=...) — the
-    same delta-window discipline as stage_baseline() (the fleet bench
-    reads per-scenario stage attribution, not process-lifetime)."""
+    same delta-window discipline as stage_baseline()."""
     return {stage: SHARD_STAGE_SECONDS.state(stage=stage)
             for stage in SHARD_STAGES}
 
 
 def shard_latency_snapshot(since: dict | None = None) -> dict:
     """Sharded-path stage decomposition (count/sum/p50/p99 per stage)
-    as one JSON-able dict — /debug/shards' `stages` pane and the fleet
-    bench's per-stage attribution."""
+    as one JSON-able dict — /debug/shards' `stages` pane."""
     from istio_tpu.utils.metrics import quantile_from_counts
 
     empty = ([], 0.0, 0)
@@ -1300,29 +1297,16 @@ def set_discovery_generation(version: int) -> None:
     DISCOVERY_GENERATION.set(float(version))
 
 
-def discovery_stage_baseline() -> dict:
-    """Subtraction token for discovery_latency_snapshot(since=...) —
-    the same delta-window discipline as stage_baseline()."""
-    token = {stage: DISCOVERY_STAGE_SECONDS.state(stage=stage)
-             for stage in DISCOVERY_STAGES}
-    token["__push__"] = DISCOVERY_PUSH_FANOUT_SECONDS.state()
-    return token
-
-
-def discovery_latency_snapshot(since: dict | None = None) -> dict:
+def discovery_latency_snapshot() -> dict:
     """Discovery stage decomposition + push fan-out percentiles as one
-    JSON-able dict — /debug/discovery's `stages` pane and the bench's
-    per-scenario attribution."""
+    JSON-able dict — /debug/discovery's `stages` pane and the SLO
+    evaluator's push-fan-out objective."""
     from istio_tpu.utils.metrics import quantile_from_counts
 
-    empty = ([], 0.0, 0)
     stages: dict[str, dict] = {}
     h = DISCOVERY_STAGE_SECONDS
     for stage in DISCOVERY_STAGES:
         counts, total, n = h.state(stage=stage)
-        if since is not None:
-            counts, total, n = _delta((counts, total, n),
-                                      since.get(stage, empty))
         if not n:
             continue
         stages[stage] = {
@@ -1335,9 +1319,6 @@ def discovery_latency_snapshot(since: dict | None = None) -> dict:
         }
     ph = DISCOVERY_PUSH_FANOUT_SECONDS
     counts, total, n = ph.state()
-    if since is not None:
-        counts, total, n = _delta((counts, total, n),
-                                  since.get("__push__", empty))
     push = {"count": n}
     if n:
         push.update({
@@ -1347,29 +1328,6 @@ def discovery_latency_snapshot(since: dict | None = None) -> dict:
                 ph.buckets, counts, n, 0.99) * 1e3, 3),
         })
     return {"stages": stages, "push": push}
-
-
-def discovery_cache_counters(since: dict | None = None) -> dict:
-    """Cache-event snapshot (+hit_rate) as one JSON-able dict — read
-    by /debug/discovery, the discovery smoke and bench.py. `since`: a
-    previous reading (the counters are process-lifetime cumulative;
-    per-scenario rates must delta against their own baseline)."""
-    out = {}
-    with DISCOVERY_CACHE._lock:
-        vals = dict(DISCOVERY_CACHE._values)
-    for e in DISCOVERY_CACHE_EVENTS:
-        out[e] = 0
-    for labels, v in vals.items():
-        e = dict(labels).get("event")
-        if e in out:
-            out[e] += int(v)
-    if since is not None:
-        for e in DISCOVERY_CACHE_EVENTS:
-            out[e] -= int(since.get(e, 0))
-    calls = out["hit"] + out["miss"]
-    out["hit_rate"] = round(out["hit"] / calls, 4) if calls else None
-    out["generation"] = int(DISCOVERY_GENERATION.value())
-    return out
 
 
 # -- mesh audit plane (runtime/audit.py) -------------------------------
@@ -1422,7 +1380,7 @@ FAULT_EXPLAINABILITY.set(1.0)
 
 def audit_counters() -> dict:
     """One JSON-able reading of the audit + explainability families —
-    read by /debug/audit, the audit smoke and bench.py."""
+    read by /debug/audit, the audit smoke and the soak gates."""
     checks = {inv: {st: int(AUDIT_CHECKS.labels(
         invariant=inv, status=st)._value.get())
         for st in AUDIT_STATUSES} for inv in AUDIT_INVARIANTS}

@@ -302,14 +302,6 @@ class RuleTelemetry:
                 "err": err_np, "exemplars": exemplars,
                 "exemplars_seen": ex_seen, "wall_s": wall}
 
-    def wait(self) -> None:
-        """Block until every dispatched fold has executed (bench
-        timing helper — NOT for the serving path)."""
-        import jax
-        with self._lock:
-            handles = (self._acc_hit, self._acc_deny, self._acc_err)
-        jax.block_until_ready(handles)
-
 
 class RuleStatsAggregator:
     """Name-keyed aggregation over drained deltas + export fan-out.

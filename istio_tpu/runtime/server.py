@@ -74,7 +74,7 @@ class ServerArgs:
     # the full snapshot; with shards>0 the banks are shared and lane
     # selection follows the shard assignment. 1 = single lane.
     replicas: int = 1
-    # False skips the background FIRST-build prewarm (bench rigs and
+    # False skips the background FIRST-build prewarm (the benchmark and
     # tests that call plan.prewarm explicitly — the duplicate compile
     # contends for the core); swap-time prewarm stays synchronous
     initial_prewarm: bool = True
@@ -84,8 +84,7 @@ class ServerArgs:
     # ONLY the banks whose namespaces (or the replicated global set)
     # changed — untouched banks carry across generations with their
     # prewarmed shapes, breaker state and rulestats bindings. False is
-    # the kill switch: every publish rebuilds every bank (and is what
-    # the bench's capacity_republish_full_s measures).
+    # the kill switch: every publish rebuilds every bank.
     delta_compile: bool = True
     # namespaces the delta planner may RELOCATE per republish to chase
     # LPT balance — each move recompiles two banks, so this is an
@@ -102,7 +101,6 @@ class ServerArgs:
     # exposes --jax-compile-cache-dir).
     jax_compile_cache_dir: str | None = None
     max_str_len: int | None = None
-    preprocess: bool = True
     # serve checks through the fused device engine (runtime/fused.py);
     # False falls back to the generic host-adapter dispatch path
     fused: bool = True
@@ -188,8 +186,8 @@ class ServerArgs:
     # per-request flight recorder: requests whose e2e latency exceeds
     # the threshold capture a complete stage timeline (+ overlapping
     # control-plane events) into the bounded ring /debug/slow serves.
-    # The fast path is one threshold compare per batch — bench pins
-    # the clean-traffic overhead at ≤2% (forensics_overhead_pct).
+    # The fast path is one threshold compare per batch — the forensics
+    # smoke gates the clean-traffic overhead at ≤2 % (CPU).
     flight_recorder: bool = True
     # capture threshold in ms; 0 = the live SLO target
     # (monitor.CHECK_P99_TARGET_MS — "slow" means "violates the p99
@@ -201,10 +199,6 @@ class ServerArgs:
     # is sometimes what you want: only the OUTLIERS above the current
     # regime are exemplars)
     slow_adaptive: bool = False
-    # bounded ring capacities (overflow is typed:
-    # mixer_forensics_dropped_total{ring=})
-    slow_ring_capacity: int = 256
-    event_ring_capacity: int = 512
     # jax.profiler trace capture directory for /debug/profile
     # (mixs --profile-dir; None → MIXS_PROFILE_DIR env → a tempdir
     # created per capture)
@@ -248,11 +242,8 @@ class ServerArgs:
     # audit_violation events, bump mixer_audit_* and flip the
     # mixer_audit_healthy gauge. /debug/audit + /debug/slo serve it.
     audit: bool = True
-    # evaluation cadence; the quota counter-plane recount samples
-    # every audit_quota_every-th evaluation (its pull is the one
-    # audit read that can touch the device transport)
+    # evaluation cadence
     audit_interval_s: float = 0.5
-    audit_quota_every: int = 8
     # fault-explainability matching window: an injection unmatched to
     # a forensics exemplar/event past this long counts unexplained
     audit_explain_window_s: float = 10.0
@@ -297,10 +288,7 @@ class RuntimeServer:
         forensics.RECORDER.configure(
             enabled=self.args.flight_recorder,
             threshold_ms=self.args.slow_threshold_ms,
-            adaptive=self.args.slow_adaptive,
-            capacity=self.args.slow_ring_capacity)
-        forensics.EVENTS.configure(
-            capacity=self.args.event_ring_capacity)
+            adaptive=self.args.slow_adaptive)
         manifest = self.args.default_manifest
         if manifest is None:
             manifest = GLOBAL_MANIFEST
@@ -541,8 +529,7 @@ class RuntimeServer:
             self.audit = AuditPlane(
                 self,
                 interval_s=self.args.audit_interval_s,
-                explain_window_s=self.args.audit_explain_window_s,
-                quota_every=self.args.audit_quota_every)
+                explain_window_s=self.args.audit_explain_window_s)
             self.audit.start()
         # full garbage collections stop every pump: time them for as
         # long as this server serves (shutdown() removes the hook).
@@ -1030,7 +1017,7 @@ class RuntimeServer:
         d = self.controller.dispatcher
         # the APA resolve costs a device step per request — skip it
         # outright unless an ATTRIBUTE_GENERATOR action is configured
-        if not self.args.preprocess or not d.has_apa:
+        if not d.has_apa:
             return bag
         return d.preprocess(bag)
 

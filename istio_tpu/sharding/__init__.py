@@ -26,8 +26,8 @@ Layers (each its own module):
               and ReplicaRouter (N CheckBatcher serving lanes behind
               one front, sticky-by-namespace)
   parity.py   SnapshotOracle-backed expected statuses — the exact
-              parity surface the shard smoke gate and fleet bench
-              judge the sharded path against
+              parity surface the shard smoke gate judges the
+              sharded path against
 """
 from istio_tpu.sharding.planner import (ShardPlan, ShardPlanError,
                                         plan_shards, predict_rule_costs)

@@ -1,8 +1,8 @@
 """Oracle parity surface for the sharded serving plane.
 
-The shard smoke gate (scripts/shard_smoke.py), the fleet bench
-(bench.py) and the sharding property tests all judge the sharded path
-against the SAME independent derivation: per-rule predicate truth via
+The shard smoke gate (scripts/shard_smoke.py) and the sharding
+property tests judge the sharded path against the SAME independent
+derivation: per-rule predicate truth via
 the compiler's SnapshotOracle programs (the conformance oracle every
 device program is pinned against) and per-rule check statuses via
 compiler/ruleset.fused_check_status (the one host-side decision-status

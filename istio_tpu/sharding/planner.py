@@ -170,9 +170,8 @@ class ShardPlan:
         return zlib.crc32(ns.encode("utf-8", "replace")) % self.n_shards
 
     def balance(self) -> dict:
-        """Shard-balance summary — the fleet bench's
-        `fleet_shard_balance` payload and the planner property tests'
-        judged surface."""
+        """Shard-balance summary — what /debug/shards and the shard
+        smoke read, and the planner property tests' judged surface."""
         costs = [float(c) for c in self.shard_cost]
         mean = sum(costs) / max(len(costs), 1)
         ns_per = [0] * self.n_shards

@@ -261,8 +261,8 @@ class ReplicaRouter:
     def routing_stats(self) -> dict:
         """Cross-lane routing aggregate — THE single home of the
         rows-per-shard / occupancy / misroute fold every consumer
-        reads (introspect /debug/shards, the fleet bench, the shard
-        smoke's conservation gates)."""
+        reads (introspect /debug/shards, the shard smoke's
+        conservation gates)."""
         rows: dict[str, int] = dict(self._retired_rows)
         misrouted = self._retired_misrouted
         for r in self._routers:

@@ -1,7 +1,6 @@
 """Whole-mesh chaos soak (ROADMAP item 5's open leg).
 
-Three pieces, composed by scripts/soak_smoke.py and bench.py's
-`soak_*` section:
+Three pieces, composed by scripts/soak_smoke.py:
 
   * fleet.FleetSimulator — N simulated sidecars running the full
     client lifecycle concurrently (discovery watch + config-version

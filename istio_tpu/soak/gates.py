@@ -146,10 +146,17 @@ def evaluate_gates(srv, fleet_totals: dict, base: dict, *,
     kinds = _matched_kinds(ex)
     gates["fault_kinds"] = len(kinds) >= min_kinds
     detail["fault_kinds"] = sorted(kinds)
-    detail["explainability"] = {"rate": ex["rate"],
-                                "matched": ex.get("matched", 0),
-                                "unexplained": ex.get("unexplained",
-                                                      0)}
+    detail["explainability"] = {
+        "rate": ex["rate"],
+        "matched": ex.get("matched", 0),
+        "unexplained": ex.get("unexplained", 0),
+        "pending": ex.get("pending", 0),
+        # the injections still waiting for their evidence: a failed
+        # gate has to name them
+        "unmatched": [{k: r[k] for k in ("kind", "detail", "n",
+                                         "expired")}
+                      for r in ex.get("records", ())
+                      if not r["matched"]]}
 
     # zero stale-generation serves: the watermark must never show
     # grants issued beyond the live generation, and the audited

@@ -1,7 +1,6 @@
 """The soak rig: build the whole mesh in-process, run the phases,
-gate the recovery. scripts/soak_smoke.py (tier-1 scale) and bench.py's
-`soak_*` section (sustained scale) are both thin wrappers over
-run_soak() — one code path, two durations, same gates.
+gate the recovery. scripts/soak_smoke.py (tier-1 scale) is a thin
+wrapper over run_soak().
 
 The harness owns every mutable endpoint so the mid-soak restart is
 just "replace what I own": the fleet reads ports through closures and
@@ -205,7 +204,7 @@ class SoakHarness:
 def run_soak(cfg: SoakConfig) -> dict:
     """Build the mesh, run warmup → storm → recovery, stop the fleet,
     evaluate the gates. Chaos/ledger state is reset on entry; the
-    caller owns the final reset (smoke/bench `finally` blocks)."""
+    caller owns the final reset (the smoke's `finally` block)."""
     from istio_tpu.runtime import monitor
     from istio_tpu.runtime.audit import INJECTIONS, SEAMS
     from istio_tpu.runtime.resilience import CHAOS

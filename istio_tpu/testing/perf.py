@@ -52,30 +52,6 @@ def make_check_payloads(dicts: Sequence[Mapping[str, Any]],
     return out
 
 
-def make_batch_check_payloads(dicts: Sequence[Mapping[str, Any]],
-                              batch_size: int,
-                              n_payloads: int = 8) -> list[bytes]:
-    """Pre-serialized BatchCheckRequest bytes (the shim protocol):
-    each payload carries `batch_size` independent bags."""
-    from istio_tpu.api import mixer_pb2 as pb
-    from istio_tpu.api.wire import bag_to_compressed, \
-        encode_batch_check_request
-    from istio_tpu.attribute.global_dict import GLOBAL_WORD_LIST
-
-    blobs = []
-    for values in dicts:
-        msg = pb.CompressedAttributes()
-        bag_to_compressed(values, msg=msg)
-        blobs.append(msg.SerializeToString())
-    out = []
-    for k in range(n_payloads):
-        batch = [blobs[(k * batch_size + i) % len(blobs)]
-                 for i in range(batch_size)]
-        out.append(encode_batch_check_request(
-            batch, len(GLOBAL_WORD_LIST)))
-    return out
-
-
 def make_report_payloads(dicts: Sequence[Mapping[str, Any]],
                          records_per_request: int = 64,
                          n_payloads: int = 8) -> list[bytes]:
@@ -303,19 +279,11 @@ def _worker(target: str, payloads: list[bytes], n_record: int,
 def run_load(target: str, payloads: Sequence[bytes],
              n_record: int = 2000, n_procs: int = 4,
              concurrency: int = 32, warmup_s: float = 2.0,
-             method: str = "/istio.mixer.v1.Mixer/Check",
-             checks_per_payload: int = 1,
-             on_go: Any = None) -> PerfReport:
+             method: str = "/istio.mixer.v1.Mixer/Check"
+             ) -> PerfReport:
     """Fire Check load at `target`; record the next `n_record`
     completions per worker after attach + warmup + steady-state, and
     report client-side numbers from those completions.
-
-    `on_go`: zero-arg callable invoked IN THIS PROCESS the moment the
-    go signal fires (warmup over, workers entering steady-state
-    detection) — the hook the bench uses to reset server-side latency
-    windows / take stage baselines so warmup traffic stays out of the
-    scraped decomposition. Exceptions are swallowed: a metrics hook
-    must never kill a measurement.
 
     Raises PerfError only if attachment fails or literally no RPC
     completes inside the recording window's hard deadline — a rig that
@@ -353,11 +321,6 @@ def run_load(target: str, payloads: Sequence[bytes],
         # worker then self-detects a steady completion rate before it
         # starts recording
         time.sleep(warmup_s)
-        if on_go is not None:
-            try:
-                on_go()
-            except Exception:
-                pass
         start_val.value = time.time()
         all_lat: list[np.ndarray] = []
         n_err = 0
@@ -412,7 +375,7 @@ def run_load(target: str, payloads: Sequence[bytes],
     rate = (n_rec_total - 1) / span if n_rec_total > 1 and span > 0 \
         else 0.0
     return PerfReport(
-        checks_per_sec=rate * checks_per_payload,
+        checks_per_sec=rate,
         p50_ms=float(np.percentile(lat, 50) * 1e3),
         p99_ms=float(np.percentile(lat, 99) * 1e3),
         mean_ms=float(lat.mean() * 1e3),

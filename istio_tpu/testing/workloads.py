@@ -120,10 +120,9 @@ def make_store(n_rules: int, n_services: int | None = None,
 
     `host_overlay_every`: every Nth rule additionally carries work the
     device GENUINELY cannot absorb — the host-overlay-heavy shape
-    (VERDICT r2 weak #4) whose per-request python cost the overlay
-    bench measures. r4's device lowering learned REGEX-entry lists and
-    silently emptied the old overlay workload (`overlay_rules: 0`);
-    the three shapes now cycle through the reference's genuinely
+    (VERDICT r2 weak #4). The device lowering learned REGEX-entry
+    lists (r4), which would empty a workload built on them;
+    the three shapes cycle through the reference's genuinely
     host-bound list semantics (mixer/adapter/list/list.go:115-247):
     case-insensitive membership, provider-refreshed entries (the TTL
     refresh loop — entries change between requests, so no compiled
@@ -161,7 +160,7 @@ def make_store(n_rules: int, n_services: int | None = None,
         "template": "listentry", "params": {"value": "source.namespace"}})
     # REPORT-path traffic (grpcServer.go:262 → dispatcher.Report →
     # metric adapter): a request-count metric into prometheus — the
-    # served report bench drives this through the real gRPC surface
+    # report smoke drives this through the real wire
     s.set(("handler", "istio-system", "prom"), {
         "adapter": "prometheus",
         "params": {"metrics": [{
@@ -629,8 +628,8 @@ def make_full_mesh(n_services: int = 5000, n_roles: int = 1000,
 
     → (engine, route_lo, route_hi, route_weights, meta dict).
     Row layout: [SAN rules | quota rule | authz rule | route rows |
-    rbac pseudo-rows]. The full step wrapper (bench.py) computes check
-    verdicts AND winning routes from the same matched plane.
+    rbac pseudo-rows]. Check verdicts and route matches are read from
+    the same matched plane.
     """
     from istio_tpu.compiler.rbac_lower import lower_rbac
     from istio_tpu.expr.parser import parse

@@ -3,7 +3,7 @@ exposition.
 
 Role of the reference's Prometheus self-monitoring (mixer/pkg/runtime/
 monitor.go:34-88, pilot discovery.go:53-113). Host-side only — device-side
-perf comes from the bench harness.
+perf comes from `benchmark/` (a profiler trace on the chip).
 """
 from __future__ import annotations
 
@@ -181,7 +181,7 @@ class SlidingWindow:
     quantiles computed on read (the live-p99 counterpart of Histogram's
     bucket-bounded quantile()). observe() is hot-path cheap (deque
     append under a lock); quantile() sorts a snapshot and is meant for
-    scrape-rate readers (the introspect server, bench scrapes)."""
+    scrape-rate readers (the introspect server)."""
 
     def __init__(self, capacity: int = 4096):
         if capacity <= 0:

@@ -369,9 +369,9 @@ def get_tracer() -> Tracer:
 
 def capture(service_name: str = "capture"):
     """Temporarily swap in a MemoryReporter-backed tracer →
-    (reporter, restore_fn). The bench uses it to decompose served
-    latency into the pipeline's stage spans (queue-wait / tensorize /
-    device / overlay) without a zipkin endpoint."""
+    (reporter, restore_fn): a test reads the pipeline's stage spans
+    (queue-wait / tensorize / device / overlay) without a zipkin
+    endpoint."""
     global _global
     prev = _global
     mem = MemoryReporter()

@@ -3,8 +3,8 @@
 
 Three contracts pinned, FAIL (nonzero exit) on any breach:
 
-1. SECTION KEYS — `_roofline_fields` (the helper every bench perf
-   section routes through) emits `<prefix>fraction_of_roof` and a
+1. SECTION KEYS — `roofline.bench_fields` emits
+   `<prefix>fraction_of_roof` and a
    named `<prefix>bound` in {hbm, mxu, host} for the headline-,
    rbac-, full-mesh- and capacity-shaped engines. If a section's
    roofline ever silently degrades to its `*_roofline_error`
@@ -62,11 +62,11 @@ def main(n_rules: int = 64) -> int:
     failures: list[str] = []
     batch = 64
 
-    # ---- 1. every bench perf section's roofline fields ----
+    # ---- 1. roofline fields for each engine family ----
     engines = {}
     engines["headline_"] = workloads.make_engine(
         n_rules=n_rules, with_quota=True, jit=False)
-    # capacity section: same engine family, no quota (bench parity)
+    # capacity section: same engine family, no quota
     engines["capacity_"] = workloads.make_engine(
         n_rules=n_rules, with_quota=False, jit=False)
     snap = SnapshotBuilder(

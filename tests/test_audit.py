@@ -114,6 +114,38 @@ def test_injection_ledger_coalesces_and_matches_by_event():
     assert recs[0]["matched_by"] == "event:breaker device"
 
 
+def test_injection_ledger_matches_device_fault_absorbed_by_retry():
+    """One injected device failure whose retry succeeds moves no
+    fallback counter and no breaker: the retry counter is its only
+    evidence. Without it the record stays pending for the whole
+    explain window (the soak's explainability gate under load)."""
+    from istio_tpu.runtime.resilience import (CHAOS, ResilienceConfig,
+                                              ResilientChecker)
+
+    forensics.EVENTS.reset()    # no neighbour's breaker event in reach
+    led = InjectionLedger()
+    prev, CHAOS.on_inject = CHAOS.on_inject, led.note
+    try:
+        def device(bags):
+            CHAOS.device_step()
+            return list(bags)
+
+        chk = ResilientChecker(
+            device, lambda bags: ["oracle"] * len(bags),
+            ResilienceConfig(retry_backoff_s=0.0, retry_jitter_s=0.0))
+        CHAOS.device_failures = 1
+        fb0 = monitor.resilience_counters()["fallback_total"]
+        assert chk.run_batch(["a", "b"]) == ["a", "b"]
+        assert monitor.resilience_counters()["fallback_total"] == fb0
+    finally:
+        CHAOS.on_inject = prev
+        CHAOS.reset()
+    out = led.evaluate(window_s=30.0)
+    assert out["pending"] == 0 and out["matched"] == 1, out
+    assert out["records"][-1]["matched_by"] == \
+        "counter:device_retries_total"
+
+
 def test_injection_ledger_expires_unmatched():
     led = InjectionLedger()
     led.note("oracle")                  # nothing will explain it
@@ -221,3 +253,7 @@ def test_audit_plane_snapshot_and_evaluate(srv):
         monitor.AUDIT_INVARIANTS)
     assert snap["healthy"] is True
     assert 0.0 <= snap["explainability"]["rate"] <= 1.0
+    # sizes no ServerArgs field sets: the rings' and the auditor's own
+    assert srv.audit.quota_every == 8
+    assert forensics.RECORDER.snapshot()["capacity"] == 256
+    assert forensics.EVENTS._buf.maxlen == 512

@@ -1,6 +1,6 @@
-"""Tier-1 hook for scripts/roofline_smoke.py: the CI gate that every
-bench perf section's roofline fields (`*_fraction_of_roof`, a named
-`*_bound`) stay emitted and that the model's bytes-per-step
+"""Tier-1 hook for scripts/roofline_smoke.py: the CI gate that
+`roofline.bench_fields` (`*_fraction_of_roof`, a named `*_bound`)
+stays emitted for each engine family and that the model's bytes-per-step
 prediction matches the compiled shapes exactly (h2d batch planes,
 d2h packed pull, index-tensor params). Runs main() in-process."""
 import importlib.util

@@ -1,7 +1,10 @@
 """Tier-1 wrapper for scripts/soak_smoke.py — the whole-mesh chaos
 soak must pass its recovery gates in-process, twice, with the SAME
 seed producing the SAME injection schedule and the SAME gate verdicts
-(the seed/replay contract), inside a hard wall-clock budget."""
+(the seed/replay contract), inside a CPU-time budget (the soak's wall
+is bounded by the harness's own recovery and quiesce timeouts; what
+the budget guards is the work it does, which other xdist workers'
+load does not change)."""
 import importlib.util
 import os
 import sys
@@ -11,7 +14,7 @@ import pytest
 
 SCRIPT = os.path.join(os.path.dirname(__file__), os.pardir,
                       "scripts", "soak_smoke.py")
-WALL_BUDGET_S = 90.0
+CPU_BUDGET_S = 90.0     # the pair costs ~35 s of CPU alone
 
 
 def _run(seed: int) -> dict:
@@ -31,10 +34,10 @@ def _run(seed: int) -> dict:
 
 @pytest.mark.filterwarnings("ignore")
 def test_soak_smoke_deterministic():
-    t0 = time.monotonic()
+    t0 = time.process_time()
     a = _run(0)
     b = _run(0)
-    wall = time.monotonic() - t0
+    cpu = time.process_time() - t0
 
     # seed/replay contract: same seed -> byte-identical injection
     # schedule and identical gate verdicts
@@ -52,6 +55,6 @@ def test_soak_smoke_deterministic():
     assert a["metrics"]["soak_explainability_rate"] == 1.0
     assert a["restarts"] == 1
 
-    assert wall <= WALL_BUDGET_S, (
-        f"soak smoke pair took {wall:.1f}s "
-        f"(budget {WALL_BUDGET_S}s)")
+    assert cpu <= CPU_BUDGET_S, (
+        f"soak smoke pair took {cpu:.1f}s of CPU "
+        f"(budget {CPU_BUDGET_S}s)")
