@@ -48,7 +48,8 @@ HOT_ROOTS: tuple[str, ...] = (
     # dispatch: direct + fused check, report coalescer dispatch
     "Dispatcher.check", "Dispatcher._check_fused", "Dispatcher.report",
     # packed device trips
-    "FusedPlan.packed_check", "FusedPlan.packed_report",
+    "FusedPlan.packed_check", "FusedPlan._launch_step",
+    "FusedPlan._launch_apart", "FusedPlan.packed_report",
     "FusedPlan.packed_check_instep",
     # report ingestion (ack-after-enqueue admission + worker hook)
     "RuntimeServer.submit_report", "RuntimeServer._run_report_batch",
@@ -66,7 +67,8 @@ HOT_ROOTS: tuple[str, ...] = (
     "RouteScopeProgram.admit_rows",
     # canary tap + rule telemetry fold (run inside the batch step)
     "TrafficRecorder.tap",
-    "RuleTelemetry.observe", "RuleTelemetry.add_host",
+    "RuleTelemetry.observe", "RuleTelemetry.chain",
+    "RuleTelemetry.add_host",
     "RuleTelemetry.sample", "RuleTelemetry.drain",
     # flight-recorder tape primitives (per-batch/per-stage)
     "FlightRecorder.batch_begin", "FlightRecorder.stage_mark",

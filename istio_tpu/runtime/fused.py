@@ -192,6 +192,11 @@ class FusedPlan:
     # in overlay_cols and their names merge host-side
     unmapped_instance_attrs: dict = dataclasses.field(default_factory=dict)
     _ns_pred_cache: dict = dataclasses.field(default_factory=dict)
+    # the dp×mp mesh build_fused_plan sharded the engine step over, or
+    # None: what packed_check selects its launch on (see there)
+    mesh: Any = None
+    # jit of _base_step: THE program of a served Check batch off a mesh
+    _step: Any = None
     _packer: Any = None
     # compiled REPORT instance-field programs (runtime/report_lower.py)
     # — None when no report instance lowered; the dispatcher then keeps
@@ -199,9 +204,9 @@ class FusedPlan:
     report_lowering: Any = None
     # on-device per-rule hit/deny/err accumulators + exemplar
     # reservoirs (runtime/rulestats.RuleTelemetry) — None when rule
-    # telemetry is disabled (ServerArgs.rule_telemetry=False / bench
-    # off-phase). Folded by packed_check/packed_check_instep on check
-    # batches only; drained off the hot path by the aggregator.
+    # telemetry is disabled (ServerArgs.rule_telemetry=False). Folded
+    # by packed_check (inside its one program) and packed_check_instep
+    # on check batches only; drained off the hot path by the aggregator.
     telemetry: Any = None
     _report_packer: Any = None
     _instep_packer: Any = None
@@ -257,7 +262,7 @@ class FusedPlan:
 
     def packed_check(self, batch, ns_ids, observe: bool = True,
                      n_real: int | None = None) -> np.ndarray:
-        """engine.check + device-side packing into ONE int32 array
+        """The engine step + device-side packing into ONE int32 array
         [5 + W + C, B] pulled with a single host↔device sync (W =
         n_ref_words, C = len(overlay_cols)). Pulling plane-by-plane
         costs one sync per plane (chip_smoke.py prints
@@ -273,12 +278,18 @@ class FusedPlan:
 
         `n_real`: count of non-padding rows (the leading prefix);
         rows past it are bucket padding the rule-telemetry fold must
-        ignore. None = every row is real."""
-        import jax
+        ignore. None = every row is real.
 
+        Off a mesh the step, the rule-telemetry delta, its fold into
+        the resident accumulators and the packer are ONE jitted
+        program (_base_step): a launch costs ~0.6–2 ms of host wall
+        whatever it computes, and the verdict's planes never leave
+        the program. Under a mesh the engine step is
+        shard_engine_check's own jit, so the same closures are
+        launched apart behind it (_launch_apart)."""
         batch = self.narrow_batch(batch)   # latency-tier byte plane
-        if self._packer is None:
-            self._packer = jax.jit(self._base_packer())
+        ns_arr = np.asarray(ns_ids)        # hotpath: sync-ok (host ids)
+        b = ns_arr.shape[0]
         if observe:
             w = int(batch.str_bytes.shape[2])
             self._tier_served[w] = self._tier_served.get(w, 0) + 1
@@ -291,30 +302,25 @@ class FusedPlan:
             # fallback never trip the breaker.
             from istio_tpu.runtime.resilience import CHAOS
             CHAOS.device_step()
-        # stage `h2d` = three async program dispatches (engine step,
-        # rule-telemetry fold, packer) with the implicit transfer of
-        # every jit argument, each under its own dispatch.* span;
-        # stage `device_step` = the one blocking pull (waits the
-        # programs out, then D2H). `observe=False` for non-Check
-        # callers (prewarm dummy batches — a compile would dwarf every
-        # real observation — and the fused report fallback): only
-        # check trips feed the Check() decomposition.
+        # rows the rule telemetry counts. `observe=False` (prewarm
+        # dummy batches — a compile would dwarf every real
+        # observation — and the fused report fallback, which is
+        # REPORT traffic) runs the same executable with none: prewarm
+        # compiles exactly what is served, and counts nothing. Only
+        # check trips feed the Check() decomposition either.
+        counted = np.int32((b if n_real is None else n_real)
+                           if observe else 0)
+        # stage `h2d` = the async launch with the implicit transfer
+        # of every jit argument; stage `device_step` = the one
+        # blocking pull (waits the program out, then D2H).
         with monitor.stage("h2d", on=observe):
-            with monitor.span("dispatch.step", on=observe):
-                verdict = self.engine.check(batch, ns_ids)
-            ns_arr = np.asarray(ns_ids)    # hotpath: sync-ok (host ids)
-            if observe and self.telemetry is not None:
-                # per-rule hit/deny/err fold into the resident device
-                # accumulators — async dispatch only, the drain thread
-                # pays the pull. Check traffic only (observe gates out
-                # prewarm dummies and the fused report fallback).
-                with monitor.span("dispatch.rulestats"):
-                    b = ns_arr.shape[0]
-                    real = np.arange(b) < (b if n_real is None
-                                           else n_real)
-                    self.telemetry.observe(verdict, ns_arr, real)
-            with monitor.span("dispatch.pack", on=observe):
-                dev = self._packer(verdict, ns_arr)
+            if self.mesh is not None:
+                dev = self._launch_apart(batch, ns_arr, counted, observe)
+            else:
+                with monitor.span("dispatch.step", on=observe):
+                    dev = self._launch_step(batch, ns_arr, counted)
+                if observe:
+                    monitor.note_device_programs("check", 1)
         with monitor.stage("device_step", on=observe):
             # the single host<->device sync — hotpath: sync-ok
             out = np.asarray(dev)              # hotpath: sync-ok
@@ -323,6 +329,95 @@ class FusedPlan:
             self._warmed_shapes.add((int(batch.ids.shape[0]),
                                      int(batch.str_bytes.shape[2])))
         return out
+
+    def _launch_step(self, batch, ns_arr, counted):
+        """Launch the one program of a Check batch (async) and return
+        the packed verdict's device handle. With a telemetry plane a
+        batch that counts rides the accumulator handles through the
+        program under RuleTelemetry's lock (chain): per-rule counts
+        stay exact under concurrent pumps and a drain's swap."""
+        import jax
+
+        if self._step is None:
+            self._step = jax.jit(self._base_step())
+        step, eng, tele = self._step, self.engine, self.telemetry
+        if tele is None:
+            return step(eng.params, batch, ns_arr, eng.quota_counts,
+                        counted)
+        shape = (int(batch.ids.shape[0]), int(batch.str_bytes.shape[2]))
+        if not counted or shape not in self._warmed_shapes:
+            # a trip that counts nothing (prewarm, Report traffic)
+            # runs on the resident zeros and leaves the live
+            # accumulators and their lock alone; so does the first
+            # trip at a shape, ahead of its counted one: a compile
+            # under that lock would stop the other pump and the drain
+            dev, _ = step(eng.params, batch, ns_arr, eng.quota_counts,
+                          np.int32(0), *tele.zeros)
+            if not counted:
+                return dev
+        return tele.chain(
+            lambda *accs: step(eng.params, batch, ns_arr,
+                               eng.quota_counts, counted, *accs))
+
+    def _launch_apart(self, batch, ns_arr, counted, observe: bool):
+        """packed_check under a mesh: the sharded engine step, the
+        rule-telemetry delta + fold and the packer as four launches,
+        each under its own dispatch.* span."""
+        import jax
+
+        if self._packer is None:
+            self._packer = jax.jit(self._base_packer())
+        with monitor.span("dispatch.step", on=observe):
+            verdict = self.engine.check(batch, ns_arr)
+        programs = 2                       # the step and the packer
+        if observe and self.telemetry is not None:
+            with monitor.span("dispatch.rulestats"):
+                self.telemetry.observe(
+                    verdict, ns_arr, np.arange(ns_arr.shape[0]) < counted)
+            programs += self.telemetry.OBSERVE_PROGRAMS
+        with monitor.span("dispatch.pack", on=observe):
+            dev = self._packer(verdict, ns_arr)
+        if observe:
+            monitor.note_device_programs("check", programs)
+        return dev
+
+    def _base_step(self):
+        """The step(params, batch, req_ns, quota_counts, n_real[,
+        acc_hit, acc_deny, acc_err]) closure _launch_step jits: the
+        engine's raw step, RuleTelemetry's pure delta folded into the
+        accumulators passed through, and _base_packer's pack, composed
+        in one trace. `n_real` is a traced scalar: rows at or past it
+        count nothing (0 for prewarm dummies and Report traffic: one
+        executable a shape). Without a telemetry plane the program is
+        step + pack and returns `packed` alone.
+
+        It is NAMED `step`: the profiler then calls it `jit_step`,
+        which is what the benchmark's device readers look for
+        (benchmark/scopes.py STEP_MODULE), and the named scopes of
+        the three closures (match … combine, rulestats, pack) ride
+        inside it. The served engine carries no device quota
+        (build_fused_plan passes quotas=()), so the counts the raw
+        step returns are the dummy it was handed."""
+        import jax
+        import jax.numpy as jnp
+
+        raw_step = self.engine.raw_step
+        pack = self._base_packer()
+        delta = None if self.telemetry is None else self.telemetry.delta
+
+        def step(params, batch, req_ns, quota_counts, n_real, *accs):
+            verdict, _counts = raw_step(params, batch, req_ns,
+                                        quota_counts)
+            packed = pack(verdict, req_ns)
+            if delta is None:
+                return packed
+            real = jnp.arange(req_ns.shape[0]) < n_real
+            deltas = delta(verdict.matched, verdict.err, verdict.status,
+                           verdict.deny_rule, req_ns, real)
+            with jax.named_scope("rulestats"):
+                return packed, tuple(a + d for a, d in zip(accs, deltas))
+
+        return step
 
     def _base_packer(self):
         """The pack(verdict, req_ns) closure shared by packed_check and
@@ -427,41 +522,60 @@ class FusedPlan:
         batch = self.narrow_batch(batch)   # latency-tier byte plane
         # report traffic feeds the served-shape set too: the pre-swap
         # warm must cover the shapes the report coalescer serves (its
-        # packer compiles per shape like the check packer's), or the
-        # first post-swap report trip pays an in-band trace — there is
-        # no oracle bridge on the report path
+        # field program compiles per shape like the check program),
+        # or the first post-swap report trip pays an in-band trace —
+        # there is no oracle bridge on the report path
         if observe:
             key = (int(batch.ids.shape[0]),
                    int(batch.str_bytes.shape[2]))
             self._shape_served[key] = \
                 self._shape_served.get(key, 0) + 1
-        if self._report_packer is None:
-            self._report_packer = jax.jit(self._base_report_packer())
-        verdict = self.engine.check(batch, ns_ids)
-        return np.asarray(                 # hotpath: sync-ok (the pull)
-            self._report_packer(
-                verdict,
-                np.asarray(ns_ids),        # hotpath: sync-ok (host ids)
-                batch))
+        ns_arr = np.asarray(ns_ids)        # hotpath: sync-ok (host ids)
+        if self.mesh is not None:
+            if self._report_packer is None:
+                self._report_packer = jax.jit(self._base_report_packer())
+            dev = self._report_packer(self.engine.check(batch, ns_arr),
+                                      ns_arr, batch)
+        else:
+            # the field planes read the batch alone, so the Check
+            # program's packed rows ARE the head: launch it counting
+            # nothing (REPORT traffic) and append the fields to its
+            # handle. No second step-bearing program a shape to
+            # compile, keep resident and prewarm.
+            if self._report_packer is None:
+                self._report_packer = jax.jit(self._base_report_fields())
+            dev = self._report_packer(
+                self._launch_step(batch, ns_arr, np.int32(0)), batch)
+        return np.asarray(dev)             # hotpath: sync-ok (the pull)
 
-    def _base_report_packer(self):
-        """The packr(verdict, req_ns, batch) closure packed_report
-        jits: _base_packer's rows plus the report field planes."""
+    def _base_report_fields(self):
+        """The fields(packed, batch) closure: packed_check's rows with
+        the report field planes appended."""
         import jax.numpy as jnp
 
-        pack = self._base_packer()
         rl = self.report_lowering
         n_f = rl.n_fields
         n_w = rl.n_valid_words
 
-        def packr(verdict, req_ns, fbatch):
-            head = pack(verdict, req_ns)
+        def fields(head, fbatch):
             vals, valid = rl.field_planes(fbatch)
             b = vals.shape[1]
             vpad = jnp.zeros((b, n_w * 32), bool)
             vpad = vpad.at[:, :n_f].set(valid.T)
             return jnp.concatenate(
                 [head, vals, pack_bool_rows(vpad, n_w)], axis=0)
+
+        return fields
+
+    def _base_report_packer(self):
+        """The packr(verdict, req_ns, batch) closure packed_report jits
+        under a mesh, behind the sharded step: _base_packer's rows plus
+        the report field planes."""
+        pack = self._base_packer()
+        fields = self._base_report_fields()
+
+        def packr(verdict, req_ns, fbatch):
+            return fields(pack(verdict, req_ns), fbatch)
 
         return packr
 
@@ -559,6 +673,10 @@ class FusedPlan:
                 q["buckets"], q["amounts"], q["be"], q["mx"],
                 q["active"], q["ticks"], q["lasts"], q["rolling"],
                 q["rule_idx"])
+        if served:
+            monitor.note_device_programs(
+                "instep", 2 if self.telemetry is None
+                else 2 + self.telemetry.OBSERVE_PROGRAMS)
         self._warmed_shapes.add((int(batch.ids.shape[0]),
                                  int(batch.str_bytes.shape[2])))
         return out
@@ -581,12 +699,14 @@ class FusedPlan:
         return frozen
 
     def cache_stats(self) -> dict:
-        """Compiled-program cache occupancy per packer (one entry per
-        warmed bucket shape) — the /debug/cache payload's compile-cache
-        half. A serving bucket missing here means the next batch at
-        that shape pays an in-band XLA trace."""
+        """Compiled-program cache occupancy per jitted serving program
+        (one entry per warmed bucket shape) — the /debug/cache
+        payload's compile-cache half. A serving bucket missing here
+        means the next batch at that shape pays an in-band XLA
+        trace."""
         out: dict[str, Any] = {}
-        for name in ("_packer", "_report_packer", "_instep_packer"):
+        for name in ("_step", "_packer", "_report_packer",
+                     "_instep_packer"):
             f = getattr(self, name, None)
             if f is None:
                 continue
@@ -646,12 +766,12 @@ class FusedPlan:
 
     def warm_shapes(self, pairs, should_stop=None,
                     backoff=None) -> None:
-        """Compile the SERVING entry (engine step + packer — the
-        packer gather is its own XLA program — plus the report packer
-        when report instances lowered) for each (bucket, byte-tier)
-        pair. `backoff` is called between shapes: the config-swap path
-        passes a serving-latency yield (controller._serving_backoff)
-        so a loaded single core keeps serving while this thread traces
+        """Compile the SERVING entry (packed_check's program(s), plus
+        packed_report's field program when report instances lowered)
+        for each (bucket, byte-tier) pair. `backoff` is
+        called between shapes: the config-swap path passes a
+        serving-latency yield (controller._serving_backoff) so a
+        loaded single core keeps serving while this thread traces
         jaxprs — the warm yields to traffic, never the reverse."""
         from istio_tpu.runtime import forensics
         for b, tier in pairs:
@@ -1034,7 +1154,7 @@ def build_fused_plan(snapshot: Snapshot,
                              ("deny", deny_info)):
         decided_section[np.asarray(sorted(members), np.intp)] = \
             _BY[section]
-    return FusedPlan(engine=engine, native=native,
+    return FusedPlan(engine=engine, native=native, mesh=mesh,
                      telemetry=telemetry,
                      decided_section=decided_section,
                      # AFTER every compile above (engine, report

@@ -171,6 +171,32 @@ def check_decided_counters() -> dict:
     }
 
 
+# -- device programs launched for served Check batches. `path`: the
+# launch site (FusedPlan.packed_check -> check, packed_check_instep ->
+# instep). Over the count of span dispatch.step, which every served
+# batch observes once at either site, it is the programs a batch pays
+# to launch: 1 where the step, the rule-telemetry fold and the packer
+# are one jit, 4 where they are launched apart (in-step quota, a
+# mesh). Prewarm dummies and Report traffic count nothing.
+DEVICE_PROGRAM_PATHS = ("check", "instep")
+DEVICE_PROGRAMS = prometheus_client.Counter(
+    "mixer_device_programs_total",
+    "device programs launched for served check batches, by launch site",
+    ["path"], registry=REGISTRY)
+for _r in DEVICE_PROGRAM_PATHS:
+    DEVICE_PROGRAMS.labels(path=_r)
+
+
+def note_device_programs(path: str, n: int) -> None:
+    DEVICE_PROGRAMS.labels(path=path).inc(n)
+
+
+def device_program_counters() -> dict:
+    """{path: programs launched} as one JSON-able dict."""
+    return {path: int(DEVICE_PROGRAMS.labels(path=path)._value.get())
+            for path in DEVICE_PROGRAM_PATHS}
+
+
 # -- adapter-executor plane (runtime/executor.py) --------------------
 #
 # Conservation invariant (the report plane's doctrine applied to host
@@ -403,10 +429,13 @@ def identity_counters() -> dict:
 #                 (native wire path, overlap_h2d) the one explicit
 #                 device_put of the byte plane, AND the ns ids; split
 #                 by the spans tensorize.decode / .stage_put / .ns_ids
-#   h2d         — misnamed, kept for its readers: three program
-#                 DISPATCHES (engine step, rule-telemetry fold, packer)
-#                 with the implicit transfer of every jit argument;
-#                 split by the spans dispatch.step / .rulestats / .pack
+#   h2d         — misnamed, kept for its readers: the LAUNCH of the
+#                 batch's device program(s) with the implicit transfer
+#                 of every jit argument. One program (span
+#                 dispatch.step) on FusedPlan.packed_check; step,
+#                 rule-telemetry fold and packer launched apart (spans
+#                 dispatch.step / .rulestats / .pack) for an in-step
+#                 quota batch or under a mesh
 #   device_step — the blocking device->host pull of the packed verdict
 #                 (waits out the programs dispatched in `h2d`, then
 #                 the D2H copy; ~0.9 ms of sync on a local chip)

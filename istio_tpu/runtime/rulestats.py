@@ -110,19 +110,26 @@ class RuleTelemetry:
     where S = len(ns_ids) + 1; the extra slot collects requests whose
     namespace is unknown to the snapshot (namespace_id() == -1).
 
-    `observe()` runs on the batch hot path: one jitted delta program
-    over the verdict (pure, dispatched async) plus one jitted fold
-    chained onto the accumulators under a lock — dispatch only, no
-    host↔device sync. Padding rows are masked out by the caller's
-    `real_mask` so bucket padding never pollutes the counts.
+    Two ways onto the accumulators, both dispatch only (no
+    host↔device sync), both chained under one lock. `chain()` hands
+    the accumulator handles to a program that computes `delta` itself
+    and returns the folded accumulators: FusedPlan.packed_check's one
+    program a batch. `observe()` is the same arithmetic as two programs
+    of its own (jitted delta, jitted fold) behind a verdict some other
+    program produced: the in-step quota and mesh paths. Padding rows
+    are masked out by `real` so bucket padding never pollutes the
+    counts.
     Host-fallback rules read matched=False on device; their hits and
     errors arrive through `add_host()` at the dispatcher's overlay
     patch point, into host-side numpy planes merged at drain.
 
-    `drain()` swaps fresh zero accumulators in under the lock (cheap
-    device allocs, no sync) and pulls the OLD buffers outside it — the
+    `drain()` swaps the resident zero accumulators in under the lock
+    (no alloc, no sync) and pulls the OLD buffers outside it — the
     only device→host copy, generation-tagged, never on the batch
     critical path."""
+
+    # device programs one observe() launches (_delta_fn, _fold_fn)
+    OBSERVE_PROGRAMS = 2
 
     def __init__(self, ruleset, n_cfg: int, exemplars_per_rule: int = 4,
                  seed: int = 0):
@@ -144,9 +151,11 @@ class RuleTelemetry:
         self._lock = threading.Lock()
         self.generation = 0
         zeros2 = jnp.zeros((self.n_slots, self.n_rows), jnp.int32)
-        self._acc_hit = zeros2
-        self._acc_deny = zeros2
-        self._acc_err = jnp.zeros(self.n_rows, jnp.int32)
+        # resident all-zero accumulators (device arrays are immutable):
+        # what a drain swaps in, and what a program runs on when its
+        # batch counts nothing (prewarm, Report traffic: no lock)
+        self.zeros = (zeros2, zeros2, jnp.zeros(self.n_rows, jnp.int32))
+        self._acc_hit, self._acc_deny, self._acc_err = self.zeros
         # host-side planes for host-fallback rules (overlay patch)
         self._host_hit = np.zeros((self.n_slots, self.n_rows), np.int64)
         self._host_err = np.zeros(self.n_rows, np.int64)
@@ -156,8 +165,11 @@ class RuleTelemetry:
         self._ex: dict[int, list] = {}
         self._ex_seen: dict[int, int] = {}
         self._rng = random.Random(seed)
-        self._delta_fn = jax.jit(self._make_delta(
-            rule_ns, self._default_ns, self.n_slots, err_rows))
+        # pure: (matched, err, status, deny_rule, req_ns, real) ->
+        # (hit, deny, err) deltas; traced into whichever program folds
+        self.delta = self._make_delta(
+            rule_ns, self._default_ns, self.n_slots, err_rows)
+        self._delta_fn = jax.jit(self.delta)
         self._fold_fn = jax.jit(
             lambda h, d, e, dh, dd, de: (h + dh, d + dd, e + de))
 
@@ -219,6 +231,22 @@ class RuleTelemetry:
                 self._fold_fn(self._acc_hit, self._acc_deny,
                               self._acc_err, *deltas)
 
+    def chain(self, launch):
+        """Run `launch(acc_hit, acc_deny, acc_err) -> (out, (acc_hit',
+        acc_deny', acc_err'))` on the current accumulator handles and
+        keep what it hands back; returns `out`. `launch` dispatches the
+        program that folds one batch (async, never a sync) and the lock
+        is held across it: handles are read, passed and replaced as one
+        step, so concurrent pumps chain their folds, none is lost or
+        doubled, and a drain() swap falls before or after a batch,
+        never inside it. A `launch` that raises leaves the handles as
+        they were. The lock is no place to compile: a caller whose
+        program may not be compiled yet runs it on `zeros` first."""
+        with self._lock:
+            out, (self._acc_hit, self._acc_deny, self._acc_err) = launch(
+                self._acc_hit, self._acc_deny, self._acc_err)
+        return out
+
     def add_host(self, cols, active_cols: np.ndarray,
                  err_counts: Mapping[int, int],
                  ns_slots: np.ndarray) -> None:
@@ -268,19 +296,14 @@ class RuleTelemetry:
     # ------------------------------------------------------------------
 
     def drain(self) -> dict:
-        """Swap fresh zero accumulators in (no sync) and pull the old
+        """Swap zero accumulators in (no sync) and pull the old
         buffers — generation-tagged deltas since the previous drain.
         Exemplars are a sample, not a counter: returned as the current
         reservoirs (bags still encoded), not reset."""
-        import jax.numpy as jnp
-
         t0 = time.perf_counter()
         with self._lock:
             hit, deny, err = self._acc_hit, self._acc_deny, self._acc_err
-            zeros2 = jnp.zeros((self.n_slots, self.n_rows), jnp.int32)
-            self._acc_hit = zeros2
-            self._acc_deny = zeros2
-            self._acc_err = jnp.zeros(self.n_rows, jnp.int32)
+            self._acc_hit, self._acc_deny, self._acc_err = self.zeros
             host_hit, self._host_hit = self._host_hit, np.zeros(
                 (self.n_slots, self.n_rows), np.int64)
             host_err, self._host_err = self._host_err, np.zeros(

@@ -60,7 +60,8 @@ HOT_SECTIONS: dict[str, frozenset[str]] = {
         "Dispatcher._apply_device_status", "Dispatcher._combine",
     }),
     "istio_tpu/runtime/fused.py": frozenset({
-        "FusedPlan.packed_check", "FusedPlan.packed_report",
+        "FusedPlan.packed_check", "FusedPlan._launch_step",
+        "FusedPlan._launch_apart", "FusedPlan.packed_report",
         "FusedPlan.packed_check_instep", "FusedPlan.narrow_batch",
         "FusedPlan.swap_warm_pending", "FusedPlan._serve_width",
     }),
@@ -72,7 +73,8 @@ HOT_SECTIONS: dict[str, frozenset[str]] = {
         "DeviceQuotaPool._flush",
     }),
     "istio_tpu/runtime/rulestats.py": frozenset({
-        "RuleTelemetry.observe", "RuleTelemetry.add_host",
+        "RuleTelemetry.observe", "RuleTelemetry.chain",
+        "RuleTelemetry.add_host",
         "RuleTelemetry.sample", "RuleTelemetry.drain",
     }),
     "istio_tpu/canary/recorder.py": frozenset({

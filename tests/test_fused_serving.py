@@ -429,7 +429,11 @@ def test_wire_fast_path_zero_decode():
     s.set(("rule", "istio-system", "deny-admin"), {
         "match": 'request.path.startsWith("/admin")',
         "actions": [{"handler": "denyall", "instances": ["nothing"]}]})
-    srv = RuntimeServer(s, ServerArgs(batch_window_s=0.001))
+    # no drain thread: a drain renders the denied request's exemplar
+    # (a decode, off the serving path by design) whenever it happens
+    # to fall inside the spy
+    srv = RuntimeServer(s, ServerArgs(batch_window_s=0.001,
+                                      rulestats_drain_s=0.0))
     plan = srv.controller.dispatcher.fused
     if plan.native is None:
         srv.close()
@@ -706,3 +710,218 @@ def test_fused_config_swap(servers):
     assert r.status_code == PERMISSION_DENIED
     store.delete(("rule", "istio-system", "r9-extra"))
     fused.controller.rebuild()
+
+
+# ---------------------------------------------------------------------------
+# one device program a Check batch (FusedPlan._base_step): the engine
+# step, the rule-telemetry delta + fold and the packer in one jit, held
+# to the same closures launched apart
+# ---------------------------------------------------------------------------
+
+_LONG = "/api/v1/" + "x" * 60     # past the 32-byte tier
+
+
+def _one_program_world(kind):
+    """(snapshot, request dicts): a rules + lists deployment and an
+    RBAC one at the benchmark's smoke sizes, with traffic that hits,
+    is denied and errors."""
+    from istio_tpu.runtime.config import SnapshotBuilder
+    from istio_tpu.testing import workloads
+
+    if kind == "rbac":
+        store = workloads.make_rbac_store(50)
+        dicts = workloads.make_rbac_request_dicts(40)
+    else:
+        store = workloads.make_store(200)
+        # random mesh traffic; rows aimed at rule i (every third
+        # denies); rows that lack what a rule their namespace sees
+        # reads (the service, rule5's cookie), which errors
+        dicts = workloads.make_request_dicts(8, seed=5) + [{
+            "destination.service": f"svc{i}.ns{i % 23}.svc.cluster.local",
+            "source.namespace": f"ns{(i * 5) % 25}",
+            "request.method": "GET",
+            "request.path": "/api/v0/products/1",
+            "request.host": f"x.ns{i % 23}.cluster.local",
+            "connection.mtls": True,
+            "request.headers": {"cookie": "session=0"},
+        } for i in range(30)] + [
+            {"source.namespace": "ns1"},
+            {"source.namespace": "ns5",
+             "destination.service": "svc5.ns5.svc.cluster.local"}]
+    snap = SnapshotBuilder(
+        default_manifest=workloads.MESH_MANIFEST).build(store)
+    return snap, dicts
+
+
+@pytest.fixture(scope="module", params=["rules+lists", "rbac"])
+def one_program(request):
+    """(plan serving through packed_check, a second plan of the same
+    snapshot to launch the closures apart on, request dicts)."""
+    snap, dicts = _one_program_world(request.param)
+    plan, apart = build_fused_plan(snap), build_fused_plan(snap)
+    assert plan.mesh is None and plan.telemetry is not None
+    return plan, apart, dicts
+
+
+def _padded_batch(plan, dicts, tier, bucket=64):
+    """The dicts tensorized and padded to `bucket` rows, routed to
+    byte tier `tier` (a long path on one row reaches the wide one)."""
+    import numpy as np
+
+    from istio_tpu.runtime.batcher import pad_to_bucket
+
+    dicts = [dict(d) for d in dicts]
+    if tier > min(plan.str_tiers):
+        dicts[1]["request.path"] = _LONG
+    bags = pad_to_bucket([bag_from_mapping(d) for d in dicts], (bucket,))
+    batch = plan.engine.tensorizer.tensorize(bags)
+    rs = plan.engine.ruleset
+    ns = np.asarray([rs.namespace_id(str(d.get("source.namespace", "")))
+                     for d in dicts] + [0] * (bucket - len(dicts)),
+                    np.int32)
+    ns[2] = -1                     # a namespace the snapshot never saw
+    assert int(plan.narrow_batch(batch).str_bytes.shape[2]) == tier
+    return batch, ns, len(dicts)
+
+
+@pytest.mark.parametrize("tier_at", [0, -1], ids=["narrow", "wide"])
+def test_one_program_equals_the_closures_launched_apart(one_program,
+                                                        tier_at):
+    import jax
+    import numpy as np
+
+    plan, apart, dicts = one_program
+    tier = plan.str_tiers[tier_at]
+    batch, ns, n_real = _padded_batch(plan, dicts, tier)
+    assert n_real < len(ns)
+    plan.telemetry.drain(), apart.telemetry.drain()
+    packed = plan.packed_check(batch, ns, n_real=n_real)
+
+    narrowed = apart.narrow_batch(batch)
+    verdict = apart.engine.check(narrowed, ns)
+    apart.telemetry.observe(verdict, ns, np.arange(len(ns)) < n_real)
+    want = np.asarray(jax.jit(apart._base_packer())(verdict, ns))
+
+    assert packed.dtype == want.dtype == np.int32
+    assert packed.shape == want.shape == (
+        5 + plan.n_ref_words + plan.n_overlay_words, len(ns))
+    np.testing.assert_array_equal(packed, want)
+    got, ref = plan.telemetry.drain(), apart.telemetry.drain()
+    for plane in ("hit", "deny", "err"):
+        np.testing.assert_array_equal(got[plane], ref[plane])
+    # the batch exercised what it says: hits and denials, one of them
+    # in the unknown-namespace slot's row set, padding counted nowhere
+    assert ref["hit"].sum() > 0 and ref["deny"].sum() > 0
+    assert len(set(packed[0, :n_real])) > 1
+    assert ref["hit"][-1].sum() > 0 or ref["deny"][-1].sum() > 0
+    if plan.fused_deny:            # the rules world errors as well
+        assert ref["err"].sum() > 0 and packed[4, 0] > 0
+
+
+def test_prewarm_compiles_what_is_served_and_counts_nothing(one_program):
+    plan, _, dicts = one_program
+    plan.telemetry.drain()
+    plan.warm_shapes(plan.all_warm_shapes((64,)))
+    drained = plan.telemetry.drain()
+    assert not any(drained[p].any() for p in ("hit", "deny", "err"))
+    # the program's own jit cache, not compile_cache.cache_event_counts:
+    # that one is process-wide, and other tests' servers warm in the
+    # background (the benchmark's compiles_in_window reads it, alone
+    # in its process)
+    compiled = plan._step._cache_size()
+    for tier in plan.str_tiers:
+        batch, ns, n_real = _padded_batch(plan, dicts, tier)
+        plan.packed_check(batch, ns, n_real=n_real)
+    assert plan._step._cache_size() == compiled == len(plan.str_tiers)
+    assert plan.telemetry.drain()["hit"].sum() > 0
+    # nothing was launched behind the step
+    assert plan._packer is None and plan.cache_stats()[
+        "step_entries"] == compiled
+
+
+def test_unobserved_trip_leaves_every_observer_untouched(one_program):
+    import numpy as np
+
+    from istio_tpu.runtime import monitor
+
+    plan, _, dicts = one_program
+    batch, ns, n_real = _padded_batch(plan, dicts, plan.str_tiers[0])
+    plan.telemetry.drain()
+    served = dict(plan._shape_served), dict(plan._tier_served)
+    programs = monitor.device_program_counters()
+    base = monitor.stage_baseline()
+    quiet = plan.packed_check(batch, ns, observe=False, n_real=n_real)
+    assert (dict(plan._shape_served), dict(plan._tier_served)) == served
+    assert monitor.device_program_counters() == programs
+    seen = monitor.latency_snapshot(since=base)
+    assert not seen["stages"] and not seen["spans"]
+    drained = plan.telemetry.drain()
+    assert not any(drained[p].any() for p in ("hit", "deny", "err"))
+    # the verdict itself is the served one
+    np.testing.assert_array_equal(
+        quiet, plan.packed_check(batch, ns, n_real=n_real))
+    assert monitor.device_program_counters()["check"] == \
+        programs["check"] + 1
+
+
+def test_neither_prewarm_nor_a_compile_holds_the_telemetry_lock(
+        one_program):
+    """The accumulators' lock is what the other pump and the drain wait
+    on: an unobserved trip never takes it, and the first trip at a
+    shape compiles before it does."""
+    import threading
+    import time
+
+    _, fresh, dicts = one_program     # has launched no program yet
+    batch, ns, n_real = _padded_batch(fresh, dicts, fresh.str_tiers[0])
+    assert fresh._step is None
+    done = []
+
+    def trip(observe):
+        fresh.packed_check(batch, ns, observe=observe, n_real=n_real)
+        done.append(observe)
+
+    served = threading.Thread(target=trip, args=(True,))
+    with fresh.telemetry._lock:
+        served.start()               # compiles, then waits for the lock
+        deadline = time.monotonic() + 120
+        while time.monotonic() < deadline and not (
+                fresh._step is not None and fresh._step._cache_size()):
+            time.sleep(0.01)
+        assert fresh._step._cache_size() == 1 and not done
+        quiet = threading.Thread(target=trip, args=(False,))
+        quiet.start()
+        quiet.join(timeout=120)      # runs to its end under our lock
+        assert done == [False]
+    served.join(timeout=120)
+    assert done == [False, True] and not served.is_alive()
+    assert fresh.telemetry.drain()["hit"].sum() > 0
+
+
+def test_report_rows_ride_the_check_program_and_count_nothing(one_program):
+    """packed_report off a mesh: the Check program's packed rows with
+    the field planes appended to its handle equal the step + report
+    packer launched apart (the mesh path), the plain step is never
+    compiled for it, and Report traffic feeds no rule counter."""
+    import jax
+    import numpy as np
+
+    from istio_tpu.runtime import monitor
+
+    plan, apart, dicts = one_program
+    batch, ns, _ = _padded_batch(plan, dicts, plan.str_tiers[0])
+    plan.telemetry.drain()
+    programs = monitor.device_program_counters()
+    got = plan.packed_report(batch, ns)
+    narrowed = apart.narrow_batch(batch)
+    verdict = apart.engine.check(narrowed, ns)
+    if plan.report_lowering is not None and plan.report_lowering.n_fields:
+        want = jax.jit(apart._base_report_packer())(verdict, ns, narrowed)
+        assert got.shape[0] > 5 + plan.n_ref_words + plan.n_overlay_words
+    else:                      # no field lowered: the check rows alone
+        want = jax.jit(apart._base_packer())(verdict, ns)
+    np.testing.assert_array_equal(got, np.asarray(want))
+    drained = plan.telemetry.drain()
+    assert not any(drained[p].any() for p in ("hit", "deny", "err"))
+    assert monitor.device_program_counters() == programs
+    assert plan.engine._step._cache_size() == 0

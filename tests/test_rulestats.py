@@ -288,3 +288,70 @@ def test_never_hit_shadow_crosscheck_ambiguity_guard():
     assert flags["ns-a/dead"] is True          # unique bare name
     assert flags["ns-a/allow"] is False        # ambiguous: two rules
     assert flags["ns-b/allow"] is False
+
+
+def test_two_pumps_and_a_drain_lose_and_double_nothing():
+    """FusedPlan.packed_check threads the accumulator handles through
+    its one device program under RuleTelemetry's lock (chain). Two
+    threads x 50 batches with drains falling between them: the drains
+    sum to 100 x one batch's counts, exactly."""
+    import sys
+    import threading
+
+    from istio_tpu.runtime.batcher import pad_to_bucket
+    from istio_tpu.runtime.config import SnapshotBuilder
+    from istio_tpu.runtime.fused import build_fused_plan
+
+    mod = _smoke()
+    plan = build_fused_plan(SnapshotBuilder(
+        default_manifest=workloads.MESH_MANIFEST).build(
+            workloads.make_store(20, seed=3)))
+    tele = plan.telemetry
+    dicts = mod.make_traffic(20, 4, 3)
+    n_real, rounds, pumps = len(dicts), 50, 2
+    bags = pad_to_bucket([bag_from_mapping(d) for d in dicts], (32,))
+    batch = plan.engine.tensorizer.tensorize(bags)
+    rs = plan.engine.ruleset
+    ns = np.zeros(32, np.int32)
+    ns[:n_real] = [rs.namespace_id(d.get("source.namespace", ""))
+                   for d in dicts]
+    plan.packed_check(batch, ns, n_real=n_real)      # compiles
+    one = tele.drain()
+    assert one["hit"].sum() > 0 and one["deny"].sum() > 0
+
+    start = threading.Barrier(pumps + 1)
+    failed: list = []
+
+    def pump():
+        try:
+            start.wait(timeout=30)
+            for _ in range(rounds):
+                plan.packed_check(batch, ns, n_real=n_real)
+        except Exception as exc:      # read back below
+            failed.append(exc)
+
+    threads = [threading.Thread(target=pump) for _ in range(pumps)]
+    total = {k: np.zeros_like(one[k]) for k in ("hit", "deny", "err")}
+    generations = []
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        start.wait(timeout=30)
+        while any(t.is_alive() for t in threads):
+            d = tele.drain()          # falls between two batches
+            generations.append(d["generation"])
+            for k in total:
+                total[k] += d[k]
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not failed and not any(t.is_alive() for t in threads)
+    last = tele.drain()
+    for k in total:
+        np.testing.assert_array_equal(total[k] + last[k],
+                                      pumps * rounds * one[k])
+    assert len(generations) >= 1
+    assert generations == sorted(set(generations))

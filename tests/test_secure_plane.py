@@ -321,6 +321,12 @@ def test_native_front_tls_lane(ca):
         with pytest.raises(grpc.RpcError) as exc:
             anon.check({"destination.service": "a.default.svc"})
         assert exc.value.code() == grpc.StatusCode.UNAVAILABLE
+        # the lane's thread counts the refusal a moment after the
+        # client has seen it
+        deadline = time.monotonic() + 5
+        while time.monotonic() < deadline and \
+                not native.tls_lane_stats()["handshake_failures"]:
+            time.sleep(0.01)
         assert native.tls_lane_stats()["handshake_failures"] >= 1
     finally:
         for c in (cl, anon):

@@ -237,15 +237,63 @@ def test_served_burst_tiles_the_pump_cycle(front):
     # the sub-spans split their stage
     for parts, whole in ((("tensorize.decode", "tensorize.ns_ids"),
                           stages["tensorize"]),
-                         (("dispatch.step", "dispatch.rulestats",
-                           "dispatch.pack"), stages["h2d"]),
+                         (("dispatch.step",), stages["h2d"]),
                          (("fold.signature",), stages["fold"])):
         assert all(spans[p]["count"] == cycles for p in parts), spans
         assert sum(spans[p]["sum_ms"] for p in parts) <= \
             whole["sum_ms"] + 1e-3
+    # one program a Check batch: nothing is launched behind the step
+    assert "dispatch.rulestats" not in spans
+    assert "dispatch.pack" not in spans
     # the tracer's grouping spans are off with no reporter configured
     assert not monitor.zipkin_on()
     assert "device" not in spans and "overlay" not in spans
+
+
+@pytest.mark.parametrize("path, programs, behind_the_step", [
+    # FusedPlan.packed_check: step, rule-telemetry fold and packer are
+    # one jit, so `dispatch.step` is the whole launch
+    ("check", 1, ()),
+    # an in-step quota batch launches them apart, each under its span
+    ("instep", 4, ("dispatch.rulestats", "dispatch.pack")),
+])
+def test_dispatch_spans_and_counter_say_what_a_batch_launched(
+        front, path, programs, behind_the_step):
+    import jax.numpy as jnp
+    import numpy as np
+
+    from istio_tpu.runtime.device_quota import _TICKS_PER_WINDOW
+
+    plan = front[0].runtime.controller.dispatcher.fused
+    assert plan.mesh is None and plan.telemetry is not None
+    b = 8
+    batch, ns = plan._dummy_batch(b, min(plan.str_tiers)), \
+        np.zeros(b, np.int32)
+    zeros = {k: np.zeros(b, np.int32) for k in (
+        "buckets", "amounts", "mx", "ticks", "lasts")}
+    flags = {k: np.zeros(b, bool) for k in ("be", "active", "rolling")}
+    q = {**zeros, **flags, "rule_idx": np.full(b, -1, np.int32)}
+    counts = jnp.zeros((4, _TICKS_PER_WINDOW), jnp.int32)
+
+    def trip(n_real, observe=True):
+        if path == "check":
+            plan.packed_check(batch, ns, observe=observe, n_real=n_real)
+        else:
+            np.asarray(plan.packed_check_instep(
+                batch, ns, q, counts, n_real=n_real)[0])
+
+    # a dummy trip (prewarm) compiles, and neither counts nor is timed
+    before = monitor.device_program_counters()
+    base = monitor.stage_baseline()
+    trip(0, observe=False)
+    assert monitor.device_program_counters() == before
+    assert not monitor.latency_snapshot(since=base)["spans"]
+    trip(b)
+    spans = monitor.latency_snapshot(since=base)["spans"]
+    assert set(spans) == {"dispatch.step", *behind_the_step}, spans
+    after = monitor.device_program_counters()
+    assert {k: after[k] - before[k] for k in after} == {
+        **dict.fromkeys(after, 0), path: programs}
 
 
 def test_zipkin_groups_exist_only_under_a_reporter(front, taps):

@@ -144,6 +144,24 @@ def test_rule_telemetry_delta_and_fold_compile(plan, one_chip):
     _fits(tele._fold_fn.lower(*accs, *accs).compile())
 
 
+def test_one_check_program_compiles(plan, one_chip):
+    """FusedPlan._base_step: step + rule-telemetry delta and fold +
+    packer as the one program packed_check launches a batch, at the
+    largest served shape."""
+    bucket, tier = SHAPES[-1]
+    tele = plan.telemetry
+    assert plan.mesh is None and tele is not None
+    n_real = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    accs = _on(one_chip, (tele._acc_hit, tele._acc_deny, tele._acc_err))
+    compiled = jax.jit(plan._base_step()).lower(
+        *_step_args(plan, one_chip, bucket, tier), n_real, *accs).compile()
+    _fits(compiled)
+    # the CONJ_ALIGN guard of test_engine_step_compiles holds for the
+    # composed program too
+    code = compiled.memory_analysis().generated_code_size_in_bytes
+    assert code < 32 * 1024 ** 2, code
+
+
 @pytest.mark.parametrize("variant", ("fast", "unit", "seg"))
 def test_rolling_quota_alloc_compiles(one_chip, variant):
     """The three alloc kernels DeviceQuotaPool._flush selects between,
