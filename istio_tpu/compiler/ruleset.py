@@ -54,6 +54,7 @@ per-rule attribute bitmaps (SURVEY.md §2.2 translation note).
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import threading
 from typing import Any, Callable, Mapping, Sequence
@@ -263,6 +264,63 @@ def _const_id(e: Expression, interner: InternTable) -> int | None:
     if folded is None:
         return None
     return interner.intern(folded)
+
+
+def _build_dfa_span(on: bool):
+    """Host time of regex -> DFA -> packed bank inside the rule
+    compile: the span `build.dfa`, off where the snapshot holds no
+    constant-pattern regex."""
+    from istio_tpu.runtime import monitor   # lazy: runtime imports us
+
+    return monitor.span("build.dfa", on=bool(on))
+
+
+def _dfa_guards(per_rule, dfa_atoms: set, eq_info: Mapping) -> dict:
+    """atom -> {column: {intern ids}}: the columns on which EVERY
+    conjunction holding the atom (either polarity) also asserts an
+    id-equality, and the ids asserted there, one a holder (a pattern
+    two hosts share reads two). Where the row's column reads none of
+    them, no conjunction that reads the atom can hold, so the atom's
+    value there reaches no verdict. Go's `&&` short-circuits the same
+    way: behind a false `destination.service == X` the regex is never
+    evaluated."""
+    guards: dict[int, dict[int, set]] = {}
+    for mn in per_rule:
+        for conj in (mn[0] | mn[1]) if mn else ():
+            held = [a for a, _ in conj if a in dfa_atoms]
+            if not held:
+                continue
+            # "column == id": an EQ atom definitely true, or a NEQ
+            # atom definitely false
+            asserts = dict(eq_info[a][:2] for a, kind in conj
+                           if a in eq_info and eq_info[a][2] == (kind == "n"))
+            for a in held:
+                if a not in guards:
+                    guards[a] = {col: {cid} for col, cid in asserts.items()}
+                    continue
+                mine = guards[a]
+                for col in [c for c in mine if c not in asserts]:
+                    del mine[col]
+                for col in mine:
+                    mine[col].add(asserts[col])
+    return guards
+
+
+def _bank_guard(atoms: Sequence[int], guards: Mapping
+                ) -> tuple[int, list[tuple]] | None:
+    """(column, [ids per atom]): the id-equality column that guards
+    the most atoms of the group (then the one that leaves a row the
+    fewest candidates), each atom with the ids under which some
+    conjunction reads it, () where this column does not guard it. None
+    if no column guards any atom."""
+    best = None
+    for col in sorted({c for a in atoms for c in guards.get(a, ())}):
+        ids = [tuple(sorted(guards.get(a, {}).get(col, ()))) for a in atoms]
+        most = collections.Counter(i for held in ids for i in held)
+        key = (-sum(map(bool, ids)), max(most.values()))
+        if best is None or key < best[0]:
+            best = (key, col, ids)
+    return None if best is None else best[1:]
 
 
 @dataclasses.dataclass
@@ -536,14 +594,26 @@ def compile_ruleset(rules: Sequence[Rule], finder: AttributeDescriptorFinder,
     # every rule that references them to host fallback.
     reqs = Requirements()
     bad_atoms: set[int] = set()
-    for aidx, ast in enumerate(atoms.asts):
-        try:
-            r = Requirements()
-            collect_requirements(ast, finder, r)
-        except HostFallback as exc:
-            bad_atoms.add(aidx)
-            continue
-        reqs.merge(r)
+
+    def collect(aidxs) -> None:
+        for aidx in aidxs:
+            try:
+                r = Requirements()
+                collect_requirements(atoms.asts[aidx], finder, r)
+            except HostFallback:
+                bad_atoms.add(aidx)
+                continue
+            reqs.merge(r)
+
+    # the regex atoms first, on their own: collecting compiles each
+    # constant pattern to its DFA (kept in reqs.dfas), which is most
+    # of a route table's host compile and the first part of build.dfa
+    regex_atoms = [aidx for aidx, ast in enumerate(atoms.asts)
+                   if ast.fn is not None and ast.fn.name == "matches"]
+    with _build_dfa_span(regex_atoms):
+        collect(regex_atoms)
+    held = set(regex_atoms)
+    collect(aidx for aidx in range(len(atoms.asts)) if aidx not in held)
     if bad_atoms:
         for ridx, mn in enumerate(per_rule):
             if mn is None:
@@ -623,10 +693,9 @@ def compile_ruleset(rules: Sequence[Rule], finder: AttributeDescriptorFinder,
             if not done and f is not None and f.name == "matches" \
                     and f.target is not None \
                     and f.target.const_ is not None:
+                pattern = f.target.const_.value
+                dfa = reqs.dfas.get(pattern)
                 try:
-                    from istio_tpu.ops.regex_dfa import compile_regex
-                    pattern = f.target.const_.value
-                    dfa = compile_regex(pattern)
                     # probe the subject NOW so an un-viewable subject
                     # falls through to the generic path's fallback
                     tensor_expr._compile_bytes(f.args[0], ctx)
@@ -679,13 +748,27 @@ def compile_ruleset(rules: Sequence[Rule], finder: AttributeDescriptorFinder,
                 host_fallback[ridx] = _rule_oracle(rules[ridx], finder)
                 fallback_reason[ridx] = "atom not lowerable"
 
-    dfa_group_fns = [tensor_expr.compile_dfa_group(
-        g["subject"], g["patterns"], g["dfas"], ctx)
-        for g in dfa_groups.values()]
-    dfa_atom_idx = [a for g in dfa_groups.values() for a in g["atoms"]]
-    # the prefix groups ride behind the dfa groups: both return
-    # (val [B, k], ee [B, k]) and run() treats them alike
-    dfa_group_fns += [tensor_expr.compile_prefix_group(
+    eq_info = {aidx: (eq_cols[i], eq_cids[i], eq_neg[i])
+               for i, aidx in enumerate(eq_atom_idx)}
+    # a bank past both one-hot tiers scans each row's candidate
+    # automata where an id-equality guards its atoms, and the few atoms
+    # read unguarded as a bank of their own
+    # (tensor_expr.compile_dfa_group); its arrays join the params
+    with _build_dfa_span(dfa_groups):
+        guards = _dfa_guards(
+            per_rule, {a for g in dfa_groups.values() for a in g["atoms"]},
+            eq_info)
+        dfa_group_fns = [tensor_expr.compile_dfa_group(
+            g["subject"], g["patterns"], g["dfas"], ctx,
+            guard=_bank_guard(g["atoms"], guards), prefix=f"dfa{gi}_")
+            for gi, g in enumerate(dfa_groups.values())]
+    # a group's columns in the order its function returns them
+    dfa_atom_idx = [g["atoms"][i]
+                    for g, gfn in zip(dfa_groups.values(), dfa_group_fns)
+                    for i in gfn.order]
+    # the prefix groups ride beside the dfa groups: both return
+    # (val [B, k], ee [B, k])
+    prefix_group_fns = [tensor_expr.compile_prefix_group(
         g["subject"], g["prefixes"], ctx) for g in prefix_groups.values()]
     prefix_atom_idx = [a for g in prefix_groups.values()
                        for a in g["atoms"]]
@@ -736,8 +819,6 @@ def compile_ruleset(rules: Sequence[Rule], finder: AttributeDescriptorFinder,
     # here and the legacy literal-gather stage compiles away.
     # Conjunction columns permute fused-first; the rule-stage index
     # matrices are remapped through the permutation.
-    eq_info = {aidx: (eq_cols[i], eq_cids[i], eq_neg[i])
-               for i, aidx in enumerate(eq_atom_idx)}
     fused_j = [j for j, conj in enumerate(conj_list)
                if all(aidx in eq_info for aidx, _ in conj)]
     fused_set = set(fused_j)
@@ -844,6 +925,8 @@ def compile_ruleset(rules: Sequence[Rule], finder: AttributeDescriptorFinder,
               "eqc_cid": jnp.asarray(eqc_cid),
               "eqc_xor": jnp.asarray(eqc_xor),
               "eqc_pad": jnp.asarray(eqc_pad)}
+    for gfn in dfa_group_fns:
+        params.update(gfn.params)
 
     def run(params: Mapping[str, Any],
             batch: AttributeBatch) -> tuple[Any, Any, Any]:
@@ -874,8 +957,15 @@ def compile_ruleset(rules: Sequence[Rule], finder: AttributeDescriptorFinder,
                 parts_m.append(cmp & pres)
                 parts_n.append(~cmp & pres)
             for gfn in dfa_group_fns:
-                gval, gee = gfn(batch)
+                # metadata only: the trace's device operations carry
+                # the scope path (benchmark/scopes.py)
+                with jax.named_scope("dfa"):
+                    gval, gee = gfn(batch, params)
                 parts_m.append(gval)           # already masked by ~ee
+                parts_n.append(~gval & ~gee)
+            for gfn in prefix_group_fns:
+                gval, gee = gfn(batch)
+                parts_m.append(gval)
                 parts_n.append(~gval & ~gee)
             for fn in gen_fns:
                 t = fn(batch)
@@ -954,6 +1044,10 @@ def compile_ruleset(rules: Sequence[Rule], finder: AttributeDescriptorFinder,
         "n_gen_atoms": len(gen_atom_idx),
         "n_dfa_groups": len(dfa_groups),
         "n_prefix_groups": len(prefix_groups),
+        # per DFA bank (one a group, two where a group splits):
+        # subject, tier, automata, resident bytes, automata scanned a
+        # row (tensor_expr.compile_dfa_group)
+        "dfa_banks": [b for gfn in dfa_group_fns for b in gfn.banks],
         "n_live": n_live,
         "n_conjs": n_conjs,
         "n_fused_conjs": n_fused,

@@ -100,11 +100,16 @@ class Requirements:
     # timestamp() conversions the tensorizer runs at ingest
     extern_sources: dict[tuple[str, str], Any] = \
         dataclasses.field(default_factory=dict)
+    # constant `matches` pattern → the DFA collecting compiled to
+    # prove it lowers (None: the device subset cannot hold it), kept
+    # for the ruleset's DFA groups: a pattern is compiled once
+    dfas: dict[str, Any] = dataclasses.field(default_factory=dict)
 
     def merge(self, other: "Requirements") -> None:
         self.derived_keys |= other.derived_keys
         self.byte_sources |= other.byte_sources
         self.extern_sources.update(other.extern_sources)
+        self.dfas.update(other.dfas)
 
 
 def _extern_operand_ok(e: Expression) -> bool:
@@ -192,8 +197,10 @@ def _collect(e: Expression, finder: AttributeDescriptorFinder,
             return
         if f.name == "matches":
             try:
-                compile_regex(pattern.const_.value)
+                reqs.dfas[pattern.const_.value] = compile_regex(
+                    pattern.const_.value)
             except UnsupportedRegex as exc:
+                reqs.dfas[pattern.const_.value] = None
                 import re as _re
                 try:
                     _re.compile(pattern.const_.value)
@@ -567,10 +574,32 @@ def _compile_byte_pred(f: FunctionCall, ctx: _Ctx) -> NodeFn:
     return fn
 
 
+def _bank_scan(tiers: dict) -> tuple[str, Callable, tuple]:
+    """(tier, scan(s: BVal) → bool [B, n], its resident arrays) of a
+    bank every row scans whole (regex_dfa.pack_dfas_tiered: the
+    one-hot tiers, or the flat gather)."""
+    # the MXU formulations win at EVERY serving batch size (profiled
+    # r4 at B=256: 0.055 ms vs 0.279 ms for the flat gather — the
+    # per-step [B, N] gather is latency-bound on TPU regardless of B)
+    for tier, key, op in (
+            ("onehot", "packed", bytes_ops.dfa_match_many_onehot),
+            ("onehot-blocked", "packed_blk",
+             bytes_ops.dfa_match_many_onehot_blocked)):
+        p = tiers[key]
+        if p is not None:
+            return tier, (lambda s: op(s.data, s.lens, p)), \
+                (p["step_bits"], p["cls"], p["accept"])
+    trans_j, accept_j = jnp.asarray(tiers["trans"]), \
+        jnp.asarray(tiers["accept"])
+    return "gather", (lambda s: bytes_ops.dfa_match_many(
+        s.data, s.lens, trans_j, accept_j)), (trans_j, accept_j)
+
+
 def compile_dfa_group(subject_ast: Expression, patterns: list[str],
-                      dfas: list, ctx: "_Ctx") -> Callable:
+                      dfas: list, ctx: "_Ctx", guard=None,
+                      prefix: str = "") -> Callable:
     """ALL constant-pattern `matches` atoms over ONE subject, evaluated
-    in a single packed scan (ops/bytes_ops.dfa_match_many).
+    in a single packed scan (ops/bytes_ops.dfa_match_many*).
 
     Per-atom DFA scans are latency-bound: each of the L scan steps is a
     tiny [B] gather, so k separate atoms cost k·L sequential steps
@@ -578,39 +607,105 @@ def compile_dfa_group(subject_ast: Expression, patterns: list[str],
     that into ONE L-step scan with [B, k] gathers — the batched-NFA
     shape SURVEY §7 hard-part 1 calls for.
 
-    Returns fn(batch) → (val [B, k], ee [B, k]) with exactly
+    `guard` = (slot column, [intern ids per pattern]) where the ruleset
+    shows one: every conjunction holding pattern i also holds
+    `column == id` for one of pattern i's ids, so on a row whose column
+    reads another id the pattern's column is read by no conjunction
+    that can still hold; () marks a pattern this column does not
+    guard. A bank past both one-hot tiers then scans each row's own
+    candidates (regex_dfa.pack_dfas_tiered's `cand`, tier
+    "candidates") and writes a guarded-out column False; the unguarded patterns are a
+    bank of their own that every row scans. The candidate bank's
+    arrays ride the step's arguments (`fn.params`, keys under
+    `prefix`), not the program's constants.
+
+    Returns fn(batch, params) → (val [B, k], ee [B, k]) with exactly
     _compile_byte_pred's semantics per column: subject absence/error
     masks the row; truncated rows are fully undecidable for $-anchored
-    patterns and miss-undecidable otherwise."""
+    patterns and miss-undecidable otherwise. Column c is pattern
+    `fn.order[c]` (a split bank returns its candidates first).
+    `fn.banks` says what was built, a record a bank: subject, tier,
+    automata, resident bytes, automata scanned a row."""
     from istio_tpu.ops.regex_dfa import pack_dfas_tiered
 
     max_len = ctx.layout.max_str_len
     fsub = _compile_bytes(subject_ast, ctx)
+    guard_of = gvals = None
+    if guard is not None:
+        gvals = np.unique(np.asarray(
+            [i for held in guard[1] for i in held], np.int32))
+        guard_of = [np.searchsorted(gvals, held) for held in guard[1]]
     # tier selection shared with the engine's list banks
     # (regex_dfa.pack_dfas_tiered)
-    tiers = pack_dfas_tiered(dfas)
-    packed = tiers["packed"]
-    packed_blk = tiers["packed_blk"]
-    trans_j = None if tiers["trans"] is None \
-        else jnp.asarray(tiers["trans"])
-    accept_j = None if tiers["accept"] is None \
-        else jnp.asarray(tiers["accept"])
-    trunc_all = jnp.asarray(np.array(["$" in p for p in patterns]))
+    tiers = pack_dfas_tiered(dfas, guard_of)
+    cand = tiers["cand"]
 
-    def fn(batch: AttributeBatch):
+    def bank(tier: str, automata: int, resident, scanned: int) -> dict:
+        return {"subject": str(subject_ast), "tier": tier,
+                "automata": automata,
+                "bytes": sum(int(a.nbytes) for a in resident),
+                "candidates": scanned}
+
+    own: dict = {}
+    if cand is None:
+        order = list(range(len(dfas)))
+        tier, whole, resident = _bank_scan(tiers)
+        banks = [bank(tier, len(dfas), resident, len(dfas))]
+    else:
+        rest = cand["rest"]
+        order = cand["members"] + (rest["members"] if rest else [])
+        n = len(cand["members"])
+        s_max, width = cand["n_states_max"], cand["width"]
+        sel = np.zeros((cand["k"], n), np.float32)
+        sel[cand["slot"], np.arange(n)] = 1.0
+        own = {"local": cand["local"], "accept": cand["accept"],
+               "class_of": cand["class_of"], "cand": cand["cand"],
+               "gvals": gvals, "sel": jnp.asarray(sel, jnp.bfloat16)}
+        own = {prefix + k: jnp.asarray(v) for k, v in own.items()}
+        banks = [bank("candidates", n, own.values(), cand["k"])]
+        if rest:
+            tier, others, resident = _bank_scan(rest)
+            banks.append(bank(tier, len(rest["members"]), resident,
+                              len(rest["members"])))
+    trunc_all = jnp.asarray(np.array(["$" in patterns[i] for i in order]))
+
+    def candidates(batch: AttributeBatch, s: BVal, params) -> Any:
+        """[B, n] acceptance from a scan of each row's own automata."""
+        n_values = gvals.shape[0]
+        ids = batch.ids[:, guard[0]]
+        vals = params[prefix + "gvals"]
+        at = jnp.minimum(jnp.searchsorted(vals, ids, method="compare_all"),
+                         n_values - 1)
+        # row -> its guard value's index; n_values: names no value
+        gi = jnp.where(batch.present[:, guard[0]] & (vals[at] == ids),
+                       at, n_values)
+        mine = params[prefix + "cand"][gi]                       # [B, K]
+        acc = bytes_ops.dfa_match_candidates(
+            s.data, s.lens, mine, params[prefix + "local"],
+            params[prefix + "accept"], params[prefix + "class_of"],
+            s_max, width)
+        # column j reads its slot, and holds where the row's candidate
+        # there is j itself and accepted: under another value the slot
+        # is another automaton's. The automaton is named by two
+        # base-256 digits, each exact in bf16; n, the dead automaton,
+        # is no column's
+        named = jnp.where(acc, mine, n)
+        col = jnp.arange(n, dtype=jnp.int32)
+        out = True
+        for digit in (lambda x: x >> 8, lambda x: x & 255):
+            at_slot = jnp.dot(digit(named).astype(jnp.bfloat16),
+                              params[prefix + "sel"])
+            out = out & (at_slot == digit(col).astype(jnp.bfloat16)[None, :])
+        return out
+
+    def fn(batch: AttributeBatch, params=None):
         s = fsub(batch)
-        # the MXU formulations win at EVERY serving batch size
-        # (profiled r4 at B=256: 0.055 ms vs 0.279 ms for the flat
-        # gather — the per-step [B, N] gather is latency-bound on TPU
-        # regardless of B)
-        if packed is not None:
-            m = bytes_ops.dfa_match_many_onehot(s.data, s.lens, packed)
-        elif packed_blk is not None:
-            m = bytes_ops.dfa_match_many_onehot_blocked(
-                s.data, s.lens, packed_blk)
+        if cand is None:
+            m = whole(s)
         else:
-            m = bytes_ops.dfa_match_many(s.data, s.lens, trans_j,
-                                         accept_j)
+            m = candidates(batch, s, params)
+            if rest:
+                m = jnp.concatenate([m, others(s)], axis=1)
         ee = (s.err | ~s.ok)[:, None] & jnp.ones_like(m)
         val = m & ~ee
         maybe = (s.ok & (s.lens >= max_len))[:, None]
@@ -618,6 +713,8 @@ def compile_dfa_group(subject_ast: Expression, patterns: list[str],
         ee = ee | undecidable
         val = val & ~ee
         return val, ee
+
+    fn.params, fn.order, fn.banks = own, order, banks
     return fn
 
 

@@ -281,6 +281,56 @@ def dfa_match_many(data: jnp.ndarray, lens: jnp.ndarray,
     return accept_flat[final]
 
 
+def dfa_match_candidates(data: jnp.ndarray, lens: jnp.ndarray,
+                         cand: jnp.ndarray, local: jnp.ndarray,
+                         accept: jnp.ndarray, class_of: jnp.ndarray,
+                         s_max: int, width: int) -> jnp.ndarray:
+    """N packed DFAs, each row against its OWN K of them
+    (regex_dfa.pack_dfas_candidates): `cand` int32 [B, K] names them,
+    the bank's dead automaton N where a row has fewer.
+
+    No gather runs inside the scan, nor before it. The rows' own
+    tables are fetched ONCE, K contiguous rows of `local` a request,
+    and laid [K, S·W, B], the batch on the lane axis; a byte step is
+    then one masked reduce over the S·W axis (cell state·W + class of
+    each (k, row)), which the vector unit does at full width: 4-8 µs a
+    byte in the served step. XLA:TPU's gather costs ~50 µs an
+    operation plus ~6 ns an element: a [K, B] gather a byte was 153 µs
+    (2048 x 8), the byte → class lookup through a 256-entry table
+    1.4 ms, and the whole-bank scan this replaces, [2048, 7500] a
+    byte, 7.2 s a batch (PERF.md §6, PR 33). Bytes are mapped to the
+    bank's byte classes once, before the loop, by a compare against
+    each of the 256 byte values.
+
+    → bool [B, K]: acceptance of the row's k-th candidate."""
+    l = data.shape[1]
+    cand = jnp.asarray(cand)
+    mine = jnp.transpose(jnp.asarray(local)[cand], (1, 2, 0))   # [K, S·W, B]
+    byte = jnp.arange(256, dtype=jnp.int32)[:, None, None]
+    cls_tm = jnp.sum(jnp.where(data.T.astype(jnp.int32)[None] == byte,
+                               jnp.asarray(class_of)[:, None, None], 0),
+                     axis=0)                                    # [L, B]
+    cell = jnp.arange(s_max * width, dtype=jnp.int32)[None, :, None]
+    maxlen = jnp.minimum(jnp.max(lens), l)
+
+    def cond(carry):
+        i, _ = carry
+        return i < maxlen
+
+    def body(carry):
+        i, state = carry                                   # [K, B], local
+        cls = jax.lax.dynamic_index_in_dim(cls_tm, i, 0, keepdims=False)
+        at = (state * width + cls[None, :])[:, None, :]
+        nxt = jnp.sum(jnp.where(cell == at, mine, 0).astype(jnp.int32),
+                      axis=1)
+        state = jnp.where((i < lens)[None, :], nxt, state)
+        return i + 1, state
+
+    _, final = jax.lax.while_loop(
+        cond, body, (jnp.int32(0), jnp.zeros(cand.T.shape, jnp.int32)))
+    return jnp.asarray(accept)[cand.T * s_max + final].T
+
+
 def dfa_match_many_onehot(data: jnp.ndarray, lens: jnp.ndarray,
                           packed: dict) -> jnp.ndarray:
     """Multi-pattern DFA on the MXU: states ride as ONE-HOT vectors and

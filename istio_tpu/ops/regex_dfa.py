@@ -405,6 +405,32 @@ def compile_regex(pattern: str) -> DFA:
                     stack.append(t)
         return frozenset(seen)
 
+    # Byte classes of THIS pattern: bytes no transition's character
+    # set tells apart move every state alike, so a state is expanded
+    # once a class (a route regex has ~30) and not once a byte.
+    # Classes are numbered by their smallest byte, so states are
+    # discovered, and numbered, in the order a byte-by-byte walk finds
+    # them.
+    set_ids: dict[frozenset[int], int] = {}
+    for outs in nfa.trans:
+        for chars, _ in outs:
+            set_ids.setdefault(chars, len(set_ids))
+    # (a last all-False row: a pattern with no transition has one class)
+    member = np.zeros((len(set_ids) + 1, ALPHABET), bool)
+    for chars, k in set_ids.items():
+        member[k, list(chars)] = True
+    _, reps, class_of = np.unique(member, axis=1, return_index=True,
+                                  return_inverse=True)
+    by_first = np.argsort(reps)
+    reps = reps[by_first]
+    rank = np.empty(len(reps), np.int64)
+    rank[by_first] = np.arange(len(reps))
+    class_of = rank[class_of.reshape(-1)]
+    covers = [np.flatnonzero(member[k, reps]).tolist()
+              for k in range(len(set_ids))]
+    moves = [[(covers[set_ids[chars]], t) for chars, t in outs]
+             for outs in nfa.trans]
+
     start_set = eps_closure(frozenset([start]))
     dfa_ids: dict[frozenset[int], int] = {start_set: 0}
     worklist = [start_set]
@@ -418,26 +444,23 @@ def compile_regex(pattern: str) -> DFA:
             rows.append(np.zeros(ALPHABET, dtype=np.int32))
             accepts.append(False)
         accepts[cur_id] = accept in cur
-        is_accepting = accept in cur
 
-        # group target NFA states by byte
-        by_byte: list[set[int]] = [set() for _ in range(ALPHABET)]
+        # group target NFA states by byte class
+        by_class: list[set[int]] = [set() for _ in reps]
         for s in cur:
-            for chars, t in nfa.trans[s]:
-                for ch in chars:
-                    by_byte[ch].add(t)
-        row = np.zeros(ALPHABET, dtype=np.int32)
+            for classes, t in moves[s]:
+                for c in classes:
+                    by_class[c].add(t)
+        row = np.zeros(len(reps), dtype=np.int32)
         closure_cache: dict[frozenset[int], int] = {}
-        for ch in range(ALPHABET):
-            tgt = frozenset(by_byte[ch])
-            key = tgt
-            if key in closure_cache:
-                row[ch] = closure_cache[key]
+        for c, targets in enumerate(by_class):
+            tgt = frozenset(targets)
+            if tgt in closure_cache:
+                row[c] = closure_cache[tgt]
                 continue
+            # sticky accept for search semantics: an unanchored end's
+            # suffix .* already keeps acceptance
             nxt = eps_closure(tgt) if tgt else frozenset()
-            # sticky accept for search semantics
-            if is_accepting and not anchored_end:
-                pass  # suffix .* already keeps acceptance
             tid = dfa_ids.get(nxt)
             if tid is None:
                 tid = len(dfa_ids)
@@ -446,9 +469,9 @@ def compile_regex(pattern: str) -> DFA:
                         f"DFA for {pattern!r} exceeds {_MAX_DFA_STATES} states")
                 dfa_ids[nxt] = tid
                 worklist.append(nxt)
-            row[ch] = tid
-            closure_cache[key] = tid
-        rows[cur_id] = row
+            row[c] = tid
+            closure_cache[tgt] = tid
+        rows[cur_id] = row[class_of]
 
     while len(rows) < len(dfa_ids):
         rows.append(np.zeros(ALPHABET, dtype=np.int32))
@@ -489,23 +512,36 @@ def pack_dfas_classes(dfas: list[DFA]) -> dict:
     CLASSES (bytes with identical transition columns across every
     state). O(S·256) numpy work — callers size-gate on
     n_states/n_classes BEFORE paying for the step matrix
-    (pack_dfas_onehot)."""
-    n = len(dfas)
+    (pack_dfas_onehot). Nothing here is quadratic in the bank: a
+    10 000-automaton bank comes through (250k states)."""
     offs = np.cumsum([0] + [d.n_states for d in dfas])
     s_tot = int(offs[-1])
     gt = np.zeros((s_tot, ALPHABET), np.int32)
-    accept = np.zeros((s_tot, n), np.float32)
     for i, d in enumerate(dfas):
         gt[offs[i]:offs[i + 1]] = d.transitions + offs[i]
-        accept[offs[i]:offs[i + 1], i] = d.accept
-    _, class_of = np.unique(gt, axis=1, return_inverse=True)
-    class_of = class_of.reshape(-1)
+    # Equal columns, found by a 64-bit mix of each column and then
+    # proved equal: sorting 256 columns of 250k states apiece
+    # (np.unique over the whole table) was 15 s of a 10 000-automaton
+    # bank's build. Classes keep the numbering that sort gave them
+    # (the lexicographic order of the distinct columns).
+    weights = np.random.default_rng(0x5eed).integers(
+        1, 1 << 63, s_tot, dtype=np.uint64) | np.uint64(1)
+    mix = (gt.astype(np.uint64) * weights[:, None]).sum(axis=0)
+    _, first, group = np.unique(mix, return_index=True,
+                                return_inverse=True)
+    distinct = gt[:, first]
+    if (gt == distinct[:, group]).all():
+        _, order = np.unique(distinct, axis=1, return_inverse=True)
+        class_of = order.reshape(-1)[group]
+    else:   # two columns mixed alike: sort them all
+        _, class_of = np.unique(gt, axis=1, return_inverse=True)
+        class_of = class_of.reshape(-1)
     n_cls = int(class_of.max()) + 1
     rep = np.zeros(n_cls, np.int64)   # a representative byte per class
     for byte in range(ALPHABET - 1, -1, -1):
         rep[class_of[byte]] = byte
     return {"gt": gt, "class_of": class_of, "rep": rep,
-            "starts": offs[:-1].astype(np.int32), "accept": accept,
+            "starts": offs[:-1].astype(np.int32),
             "n_states": s_tot, "n_classes": n_cls}
 
 
@@ -532,8 +568,11 @@ def pack_dfas_onehot(dfas: list[DFA],
     step[rows, cols] = True
     cls = np.zeros((ALPHABET, n_cls), np.float32)
     cls[np.arange(ALPHABET), class_of] = 1.0
+    accept = np.zeros((s_tot, len(dfas)), np.float32)
+    for i, (d, lo) in enumerate(zip(dfas, k["starts"])):
+        accept[lo:lo + d.n_states, i] = d.accept
     return {"step_bits": pack_bits(step), "cls": cls,
-            "starts": k["starts"], "accept": k["accept"],
+            "starts": k["starts"], "accept": accept,
             "n_states": s_tot, "n_classes": n_cls}
 
 
@@ -577,19 +616,105 @@ def pack_dfas_onehot_blocked(dfas: list[DFA],
             "n_states_max": s_max, "n_classes": n_cls, "n_pats": n}
 
 
-def pack_dfas_tiered(dfas: "list[DFA]") -> dict:
+# Table cells one row's candidates may hold (K·s_max·width): the
+# candidate scan lays every row's own tables out beside it, so a bank
+# of wide automata would cost the batch what it saves the scan
+CANDIDATE_CELLS = 1 << 15
+# Automata a candidate bank may hold: the scatter back to the bank's
+# columns names an automaton by two base-256 digits (bf16 holds an
+# integer up to 256 exactly; compile_dfa_group)
+CANDIDATE_AUTOMATA = 1 << 16
+
+
+def pack_dfas_candidates(dfas: list[DFA], classes: dict,
+                         guard_of: list) -> dict | None:
+    """CANDIDATE packing for bytes_ops.dfa_match_candidates: one small
+    table per automaton, plus, per guard value, the automata that
+    value guards. `guard_of[i]`, values in [0, G), names the values of
+    the bank's guard (an id-equality every conjunction holding
+    automaton i asserts, compiler/ruleset.py) under which automaton i
+    can change a verdict: one where one host holds the pattern,
+    several where hosts share it. A row scans its own guard value's K
+    automata and not the bank's N.
+
+    A table is indexed by state and byte CLASS, not byte (a
+    10 000-automaton route table has ~30 classes: an eighth of a
+    256-column table), its width rounded up to a power of two, and
+    holds the automaton's LOCAL next state, one byte a cell where the
+    widest automaton allows. `classes` may be those of a larger bank
+    (a finer partition serves). Automaton N is the dead one every
+    padding candidate runs: it stays in state 0 and never accepts.
+
+    An automaton sits at ONE slot under every value that holds it
+    (shared automata are placed first, each at the lowest slot free
+    under all its values), so the scatter back to the bank's columns
+    is one [K, N] selection whatever the row's value.
+
+    Returns {"local": int8|int32 [N+1, s_max·width], "accept": bool
+    [(N+1)·s_max], "class_of": int32 [256], "cand": int32 [G+1, K]
+    automaton indices padded with N (row G: a row whose guard names no
+    value), "slot": int32 [N], "n_states_max", "width", "k"}; None
+    when K tables are more than CANDIDATE_CELLS a row, or the bank
+    more than CANDIDATE_AUTOMATA."""
+    n = len(dfas)
+    s_max = max(d.n_states for d in dfas)
+    rep = classes["rep"]
+    width = 1 << max(len(rep) - 1, 0).bit_length()
+    n_values = 1 + max(v for held in guard_of for v in held)
+    taken: list[set] = [set() for _ in range(n_values)]
+    slot = np.empty(n, np.int32)
+    for i in sorted(range(n), key=lambda i: -len(guard_of[i])):
+        at = 0
+        while any(at in taken[v] for v in guard_of[i]):
+            at += 1
+        slot[i] = at
+        for v in guard_of[i]:
+            taken[v].add(at)
+    k = int(slot.max()) + 1
+    if k * s_max * width > CANDIDATE_CELLS or n >= CANDIDATE_AUTOMATA:
+        return None
+    # unwritten cells (padding states and classes, the dead automaton)
+    # are never reached, or lead to state 0 of an automaton that
+    # accepts nothing
+    local = np.zeros((n + 1, s_max, width),
+                     np.int8 if s_max <= 127 else np.int32)
+    accept = np.zeros((n + 1, s_max), bool)
+    cand = np.full((n_values + 1, k), n, np.int32)
+    for i, d in enumerate(dfas):
+        local[i, :d.n_states, :len(rep)] = d.transitions[:, rep]
+        accept[i, :d.n_states] = d.accept
+        cand[list(guard_of[i]), slot[i]] = i
+    return {"local": local.reshape(n + 1, -1),
+            "accept": accept.reshape(-1),
+            "class_of": classes["class_of"].astype(np.int32),
+            "cand": cand, "slot": slot, "n_states_max": s_max,
+            "width": width, "k": k}
+
+
+def pack_dfas_tiered(dfas: "list[DFA]", guard_of=None) -> dict:
     """One home for the engine-wide DFA bank strategy (used by both
     tensor_expr.compile_dfa_group and the policy engine's list banks):
     dense one-hot MXU matmul (small banks), BLOCK-DIAGONAL one-hot
     (banks of many small automata — O(N·s_max²·C) per step where dense
-    is quadratic in the whole bank), flat-gather scan (pathological
-    single automata too big for either). The MXU formulations win at
-    EVERY batch size — the per-step [B, N] gather is latency-bound on
-    TPU — so flat tables are built ONLY when both one-hot tiers are
-    infeasible (they would otherwise be dead device weight).
+    is quadratic in the whole bank), and past both either a scan of
+    each row's CANDIDATE automata, where the caller can name guard
+    values for automata (`guard_of`: per automaton the values that
+    guard it, () for one read unguarded; pack_dfas_candidates: a route
+    table's 10 000 patterns, ten a host), or the flat-gather scan of
+    the whole bank (pathological single automata too big for either
+    one-hot tier). The MXU formulations win at EVERY batch size — the
+    per-step [B, N] gather is latency-bound on TPU — so flat tables
+    are built ONLY when nothing else is feasible (they would otherwise
+    be dead device weight).
 
-    → {"packed", "packed_blk", "trans", "accept", "classes"} with
-    exactly one of packed / packed_blk / (trans, accept) non-None.
+    → {"packed", "packed_blk", "cand", "trans", "accept", "classes"}
+    with exactly one of packed / packed_blk / cand / (trans, accept)
+    non-None. Under `cand` the bank is SPLIT: it packs the guarded
+    automata (cand["members"]: their indices in `dfas`) and `rest`,
+    None where every automaton is guarded, is this function's own
+    result for the others (rest["members"]), a bank every row scans:
+    a mesh-wide pattern beside a route table must not take the table
+    off the candidate scan.
     """
     classes = pack_dfas_classes(dfas)
     s_max = max(d.n_states for d in dfas)
@@ -597,11 +722,23 @@ def pack_dfas_tiered(dfas: "list[DFA]") -> dict:
                 <= 4_000_000)
     blocked_ok = (len(dfas) * s_max ** 2 * classes["n_classes"]
                   <= 8_000_000)
-    packed = pack_dfas_onehot(dfas, classes) if dense_ok else None
-    packed_blk = None if dense_ok or not blocked_ok else \
-        pack_dfas_onehot_blocked(dfas, classes)
-    trans = accept = None
-    if packed is None and packed_blk is None:
-        trans, accept = pack_dfas(dfas)
-    return {"packed": packed, "packed_blk": packed_blk,
-            "trans": trans, "accept": accept, "classes": classes}
+    out = {"packed": None, "packed_blk": None, "cand": None,
+           "trans": None, "accept": None, "classes": classes}
+    if dense_ok:
+        out["packed"] = pack_dfas_onehot(dfas, classes)
+        return out
+    if blocked_ok:
+        out["packed_blk"] = pack_dfas_onehot_blocked(dfas, classes)
+        return out
+    held = [i for i, g in enumerate(guard_of or ()) if len(g)]
+    cand = pack_dfas_candidates(
+        [dfas[i] for i in held], classes,
+        [guard_of[i] for i in held]) if held else None
+    if cand is None:
+        out["trans"], out["accept"] = pack_dfas(dfas)
+        return out
+    rest = sorted(set(range(len(dfas))) - set(held))
+    out["cand"] = {**cand, "members": held, "rest": {
+        "members": rest, **pack_dfas_tiered([dfas[i] for i in rest])}
+        if rest else None}
+    return out

@@ -1076,6 +1076,7 @@ def build_fused_plan(snapshot: Snapshot,
     except Exception as exc:   # toolchain missing → python tensorize
         log.warning("native tensorizer unavailable, serving with the "
                     "python wire decoder: %s", exc)
+    monitor.note_dfa_banks(rs.geometry.get("dfa_banks", ()))
     log.info("fused plan: %d deny rules, %d lists, %d rbac actions "
              "(%d pseudo-rules), %d host-overlay rules, native=%s",
              len(deny_by_rule), len(lists), len(rbacs),

@@ -197,6 +197,42 @@ def device_program_counters() -> dict:
             for path in DEVICE_PROGRAM_PATHS}
 
 
+# -- the resident DFA banks of the plan in force, set at plan build
+# (runtime/fused.py) from the compiled ruleset's geometry: what the
+# snapshot's constant-pattern regexes cost on the device and how the
+# compiler chose to scan them (tier: onehot / onehot-blocked /
+# candidates / gather, ops/regex_dfa.pack_dfas_tiered). `subject` is
+# the scanned expression (request.path, a header probe).
+DFA_BANK_BYTES = hostmetrics.default_registry.gauge(
+    "mixer_dfa_bank_bytes",
+    "resident device bytes of one subject's DFA bank (label: subject)")
+DFA_BANK_AUTOMATA = hostmetrics.default_registry.gauge(
+    "mixer_dfa_bank_automata",
+    "automata in one subject's DFA bank (labels: subject, tier)")
+DFA_CANDIDATES_MAX = hostmetrics.default_registry.gauge(
+    "mixer_dfa_candidates_max",
+    "automata one row is scanned against: the bank's size, or under "
+    "tier=candidates the most any guard value holds plus the automata "
+    "no value guards (label: subject)")
+
+
+def note_dfa_banks(banks) -> None:
+    """`banks`: RuleSetProgram.geometry["dfa_banks"] of the plan being
+    built; a subject whose bank is split (its guarded automata under
+    tier=candidates, the others beside them) reads the sum. Series of
+    a bank the new plan no longer has read 0."""
+    gauges = (DFA_BANK_BYTES, DFA_BANK_AUTOMATA, DFA_CANDIDATES_MAX)
+    for gauge in gauges:
+        for labels in gauge.label_sets():
+            gauge.set(0, **labels)
+    for b in banks:
+        by = {"subject": b["subject"]}
+        DFA_BANK_BYTES.set(DFA_BANK_BYTES.value(**by) + b["bytes"], **by)
+        DFA_BANK_AUTOMATA.set(b["automata"], tier=b["tier"], **by)
+        DFA_CANDIDATES_MAX.set(
+            DFA_CANDIDATES_MAX.value(**by) + b["candidates"], **by)
+
+
 # -- adapter-executor plane (runtime/executor.py) --------------------
 #
 # Conservation invariant (the report plane's doctrine applied to host

@@ -162,6 +162,55 @@ def test_one_check_program_compiles(plan, one_chip):
     assert code < 32 * 1024 ** 2, code
 
 
+@pytest.fixture(scope="module")
+def route_plan():
+    """benchmark/configs/routematch10k.json as run: 10 000 match
+    blocks, each its own regex (BASELINE config 3)."""
+    import importlib.util
+    import json
+    from pathlib import Path
+
+    from istio_tpu.attribute.global_dict import GLOBAL_MANIFEST
+    from istio_tpu.runtime.config import SnapshotBuilder
+    from istio_tpu.runtime.fused import build_fused_plan
+
+    configs = Path(__file__).resolve().parent.parent / "benchmark" / "configs"
+    sizes = json.loads((configs / "routematch10k.json").read_text())
+    spec = importlib.util.spec_from_file_location(
+        "bench_config_routematch", configs / "routematch.py")
+    config = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(config)
+    snap = SnapshotBuilder(default_manifest={
+        k: GLOBAL_MANIFEST[k] for k in sizes["manifest"]}).build(
+            config.make_store(sizes))
+    return build_fused_plan(snap)
+
+
+def test_candidate_tier_step_compiles_at_10k_automata(route_plan,
+                                                      one_chip):
+    """Both banks of the route table past the one-hot tiers, scanned
+    a host's own blocks a row (8 + 3); the whole-bank gather this replaces put
+    the bank in the program text (625 MB of StableHLO a shape, 335 MB
+    of code) and gathered [2048, 7500] a byte."""
+    plan = route_plan
+    banks = plan.engine.ruleset.geometry["dfa_banks"]
+    assert [(b["tier"], b["automata"], b["candidates"]) for b in banks] \
+        == [("candidates", 7500, 8), ("candidates", 2500, 3)]
+    assert sum(b["bytes"] for b in banks) < 64 * 1024 ** 2
+    bucket, tier = SHAPES[-1]
+    tele = plan.telemetry
+    n_real = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    accs = _on(one_chip, (tele._acc_hit, tele._acc_deny, tele._acc_err))
+    lowered = jax.jit(plan._base_step()).lower(
+        *_step_args(plan, one_chip, bucket, tier), n_real, *accs)
+    # the banks ride the arguments, not the program
+    assert len(lowered.as_text()) < 4 * 1024 ** 2
+    compiled = lowered.compile()
+    _fits(compiled)
+    code = compiled.memory_analysis().generated_code_size_in_bytes
+    assert code < 32 * 1024 ** 2, code
+
+
 @pytest.mark.parametrize("variant", ("fast", "unit", "seg"))
 def test_rolling_quota_alloc_compiles(one_chip, variant):
     """The three alloc kernels DeviceQuotaPool._flush selects between,
