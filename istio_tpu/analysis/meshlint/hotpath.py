@@ -69,7 +69,7 @@ HOT_ROOTS: tuple[str, ...] = (
     "TrafficRecorder.tap",
     "RuleTelemetry.observe", "RuleTelemetry.chain",
     "RuleTelemetry.add_host",
-    "RuleTelemetry.sample", "RuleTelemetry.drain",
+    "RuleTelemetry.sample_rows", "RuleTelemetry.drain",
     # flight-recorder tape primitives (per-batch/per-stage)
     "FlightRecorder.batch_begin", "FlightRecorder.stage_mark",
     "FlightRecorder.host_wait", "FlightRecorder.note_wire_decode",
@@ -95,6 +95,13 @@ DYNAMIC_EDGES: tuple[tuple[str, str], ...] = (
     # Dispatcher.fused is an untyped ctor param (plan = self.fused);
     # the swap-warm oracle bridge consults it on every served batch
     ("Dispatcher._check_fused", "FusedPlan.swap_warm_pending"),
+    # _fold_respond's nested respond_row (the resolver follows no call
+    # into or out of a nested def): every call the row builder makes
+    ("Dispatcher._fold_respond", "Dispatcher._apply_device_status"),
+    ("Dispatcher._fold_respond", "Dispatcher._safe_check"),
+    ("Dispatcher._fold_respond", "Dispatcher._combine"),
+    ("Dispatcher._fold_respond", "Dispatcher._handler_for"),
+    ("Dispatcher._fold_respond", "AdapterExecutor.resolve"),
 )
 
 # reachable-but-cold: traversal stops AT these functions and they are

@@ -55,6 +55,22 @@ _REJECT_CODES = {
 }
 
 
+def _real_rows(responses: list, n: int) -> list:
+    """A padded chunk's responses without its padding rows' (the
+    fused path answers the real rows alone, and keeps its classes)."""
+    return responses if len(responses) == n else responses[:n]
+
+
+def _joined(parts: list) -> list:
+    """A batch served in chunks, as one list. One chunk, the usual
+    case, stays the list it is, with its verdict classes
+    (ClassedResponses); more make a plain list, which a front answers
+    a row at a time."""
+    if len(parts) == 1:
+        return parts[0]
+    return [r for part in parts for r in part]
+
+
 def _reject_status(exc: CheckRejected) -> "grpc.StatusCode":
     return _REJECT_CODES.get(exc.grpc_code, grpc.StatusCode.UNKNOWN)
 
@@ -346,19 +362,20 @@ class MixerGrpcServer:
         from istio_tpu.runtime.batcher import pad_to_bucket
 
         buckets = self.runtime.batcher.buckets
-        results: list = []
+        parts: list = []
         for lo in range(0, len(bags), buckets[-1]):
             chunk = bags[lo:lo + buckets[-1]]
             if deadline is not None and \
                     time.perf_counter() >= deadline:
                 monitor.CHECK_DEADLINE_EXPIRED.inc(len(chunk))
-                results.extend(self._expired_response()
-                               for _ in chunk)
+                parts.append([self._expired_response()
+                              for _ in chunk])
                 continue
             padded = pad_to_bucket(chunk, buckets)
-            results.extend(
-                self.runtime.check_batch_preprocessed(padded)[:len(chunk)])
-        return results
+            parts.append(_real_rows(
+                self.runtime.check_batch_preprocessed(padded),
+                len(chunk)))
+        return _joined(parts)
 
     def _check_bags_quota_instep(self, bags: list, qspecs: list,
                                  target, deadline: float | None = None
@@ -377,7 +394,7 @@ class MixerGrpcServer:
         from istio_tpu.runtime.batcher import pad_to_bucket
 
         buckets = self.runtime.batcher.buckets
-        results: list = []
+        parts: list = []
         qres: dict[int, Any] = {}
         cap = buckets[-1]
         for lo in range(0, len(bags), cap):
@@ -385,8 +402,8 @@ class MixerGrpcServer:
             if deadline is not None and \
                     time.perf_counter() >= deadline:
                 monitor.CHECK_DEADLINE_EXPIRED.inc(len(chunk))
-                results.extend(self._expired_response()
-                               for _ in chunk)
+                parts.append([self._expired_response()
+                              for _ in chunk])
                 continue
             padded = pad_to_bucket(chunk, buckets)
             qrows = [(i, qspecs[lo + i][0], qspecs[lo + i][1])
@@ -394,10 +411,10 @@ class MixerGrpcServer:
                      if qspecs[lo + i] is not None]
             resps, rq = self.runtime.check_batch_quota_instep(
                 padded, qrows, target)
-            results.extend(resps[:len(chunk)])
+            parts.append(_real_rows(resps, len(chunk)))
             for i, qr in rq.items():
                 qres[lo + i] = qr
-        return results, qres
+        return _joined(parts), qres
 
     def _check_bag(self, request: RawCheckRequest,
                    identity: str | None = None):

@@ -16,14 +16,19 @@ wire keeps running), run the batch through
 RuntimeServer.check_batch_preprocessed / report, resolve quotas via
 the device pools, and hand serialized CheckResponse bytes back for
 C++ to frame. Response serialization is memoized per verdict signature
-(uniform traffic → a handful of distinct responses per snapshot).
+(uniform traffic → a handful of distinct responses per snapshot), and
+a batch that comes with its verdict classes (ClassedResponses) is
+serialised and framed once a class, not once a row.
 """
 from __future__ import annotations
 
 import ctypes
+import functools
 import logging
 import struct
 import threading
+
+import numpy as np
 
 from istio_tpu.adapters.sdk import QuotaArgs
 from istio_tpu.api import mixer_pb2 as pb
@@ -51,6 +56,45 @@ class _RowRequest:
     def __init__(self, dedup: str, quotas: dict):
         self.deduplication_id = dedup
         self.quotas = quotas
+
+
+# Rows of a batch that share their response's bytes are framed as one
+# numpy record array from this many on; fewer take the per-row
+# framing. A record array costs 4-5 us whatever its length, a row of
+# struct.pack and its two appends ~0.6 us (timed on a CPU host, 90-byte
+# responses: 8 rows 4.8 against 5.3 us, 64 rows 10 against 35).
+_FRAME_CLASS_MIN_ROWS = 8
+
+
+@functools.lru_cache(maxsize=256)
+def _frame_dtype(length: int) -> np.dtype:
+    """One completion of the blob h2srv_complete takes (tag, status,
+    length, bytes), packed, for a response of `length` bytes."""
+    return np.dtype([("tag", "<u8"), ("status", "<i4"), ("len", "<u4"),
+                     ("raw", "u1", (length,))])
+
+
+class _Completions(list):
+    """A batch's completions on their way to h2srv_complete: the list
+    holds (tag, status, bytes), one a row, framed at the send; rows
+    that share an OK response's bytes are framed here, a class at a
+    time, and counted beside it."""
+
+    def __init__(self):
+        super().__init__()
+        self.framed: list[bytes] = []
+        self.framed_tags: list[np.ndarray] = []
+        self.n_framed = 0
+
+    def frame(self, tags: np.ndarray, raw: bytes) -> None:
+        rec = np.empty(len(tags), _frame_dtype(len(raw)))
+        rec["tag"] = tags
+        rec["status"] = 0
+        rec["len"] = len(raw)
+        rec["raw"] = np.frombuffer(raw, np.uint8)
+        self.framed.append(rec.tobytes())
+        self.framed_tags.append(tags)
+        self.n_framed += len(tags)
 
 
 # must mirror Server::kLatBuckets in httpd.cpp: the wire latency
@@ -483,7 +527,7 @@ class NativeMixerServer(MixerGrpcServer):
         """One taken batch: the first `n` bytes of the pump's take
         buffer `buf`."""
         items: list = []
-        completions: list[tuple[int, int, bytes]] = []
+        completions = _Completions()
         deferred: set[int] = set()
         try:
             with monitor.span("wire_decode") as decode:
@@ -511,6 +555,8 @@ class NativeMixerServer(MixerGrpcServer):
             log.exception("native pump batch failed")
         with monitor.span("send"):
             done = {tag for tag, _, _ in completions} | deferred
+            for tags in completions.framed_tags:
+                done.update(tags.tolist())
             for item in items:
                 if item[0] not in done:
                     completions.append(
@@ -699,9 +745,87 @@ class NativeMixerServer(MixerGrpcServer):
             self._serialize_rows(checks, bags, results, inres,
                                  completions, deferred, span)
 
+    def _memo_response(self, result) -> tuple[bytes | None, bool]:
+        """→ (the serialized response of a quota-less `result`, whether
+        the memo held it). Memoised ONLY where the bytes do not depend
+        on the bag, which is therefore not asked for: presence must
+        COVER the referenced set (incomplete presence makes
+        _referenced_proto fall back to per-bag lookups —
+        grpc_server._referenced_proto applies the same gate); (None,
+        False) where it does not."""
+        presence = result.referenced_presence
+        if presence is None or \
+                len(presence) != len(result.referenced):
+            return None, False
+        key = (result.status_code, result.status_message,
+               result.valid_duration_s,
+               result.valid_use_count, result.referenced,
+               frozenset(presence.items()))
+        raw = self._resp_memo.get(key)
+        if raw is not None:
+            return raw, True
+        raw = self._check_response(
+            None, None, result, quotas=[]).SerializeToString()
+        if len(self._resp_memo) > 8192:
+            self._resp_memo.clear()
+        self._resp_memo[key] = raw
+        return raw, False
+
+    def _frame_classes(self, checks: list, results,
+                       completions: _Completions) -> list[int]:
+        """Serialise once a verdict class (ClassedResponses) and frame
+        the rows that share bytes together: one record array a wire
+        class of _FRAME_CLASS_MIN_ROWS rows or more, the per-row tuple
+        below it. → the rows left to the per-row code: those that ask
+        for a quota, and those whose bytes depend on their bag."""
+        class_of = results.class_of
+        asking = [row for row, item in enumerate(checks) if item[5]]
+        here = range(len(results.classes))
+        if asking:
+            # a class all of whose rows ask for a quota is not looked
+            # up: every response built here answers a row here (the
+            # count below stays exact, and never negative)
+            quiet = np.ones(len(checks), bool)
+            quiet[asking] = False
+            here = np.unique(class_of[quiet]).tolist()
+        raws: list[bytes] = []
+        wire_ids: dict[bytes, int] = {}
+        wire_of_class = np.full(len(results.classes), -1, np.intp)
+        built = 0
+        for c in here:
+            raw, held = self._memo_response(results.classes[c])
+            if raw is None:
+                continue
+            built += not held
+            wire = wire_ids.get(raw)
+            if wire is None:
+                wire = wire_ids[raw] = len(raws)
+                raws.append(raw)
+            wire_of_class[c] = wire
+        wire_of = wire_of_class[class_of]
+        if asking:
+            wire_of[asking] = -1
+        order = np.argsort(wire_of, kind="stable")
+        counts = np.bincount(wire_of + 1, minlength=len(raws) + 1)
+        tags = np.fromiter((item[0] for item in checks), np.uint64,
+                           len(checks))
+        left = at = int(counts[0])
+        for raw, rows in zip(raws, counts[1:].tolist()):
+            mine = tags[order[at:at + rows]]
+            at += rows
+            if rows >= _FRAME_CLASS_MIN_ROWS:
+                completions.frame(mine, raw)
+            else:
+                completions.extend((tag, 0, raw)
+                                   for tag in mine.tolist())
+        # every row answered here is a response; _check_response
+        # counted the ones it built
+        monitor.CHECK_RESPONSES.inc(len(checks) - left - built)
+        return order[:left].tolist()
+
     def _serialize_rows(self, checks: list, bags: list, results: list,
-                        inres: dict, completions: list, deferred: set,
-                        span: dict | None) -> None:
+                        inres: dict, completions: _Completions,
+                        deferred: set, span: dict | None) -> None:
         # `status` tag (batch-level: ok or the first non-OK code) so
         # /debug/traces can filter failing check spans on this front
         if span is not None:
@@ -709,9 +833,13 @@ class NativeMixerServer(MixerGrpcServer):
                               if r.status_code), 0)
             span["tags"]["status"] = "ok" if first_bad == 0 \
                 else str(first_bad)
+        rows = range(len(checks))
+        if len(getattr(results, "class_of", ())) == len(checks) \
+                and len(results.classes) < len(checks) and not inres:
+            rows = self._frame_classes(checks, results, completions)
         memo_hits = 0
-        for row, (item, bag, result) in enumerate(
-                zip(checks, bags, results)):
+        for row in rows:
+            item, bag, result = checks[row], bags[row], results[row]
             tag, _, _, _, dedup, quotas, _ = item
             try:
                 if row in inres:
@@ -749,39 +877,25 @@ class NativeMixerServer(MixerGrpcServer):
                 completions.append(
                     (tag, 13, f"quota submit: {exc}".encode()))
                 continue
-            # memo ONLY bag-independent responses: presence must
-            # COVER the referenced set (incomplete presence makes
-            # _referenced_proto fall back to per-bag lookups —
-            # grpc_server._referenced_proto applies the same gate)
-            presence = result.referenced_presence
-            if presence is not None and \
-                    len(presence) == len(result.referenced):
-                key = (result.status_code, result.status_message,
-                       result.valid_duration_s,
-                       result.valid_use_count, result.referenced,
-                       frozenset(presence.items()))
-                raw = self._resp_memo.get(key)
-                if raw is None:
-                    raw = self._check_response(
-                        None, bag, result,
-                        quotas=[]).SerializeToString()
-                    if len(self._resp_memo) > 8192:
-                        self._resp_memo.clear()
-                    self._resp_memo[key] = raw
-                else:
-                    memo_hits += 1
-            else:
+            raw, held = self._memo_response(result)
+            if raw is None:
                 raw = self._check_response(
                     None, bag, result,
                     quotas=[]).SerializeToString()
+            memo_hits += held
             completions.append((tag, 0, raw))
         if memo_hits:   # memoized rows skip _check_response
             monitor.CHECK_RESPONSES.inc(memo_hits)
 
     def _send_completions(self, completions: list) -> None:
-        if not completions:
+        """A _Completions brings the completions it framed already
+        beside its tuples; their order in the blob is free, the tags
+        name them."""
+        framed = getattr(completions, "framed", ())
+        n_framed = getattr(completions, "n_framed", 0)
+        if not completions and not n_framed:
             return
-        out = [struct.pack("<I", len(completions))]
+        out = [struct.pack("<I", len(completions) + n_framed), *framed]
         for tag, status, raw in completions:
             out.append(struct.pack("<QiI", tag, status, len(raw)))
             out.append(raw)

@@ -50,7 +50,13 @@ DEFAULT_IDENTITY_ATTR = "destination.service"
 
 @dataclasses.dataclass
 class CheckResponse:
-    """Precondition result (CheckResponse.PreconditionResult)."""
+    """Precondition result (CheckResponse.PreconditionResult).
+
+    SHARED, DO NOT MUTATE: the fused path builds one response a verdict
+    class and hands the same object to every row of the class (and
+    `referenced` / `referenced_presence` are shared wider still, by
+    signature). A caller that must change a field changes a copy
+    (`dataclasses.replace`)."""
     status_code: int = OK
     status_message: str = ""
     valid_duration_s: float = 5.0
@@ -74,6 +80,29 @@ class CheckResponse:
     # status, which stays unattributed here). The canary recorder and
     # shadow replay (istio_tpu/canary) key their per-rule diff on it.
     deny_rule: int = -1
+
+
+class ClassedResponses(list):
+    """A batch's CheckResponses, one a real row, as every caller
+    indexes them, with the verdict classes the respond stage built
+    them by: `classes[class_of[b]] is self[b]`. Rows equal in all that
+    decides a response (status, TTL, use count, denying rule,
+    referenced/presence signature with the active quota rules, grant)
+    share one object; a row under a host action is a class of one. A
+    front serialises and frames once a class instead of once a row."""
+    classes: list            # the distinct CheckResponse objects
+    class_of: np.ndarray     # int [n rows] → index into `classes`
+
+
+# A batch of fewer real rows than this builds its responses a row: the
+# class keys (a min/max a plane and one np.unique) cost more than the
+# loop they would save. Stage `respond` on the chip machine's host,
+# ~6 classes a batch (PERF.md §6, PR 34): 32 rows 0.18-0.20 ms a class
+# against 0.17-0.18 a row, 48 rows 0.19 against 0.24-0.26, 64 rows 0.21
+# against 0.30, 1 363 rows 0.52-0.58 against 5.1-5.3. Read at few
+# classes a batch: a class costs its build, so many classes in a batch
+# this small would move the break-even up (no cell sends one).
+RESPOND_CLASS_MIN_ROWS = 48
 
 
 def _namespace_of(bag: Bag, identity_attr: str) -> str:
@@ -154,11 +183,11 @@ class Dispatcher:
     # resolution
     # ------------------------------------------------------------------
 
-    def _grants_for_rows(self, ns_ids) -> list | None:
-        """Per-row (ttl_s, use_count) from the grant policy — one
-        policy round per DISTINCT namespace in the batch (uniform
-        traffic: one or two lock acquisitions per batch). None when
-        grants are off."""
+    def _grants_for_rows(self, ns_ids) -> tuple | None:
+        """(grants, inverse): the (ttl_s, use_count) of each DISTINCT
+        namespace in the batch and each row's index into them — one
+        policy round per distinct namespace (uniform traffic: one or
+        two lock acquisitions per batch). None when grants are off."""
         if self.grants is None:
             return None
         inv = self._ns_name_of
@@ -170,9 +199,8 @@ class Dispatcher:
         # never a device buffer, so this asarray copies host memory
         uniq, inverse = np.unique(np.asarray(ns_ids),  # hotpath: sync-ok host id list
                                   return_inverse=True)
-        gs = self.grants.grants_for(
-            [inv.get(int(u), "") for u in uniq])
-        return [gs[i] for i in inverse]
+        return self.grants.grants_for(
+            [inv.get(int(u), "") for u in uniq]), inverse
 
     def _apply_grants(self, bags: Sequence[Bag], responses) -> None:
         """Generic/oracle-path grant fold (per-bag namespace lookup —
@@ -501,16 +529,18 @@ class Dispatcher:
     def _fold_respond(self, snap, plan, packed: np.ndarray, batch,
                       bags: Sequence[Bag], ns_ids: np.ndarray,
                       observe: bool, deadline: float | None
-                      ) -> list[CheckResponse]:
+                      ) -> ClassedResponses:
         """The host half of the fused check after the pull: stage
         `fold` (packed-plane decode: overlay bits, host-action
         submits, referenced/presence signature dedup) then stage
-        `respond` (the per-row CheckResponse loop). `bags`/`ns_ids`
+        `respond` (one CheckResponse a verdict class). `bags`/`ns_ids`
         are the real prefix — bucket-padding rows carry no caller
         (the batcher appends PadBags at the tail and zips results
         against real requests), and at small arrival rates a
         512-bucket batch is mostly padding: per-row python here is
         the serving CPU budget."""
+        from istio_tpu.runtime.fused import (
+            class_int_rows, dedup_bit_rows, unpack_word_rows)
         from istio_tpu.utils import tracing
 
         tr = tracing.get_tracer()
@@ -534,8 +564,6 @@ class Dispatcher:
                 # request; the host just decodes set bits into names
                 n_words = plan.n_ref_words
                 if n_words:
-                    from istio_tpu.runtime.fused import (
-                        dedup_bit_rows, unpack_word_rows)
                     ref_bits = unpack_word_rows(
                         packed[5:5 + n_words, :n_real],
                         len(plan.item_names))
@@ -562,10 +590,12 @@ class Dispatcher:
                                     np.int64)
                 qa_rules = sorted({qa[0] for qa in plan.quota_actions})
                 qa_pos = [col_pos[r] for r in qa_rules]
+                # rows with a host action active: their response
+                # reads the bag and the executor's results
+                host_rows = active_sub[:, ha_pos].any(axis=1)
                 if observe:
                     monitor.note_check_decided(plan.rows_by_section(
-                        deny_rule[:n_real],
-                        active_sub[:, ha_pos].any(axis=1)))
+                        deny_rule[:n_real], host_rows))
 
                 # adapter-executor plane (runtime/executor.py): submit
                 # every host action NOW, so adapter calls run on their
@@ -619,14 +649,14 @@ class Dispatcher:
                 # 2048-batch, single-threaded in the batcher worker.
                 # Shared objects are read-only by contract (the gRPC
                 # layer only serializes them).
-                ref_of = None
+                ref_of = sig_of = None
                 if n_words:
                     # NOT np.unique over rows (`axis=0`) of the unpacked
                     # bytes: it sorts ~60-field records field by field
                     # under the GIL, ~20 ms a 1,300-row batch.
                     with monitor.span("fold.signature", on=observe,
                                       batch=n_real) as keyed:
-                        first, inverse = dedup_bit_rows(
+                        first, sig_of = dedup_bit_rows(
                             (ref_bits, present_np, map_present_np,
                              active_sub))
                         keyed.tag(distinct=len(first))
@@ -662,7 +692,7 @@ class Dispatcher:
                         shared.append(
                             (tuple(sorted(referenced, key=str)),
                              presence))
-                    ref_of = [shared[i] for i in inverse]
+                    ref_of = [shared[i] for i in sig_of]
                 elif plan.unmapped_instance_attrs:
                     # no layout items at all, but some rules still
                     # carry instance attrs — merge them per row from
@@ -696,8 +726,13 @@ class Dispatcher:
                 with monitor.span("grant", tap=True, on=observe and
                                   self.grants is not None):
                     grant_of = self._grants_for_rows(ns_ids)
-                out = []
-                for b, bag in enumerate(bags):
+                denied = status[:n_real] != OK
+
+                def respond_row(b: int) -> CheckResponse:
+                    """Row b's response: the device verdict merged
+                    with the row's host actions, lowest rule index
+                    first. The one copy of the merge rules: a verdict
+                    class takes its response from its first row."""
                     resp = CheckResponse()
                     resp.valid_duration_s = min(resp.valid_duration_s,
                                                 float(dur[b]))
@@ -706,7 +741,7 @@ class Dispatcher:
                     dev_rule = int(deny_rule[b])
                     dev_applied = False
                     host_active = ha[active_sub[b, ha_pos]] \
-                        if len(ha) else ()
+                        if host_rows[b] else ()
                     pend = host_pending[b] \
                         if host_pending is not None else None
                     pi = 0
@@ -747,16 +782,13 @@ class Dispatcher:
                             for iname in inst_names:
                                 ib = snap.instances[iname]
                                 result = self._safe_check(
-                                    handler, template, ib, bag)
+                                    handler, template, ib, bags[b])
                                 self._combine(resp, result)
                     if not dev_applied:
                         self._apply_device_status(resp, plan, dev_rule,
                                                   int(status[b]))
                     if status[b] != OK:
                         resp.deny_rule = dev_rule
-                        if tele is not None:
-                            tele.sample(dev_rule, int(status[b]), bag,
-                                        tele_span)
                     # referenced/presence: precomputed per unique
                     # signature
                     if ref_of is not None:
@@ -770,12 +802,53 @@ class Dispatcher:
                     else:
                         resp.active_quota_rules = ()
                     if grant_of is not None:
-                        g_ttl, g_uses = grant_of[b]
+                        grants, grant_class = grant_of
+                        g_ttl, g_uses = grants[grant_class[b]]
                         resp.valid_duration_s = min(
                             resp.valid_duration_s, g_ttl)
                         resp.valid_use_count = min(
                             resp.valid_use_count, g_uses)
-                    out.append(resp)
+                    return resp
+
+                if n_real < RESPOND_CLASS_MIN_ROWS:
+                    # too few rows to repay the keys: a response a row
+                    first = class_of = np.arange(n_real)
+                else:
+                    # One response a VERDICT CLASS: rows equal in every
+                    # plane a response is built from share one object.
+                    # A row under a host action reads its bag and its
+                    # executor items, so it is a class of its own; the
+                    # signature class holds the row's active_sub bits,
+                    # the quota rules' among them.
+                    columns = [
+                        status[:n_real], packed[1, :n_real],
+                        uses[:n_real],
+                        np.where(denied, deny_rule[:n_real], -1),
+                        sig_of if sig_of is not None else
+                        dedup_bit_rows((active_sub,))[1]]
+                    if grant_of is not None:
+                        columns.append(grant_of[1])
+                    if host_rows.any():
+                        columns.append(np.where(
+                            host_rows, np.arange(1, n_real + 1), 0))
+                    first, class_of = class_int_rows(columns)
+                classes = [respond_row(b) for b in first.tolist()]
+                out = ClassedResponses(
+                    map(classes.__getitem__, class_of.tolist())
+                    if len(classes) < n_real else classes)
+                out.classes, out.class_of = classes, class_of
+                if observe:
+                    alone = np.count_nonzero(np.bincount(class_of) == 1) \
+                        if len(first) < n_real else n_real
+                    monitor.note_respond_classes(
+                        len(classes), n_real - alone, alone)
+                if tele is not None:
+                    rows = np.flatnonzero(denied)
+                    if len(rows):
+                        tele.sample_rows(
+                            deny_rule[rows].tolist(),
+                            status[rows].tolist(),
+                            [bags[b] for b in rows.tolist()], tele_span)
             if self.recorder is not None:
                 # canary tap: bags/out are already padding-trimmed; one
                 # stride check per batch, bounded appends for sampled rows

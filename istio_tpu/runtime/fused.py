@@ -125,6 +125,39 @@ def dedup_bit_rows(planes) -> tuple[np.ndarray, np.ndarray]:
     return order[new], inverse
 
 
+def class_int_rows(columns) -> tuple[np.ndarray, np.ndarray]:
+    """Exact classes of the rows of integer columns [B] laid side by
+    side → (first [U]: one representative row a class, inverse [B]:
+    each row's class), as dedup_bit_rows gives them for bit planes.
+    Each column becomes one digit of a mixed-radix int64 key (its
+    offset from the column's least value where the span is small, else
+    its 1-D np.unique rank; a constant column is no digit at all) and
+    one 1-D np.unique over the key names the classes: injective, so
+    nothing is hashed, and never np.unique over rows (`axis=0`)."""
+    n = len(columns[0])
+    key, radix = None, 1
+    for col in columns:
+        lo, hi = int(col.min()), int(col.max())   # hotpath: sync-ok host planes
+        if lo == hi:
+            continue
+        span = hi - lo + 1
+        if span <= 1 << 20:
+            digit = col.astype(np.int64) - lo
+        else:
+            uniq, digit = np.unique(col, return_inverse=True)
+            span = len(uniq)
+        if radix * span >= 1 << 62:
+            uniq, key = np.unique(key, return_inverse=True)
+            radix = len(uniq)
+        key = digit if key is None else key * span + digit
+        radix *= span
+    if key is None:
+        return np.zeros(1, np.intp), np.zeros(n, np.intp)
+    _, first, inverse = np.unique(key, return_index=True,
+                                  return_inverse=True)
+    return first, inverse
+
+
 @dataclasses.dataclass
 class FusedPlan:
     """Per-snapshot serving plan: device engine + host overlay map."""

@@ -152,6 +152,23 @@ FOLD_SIGNATURE_CLASSES = prometheus_client.Counter(
     registry=REGISTRY)
 for _r in CHECK_DECIDED_BY:
     CHECK_DECIDED.labels(by=_r)
+# the respond stage's verdict classes (Dispatcher._fold_respond): the
+# distinct CheckResponse objects a batch built, and its rows by the way
+# each got its object — `classed`: shared with another row of its
+# class; `row`: an object of its own (a class of one, a row under a
+# host action, a batch under RESPOND_CLASS_MIN_ROWS).
+RESPOND_CLASSES = prometheus_client.Counter(
+    "mixer_respond_classes_total",
+    "distinct CheckResponse objects built over the served batches "
+    "(/ the count of stage respond = mean verdict classes a batch)",
+    registry=REGISTRY)
+RESPOND_ROW_PATHS = ("classed", "row")
+RESPOND_ROWS = prometheus_client.Counter(
+    "mixer_respond_rows_total",
+    "served check rows by how the respond stage built their response",
+    ["path"], registry=REGISTRY)
+for _r in RESPOND_ROW_PATHS:
+    RESPOND_ROWS.labels(path=_r)
 
 
 def note_check_decided(rows_by_section) -> None:
@@ -159,6 +176,25 @@ def note_check_decided(rows_by_section) -> None:
     for by, rows in zip(CHECK_DECIDED_BY, rows_by_section):
         if rows:
             CHECK_DECIDED.labels(by=by).inc(int(rows))
+
+
+def note_respond_classes(classes: int, classed: int, row: int) -> None:
+    """One batch: objects built, rows that share one, rows with their
+    own."""
+    RESPOND_CLASSES.inc(classes)
+    if classed:
+        RESPOND_ROWS.labels(path="classed").inc(classed)
+    if row:
+        RESPOND_ROWS.labels(path="row").inc(row)
+
+
+def respond_class_counters() -> dict:
+    """The class sum and {path: rows}, as one JSON-able dict."""
+    return {
+        "classes_total": int(RESPOND_CLASSES._value.get()),
+        "rows": {path: int(RESPOND_ROWS.labels(path=path)._value.get())
+                 for path in RESPOND_ROW_PATHS},
+    }
 
 
 def check_decided_counters() -> dict:
@@ -478,7 +514,7 @@ def identity_counters() -> dict:
 #   fold        — packed-plane decode: overlay bits, host-action
 #                 submits, referenced / presence signature dedup
 #                 (the span fold.signature)
-#   respond     — per-row CheckResponse construction (grants included)
+#   respond     — one CheckResponse a verdict class (grants included)
 CHECK_STAGES = ("queue_wait", "tensorize", "h2d", "device_step",
                 "fold", "respond")
 CHECK_P99_TARGET_MS = 1.0   # BASELINE north star: <1ms p99 at 10k rules

@@ -264,28 +264,31 @@ class RuleTelemetry:
             for ridx, n in err_counts.items():
                 self._host_err[ridx] += n
 
-    def sample(self, ridx: int, status: int, bag, span) -> None:
-        """Reservoir-sample one denied/errored request for rule
-        `ridx`: keep the bag (compressed attribute bag — decoded at
-        drain, never here) and the active trace span ids so the
-        exemplar links straight to a RingReporter trace."""
-        entry = {
-            "status": status,
-            "bag": bag,
-            "trace_id": span.get("traceId") if span else None,
-            "span_id": span.get("id") if span else None,
-            "t": time.time(),
-        }
+    def sample_rows(self, ridxs, statuses, bags, span) -> None:
+        """Reservoir-sample one batch's denied/errored requests, row i
+        decided by rule `ridxs[i]`, under one lock: keep the bag
+        (compressed attribute bag — decoded at drain, never here) and
+        the active trace span ids so the exemplar links straight to a
+        RingReporter trace. Every row is drawn for, a uniform
+        reservoir a rule; an entry is built only for a row it keeps."""
+        trace_id = span.get("traceId") if span else None
+        span_id = span.get("id") if span else None
+        now = time.time()
         with self._lock:
-            seen = self._ex_seen.get(ridx, 0) + 1
-            self._ex_seen[ridx] = seen
-            bucket = self._ex.setdefault(ridx, [])
-            if len(bucket) < self._ex_cap:
-                bucket.append(entry)
-            else:
-                j = self._rng.randrange(seen)
+            for ridx, status, bag in zip(ridxs, statuses, bags):
+                seen = self._ex_seen.get(ridx, 0) + 1
+                self._ex_seen[ridx] = seen
+                bucket = self._ex.setdefault(ridx, [])
+                j = len(bucket)
                 if j < self._ex_cap:
-                    bucket[j] = entry
+                    bucket.append(None)
+                else:
+                    j = self._rng.randrange(seen)
+                    if j >= self._ex_cap:
+                        continue
+                bucket[j] = {"status": status, "bag": bag,
+                             "trace_id": trace_id, "span_id": span_id,
+                             "t": now}
 
     def ns_slots(self, ns_ids: np.ndarray) -> np.ndarray:
         """Request ns ids → accumulator slots (unknown/-1 → last)."""

@@ -1120,7 +1120,9 @@ class RuntimeServer:
         # inline below), and the front's wire-decode pre-mark is
         # absorbed here
         forensics.RECORDER.batch_begin()
-        out = list(self._run_check_batch(bags))
+        out = self._run_check_batch(bags)
+        if not isinstance(out, list):   # a ClassedResponses stays one
+            out = list(out)
         e2e = _time.perf_counter() - t0
         real = trim_pads(bags)
         for _ in real:                 # padding rows carry no caller

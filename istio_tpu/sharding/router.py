@@ -25,6 +25,7 @@ Stage attribution (runtime/monitor.py SHARD_STAGES):
 """
 from __future__ import annotations
 
+import dataclasses
 import time
 from typing import Any, Mapping, Sequence
 
@@ -116,10 +117,17 @@ class ShardRouter:
                     f"bank {shard} returned {len(resp)} responses "
                     f"for {len(idxs)} rows")
             l2g = bank.local_to_global
+            # a bank hands one response to every row of a verdict
+            # class (CheckResponse: shared, do not mutate): the global
+            # rule index goes on one copy a class
+            moved: dict[int, Any] = {}
             for i, r in zip(idxs, resp):
                 dr = r.deny_rule
                 if dr >= 0 and dr < len(l2g):
-                    r.deny_rule = int(l2g[dr])
+                    if id(r) not in moved:
+                        moved[id(r)] = dataclasses.replace(
+                            r, deny_rule=int(l2g[dr]))
+                    r = moved[id(r)]
                 out[i] = r
             with self._stats_lock:
                 self.rows_routed[shard] = \

@@ -22,10 +22,17 @@ def _label_key(labels: dict[str, str] | None) -> tuple:
     return tuple(sorted((labels or {}).items()))
 
 
+_LABEL_ESCAPES = str.maketrans({"\\": r"\\", '"': r"\"", "\n": r"\n"})
+
+
 def _fmt_labels(key: tuple) -> str:
+    """A label value escaped as the text format asks (backslash, double
+    quote, newline): a DFA bank's subject is an expression and holds
+    quotes and commas."""
     if not key:
         return ""
-    return "{" + ",".join(f'{k}="{v}"' for k, v in key) + "}"
+    return "{" + ",".join(
+        f'{k}="{str(v).translate(_LABEL_ESCAPES)}"' for k, v in key) + "}"
 
 
 def quantile_from_counts(buckets: tuple[float, ...],

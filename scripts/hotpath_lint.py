@@ -75,7 +75,7 @@ HOT_SECTIONS: dict[str, frozenset[str]] = {
     "istio_tpu/runtime/rulestats.py": frozenset({
         "RuleTelemetry.observe", "RuleTelemetry.chain",
         "RuleTelemetry.add_host",
-        "RuleTelemetry.sample", "RuleTelemetry.drain",
+        "RuleTelemetry.sample_rows", "RuleTelemetry.drain",
     }),
     "istio_tpu/canary/recorder.py": frozenset({
         "TrafficRecorder.tap",

@@ -17,7 +17,11 @@ from istio_tpu.utils.metrics import (Counter, Gauge, Histogram,
 
 _SAMPLE = re.compile(
     r"^(?P<name>[a-zA-Z_:][a-zA-Z0-9_:]*)"
-    r"(\{(?P<labels>[^}]*)\})?\s+(?P<value>\S+)$")
+    r"(\{(?P<labels>.*)\})?\s+(?P<value>\S+)$")
+# one label pair; the value escaped as the text format asks (\\, \", \n),
+# so it may hold commas, braces and quotes
+_PAIR = re.compile(r'([a-zA-Z_][a-zA-Z0-9_]*)="((?:[^"\\]|\\.)*)"(,|$)')
+_UNESCAPE = {"\\\\": "\\", '\\"': '"', "\\n": "\n"}
 
 
 def _parse(text: str):
@@ -29,11 +33,13 @@ def _parse(text: str):
         m = _SAMPLE.match(line)
         assert m, f"malformed sample line: {line!r}"
         labels = {}
-        if m.group("labels"):
-            for pair in m.group("labels").split(","):
-                k, v = pair.split("=", 1)
-                assert v.startswith('"') and v.endswith('"'), line
-                labels[k] = v[1:-1]
+        at, body = 0, m.group("labels") or ""
+        while at < len(body):
+            pair = _PAIR.match(body, at)
+            assert pair, line
+            labels[pair.group(1)] = re.sub(
+                r'\\[\\"n]', lambda e: _UNESCAPE[e.group()], pair.group(2))
+            at = pair.end()
         out.setdefault(m.group("name"), []).append(
             (labels, float(m.group("value"))))
     return out
@@ -126,6 +132,24 @@ def test_counter_gauge_exposition_and_help():
     assert "# TYPE reqs_total counter" in text
     assert 'reqs_total{front="grpc"} 3.0' in text
     assert "depth 7.5" in text
+
+
+def test_a_label_value_with_quotes_and_commas_reads_back():
+    """A DFA bank's subject is an expression (monitor.note_dfa_banks):
+    the exposition escapes what the text format asks, so a scraper
+    reads the value back whole."""
+    r = Registry()
+    g = r.gauge("mixer_dfa_bank_bytes", "resident bytes")
+    subject = 'OR(INDEX($request.headers, "cookie"), " absent ")'
+    odd = 'a\\b}\n{c="d",'
+    g.set(4096, subject=subject)
+    g.set(7, subject=odd, tier="onehot")
+    text = r.expose_text()
+    assert '\\"cookie\\"' in text
+    assert len(text.splitlines()) == 4      # the newline is escaped
+    assert _parse(text)["mixer_dfa_bank_bytes"] == [
+        ({"subject": subject}, 4096.0),
+        ({"subject": odd, "tier": "onehot"}, 7.0)]
 
 
 def test_runtime_monitor_registry_lints():
