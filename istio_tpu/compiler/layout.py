@@ -42,6 +42,15 @@ ID_FALSE = 1
 ID_TRUE = 2
 
 DEFAULT_MAX_STR_LEN = 128
+# The wide byte plane. A row with a subject of max_str_len bytes or more
+# is possibly truncated in the byte slots every batch carries, so both
+# tensorizers keep such a row's subjects a second time, whole up to
+# this width (AttributeBatch.wide), and the fused Check path serves
+# the row through a program compiled at it (runtime/fused.py: the
+# split). RFC 6265 has user agents hold 4 096 bytes a cookie; a plane
+# that wide costs every long row twice the scan steps and transfer of
+# this one for the last 0.5 % of rows, which the host decides exactly.
+WIDE_STR_LEN = 2048
 
 # types whose byte slots carry order-preserving keys (BOOL is NOT
 # orderable — the oracle raises on it, expr/oracle.py _ordered)
@@ -257,6 +266,13 @@ class BatchLayout:
     def n_byte_slots(self) -> int:
         return len(self.byte_slots)
 
+    @property
+    def wide_str_len(self) -> int:
+        """Width of the wide byte plane, 0 where there is none: no byte
+        slot to truncate, or slots already that wide."""
+        return WIDE_STR_LEN if self.byte_slots \
+            and self.max_str_len < WIDE_STR_LEN else 0
+
     def slot_of(self, name: str) -> int:
         return self.slots[name]
 
@@ -307,6 +323,17 @@ def build_layout(manifest: Mapping[str, ValueType],
                        extern_slots=externs, extern_defs=defs)
 
 
+@dataclasses.dataclass
+class WideRows:
+    """The rows of a batch that hold a subject of max_str_len bytes or
+    more, with every byte slot of such a row kept up to the wide width.
+    Host-only: what the fused Check path's split is cut from."""
+    row: np.ndarray      # int32 [B]: the row's index below, -1 for none
+    data: np.ndarray     # uint8 [>= count, n_byte_slots, wide_str_len]
+    lens: np.ndarray     # int32 [>= count, n_byte_slots], <= the width
+    count: int
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class AttributeBatch:
@@ -331,6 +358,9 @@ class AttributeBatch:
     # Deliberately NOT part of the pytree (neither leaf nor aux): it
     # must not retrace jits or ride to the device; id -1-k ↔ entry k.
     ephemeral_values: Any = None
+    # host-only, and no part of the pytree either: the batch's long rows
+    # (WideRows), None where the layout has no wide plane
+    wide: Any = None
 
     @property
     def batch_size(self) -> int:
@@ -408,6 +438,8 @@ class Tensorizer:
         # never equal to any constant, never retained after the batch
         eph_ids: dict[tuple[str, Hashable], int] = {}
         eph_values: list[Any] = []
+        wide_len = lay.wide_str_len
+        long_rows: dict[int, dict[int, bytes]] = {}
 
         # lock-free constant lookup (see InternTable.reader): a
         # concurrently-added constant we miss simply becomes a batch
@@ -458,6 +490,8 @@ class Tensorizer:
                     str_bytes[i, bcol, :len(enc)] = np.frombuffer(
                         enc, dtype=np.uint8)
                 str_lens[i, bcol] = len(enc)
+                if wide_len and len(raw) >= lay.max_str_len:
+                    long_rows.setdefault(i, {})[bcol] = raw[:wide_len]
             for col, prog, convert in self._externs:
                 # normalize-at-ingest: run the extern over the operand
                 # oracle; a lookup or conversion error marks the column
@@ -474,11 +508,28 @@ class Tensorizer:
                 if col in hash_slots:
                     hash_ids[i, col] = stable_hash31(converted)
 
+        wide = None
+        if wide_len:
+            # as the shim lays them: a long row's every slot, the long
+            # ones whole up to the wide width, the others as above
+            wide = WideRows(row=np.full(b, -1, np.int32),
+                            data=np.zeros((len(long_rows), nbyte, wide_len),
+                                          np.uint8),
+                            lens=np.zeros((len(long_rows), nbyte), np.int32),
+                            count=len(long_rows))
+            for k, (i, slots) in enumerate(long_rows.items()):
+                wide.row[i] = k
+                wide.data[k, :, :lay.max_str_len] = str_bytes[i]
+                wide.lens[k] = str_lens[i]
+                for bcol, raw in slots.items():
+                    wide.data[k, bcol, :len(raw)] = np.frombuffer(
+                        raw, dtype=np.uint8)
+                    wide.lens[k, bcol] = len(raw)
         return AttributeBatch(ids=ids, present=present,
                               map_present=map_present,
                               str_bytes=str_bytes, str_lens=str_lens,
                               hash_ids=hash_ids,
-                              ephemeral_values=eph_values)
+                              ephemeral_values=eph_values, wide=wide)
 
     def _byte_source_value(self, bag: Bag, src: Any) -> bytes | None:
         if isinstance(src, tuple):

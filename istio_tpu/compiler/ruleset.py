@@ -323,6 +323,44 @@ def _bank_guard(atoms: Sequence[int], guards: Mapping
     return None if best is None else best[1:]
 
 
+def _rule_guards(per_rule, eq_info: Mapping) -> tuple:
+    """(column, {intern id: [rule idx]}, [rule idx]): the id-equality
+    column that guards the most rules, the rules it guards by the ids
+    under which they can match, and the rules it does not guard. A
+    rule is guarded on a column where EVERY conjunction of its M-DNF
+    asserts `column == id` there (an EQ atom definitely true, a NEQ
+    atom definitely false): on a row whose column reads another id
+    the rule cannot match. What lets the host decide a row by its
+    host's ten blocks and not its namespace's six hundred
+    (RuleSetProgram.host_candidates). (None, {}, all rules) where no
+    column guards a rule."""
+    held: list[dict | None] = []
+    for mn in per_rule:
+        cols: dict[int, set] | None = None
+        for conj in (mn[0] if mn else ()):
+            asserts = dict(eq_info[a][:2] for a, kind in conj
+                           if a in eq_info and eq_info[a][2] == (kind == "n"))
+            if cols is None:
+                cols = {c: {cid} for c, cid in asserts.items()}
+            else:
+                cols = {c: ids | {asserts[c]} for c, ids in cols.items()
+                        if c in asserts}
+        held.append(cols)
+    guarded = collections.Counter(c for cols in held for c in cols or ())
+    if not guarded:
+        return None, {}, list(range(len(per_rule)))
+    col = min(guarded, key=lambda c: (-guarded[c], c))
+    by_id: dict[int, list] = {}
+    free = []
+    for ridx, cols in enumerate(held):
+        if cols and col in cols:
+            for cid in sorted(cols[col]):
+                by_id.setdefault(cid, []).append(ridx)
+        else:
+            free.append(ridx)
+    return col, by_id, free
+
+
 @dataclasses.dataclass
 class RuleSetProgram:
     """The compiled snapshot. `fn(batch)` → (matched, not_matched, err)
@@ -354,10 +392,22 @@ class RuleSetProgram:
     #      (compiler/roofline.py) derives per-step bytes/op counts from
     #      THESE shapes, never from hand constants
     geometry: dict = dataclasses.field(default_factory=dict)
+    # _rule_guards' (column, {id: rules}, unguarded rules)
+    guards: tuple = (None, {}, ())
 
     @property
     def n_rules(self) -> int:
         return len(self.rules)
+
+    def host_candidates(self, batch: AttributeBatch, row: int) -> list:
+        """The rules that can match row `row` of a host batch, in rule
+        order: those the guard column does not guard and those it
+        guards under the id the row reads there. Every rule where the
+        row lacks the column: a guard then errs and does not miss."""
+        col, by_id, free = self.guards
+        if col is None or not batch.present[row, col]:
+            return list(range(self.n_rules))
+        return sorted(free + by_id.get(int(batch.ids[row, col]), []))
 
     def __call__(self, batch: AttributeBatch) -> tuple[Any, Any, Any]:
         return self.fn(self.params, batch)
@@ -463,6 +513,11 @@ class SnapshotOracle:
             with self._lock:
                 self._progs.setdefault(ridx, prog)
         return prog
+
+    def evaluate(self, ridx: int, bag) -> bool:
+        """Rule `ridx`'s predicate on one request; raises where its
+        evaluation does."""
+        return bool(self._prog(ridx).evaluate(bag))
 
     def resolve(self, bag, request_ns: str
                 ) -> tuple[list[int], list[int], int]:
@@ -1067,7 +1122,8 @@ def compile_ruleset(rules: Sequence[Rule], finder: AttributeDescriptorFinder,
         attr_mask=attr_mask, attr_names=attr_names,
         rule_ns=rule_ns, ns_ids=ns_ids,
         atom_asts=list(atoms.asts), atom_tier=atom_tier,
-        per_rule_dnf=list(per_rule), geometry=geometry)
+        per_rule_dnf=list(per_rule), geometry=geometry,
+        guards=_rule_guards(per_rule, eq_info))
 
 
 def _collect_attr_names(e: Expression, finder: AttributeDescriptorFinder,

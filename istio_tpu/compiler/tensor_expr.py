@@ -434,6 +434,15 @@ def _compile_node(e: Expression, ctx: _Ctx) -> NodeFn:
     raise HostFallback(f"unsupported function on device: {name}")
 
 
+def _cap(max_len: int, data) -> int:
+    """The length at which a row of the byte plane `data` [B, W] may be
+    truncated: the layout's max_str_len on the planes every batch
+    carries (a tier below it only drops padding), the plane's own width
+    on the wide plane (layout.WIDE_STR_LEN), whose rows are kept whole
+    up to it. A Python int at trace time."""
+    return max(max_len, int(data.shape[1]))
+
+
 def _compile_cmp(f: FunctionCall, ctx: _Ctx) -> NodeFn:
     """Ordered comparison (expr LSS/LEQ/GTR/GEQ, reference func.go's
     ordered intrinsics) over the byte planes.
@@ -443,7 +452,8 @@ def _compile_cmp(f: FunctionCall, ctx: _Ctx) -> NodeFn:
     one lex_cmp. NaN operands arrive as present-but-EMPTY numeric rows
     and read False under every comparison (IEEE semantics, oracle
     parity). String rows at the byte-slot cap may be truncated, making
-    the comparison undecidable → err, routed to the host oracle."""
+    the comparison undecidable → err (_compile_byte_pred says what the
+    serving paths make of it)."""
     name = f.name
     ta = ctx.type_of(f.args[0])
     tb = ctx.type_of(f.args[1])
@@ -481,8 +491,9 @@ def _compile_cmp(f: FunctionCall, ctx: _Ctx) -> NodeFn:
             val = val & ~nan
         else:
             # either side possibly truncated → order undecidable
-            ee = ee | (a.ok & (a.lens >= max_len)) \
-                    | (b.ok & (b.lens >= max_len))
+            cap = _cap(max_len, a.data)
+            ee = ee | (a.ok & (a.lens >= cap)) \
+                    | (b.ok & (b.lens >= cap))
         val = val & ~ee
         return TVal(val, ~ee, ee)
     return fn
@@ -491,18 +502,31 @@ def _compile_cmp(f: FunctionCall, ctx: _Ctx) -> NodeFn:
 def _compile_byte_pred(f: FunctionCall, ctx: _Ctx) -> NodeFn:
     """Byte predicates with truncation safety.
 
-    Strings longer than max_str_len land truncated in the byte plane
-    (layout.py). Per predicate:
+    Strings of max_str_len bytes or more land truncated in the byte
+    plane (layout.py). Per predicate:
       * prefix checks (startsWith, `x*` globs, exact globs shorter
         than the cap) only read the head — always decidable;
       * suffix/tail checks (endsWith, `*x` globs, cap-length exact
-        globs) are undecidable on a possibly-truncated row → the row
-        is marked err, which the serving path routes to the host
-        oracle (dispatcher._overlay_fallback);
+        globs) are undecidable on a possibly-truncated row → the rule
+        errs on the row;
       * unanchored regex: a hit inside the stored prefix proves a hit
         in the full string, so only a MISS on a truncated row is
         undecidable; a `$`-anchored regex could falsely anchor at the
         truncation point, so every truncated row is undecidable.
+    What becomes of such an err. The fused Check path (the one every
+    deployment with a fused plan serves through) never shows a row a
+    plane that truncates it while a wider one is to be had: a row with
+    a subject at the cap is served by the wide program
+    (Dispatcher._split_by_length, FusedPlan's `step_wide`), where the
+    cap is that plane's width (_cap), and a row that saturates the wide
+    plane too and has a rule err is given the host oracle's verdict
+    (Dispatcher._decide_on_host, counted in
+    mixer_check_undecided_rows_total). The generic path
+    (Dispatcher._resolve), the fused report resolve, the in-step quota
+    program and a mesh keep upstream's meaning of an evaluation error
+    for it: the rule is skipped on the row and RESOLVE_ERRORS counts
+    it, so there a deny rule that reads a subject past the cap does
+    not fire.
     A pattern longer than the cap can't be represented on device at
     all → HostFallback at compile time.
     """
@@ -565,7 +589,7 @@ def _compile_byte_pred(f: FunctionCall, ctx: _Ctx) -> NodeFn:
         ee = s.err | ~s.ok
         val = op(s.data, s.lens) & ~ee
         if trunc != "safe":
-            maybe_truncated = s.ok & (s.lens >= max_len)
+            maybe_truncated = s.ok & (s.lens >= _cap(max_len, s.data))
             undecidable = maybe_truncated if trunc == "all" \
                 else (maybe_truncated & ~val)
             ee = ee | undecidable
@@ -708,7 +732,7 @@ def compile_dfa_group(subject_ast: Expression, patterns: list[str],
                 m = jnp.concatenate([m, others(s)], axis=1)
         ee = (s.err | ~s.ok)[:, None] & jnp.ones_like(m)
         val = m & ~ee
-        maybe = (s.ok & (s.lens >= max_len))[:, None]
+        maybe = (s.ok & (s.lens >= _cap(max_len, s.data)))[:, None]
         undecidable = jnp.where(trunc_all[None, :], maybe, maybe & ~val)
         ee = ee | undecidable
         val = val & ~ee
@@ -770,9 +794,10 @@ def _compile_dyn_byte_pred(f: FunctionCall, ctx: _Ctx) -> NodeFn:
         s, p = fsub(batch), fpat(batch)
         ee = (s.err | ~s.ok) | (p.err | ~p.ok)
         val = op(s.data, s.lens, p.data, p.lens)
-        undecidable = p.ok & (p.lens >= max_len)
+        cap = _cap(max_len, s.data)
+        undecidable = p.ok & (p.lens >= cap)
         if trunc_subject == "all":
-            undecidable = undecidable | (s.ok & (s.lens >= max_len))
+            undecidable = undecidable | (s.ok & (s.lens >= cap))
         ee = ee | undecidable
         val = val & ~ee
         return TVal(val, ~ee, ee)
@@ -806,8 +831,11 @@ def _compile_bytes(e: Expression, ctx: _Ctx) -> ByteFn:
             # tier to >= the longest compiled constant (note_byte_const
             # above): row[:w] only ever drops zero padding, and `n`
             # keeps the TRUE length for the tiebreaks.
+            # The wide plane (layout.WIDE_STR_LEN) pads the row out.
             w = batch.str_bytes.shape[2]
-            return BVal(jnp.broadcast_to(jnp.asarray(row[:w]), (b, w)),
+            at_w = row[:w] if w <= row.shape[0] else np.concatenate(
+                [row, np.zeros(w - row.shape[0], np.uint8)])
+            return BVal(jnp.broadcast_to(jnp.asarray(at_w), (b, w)),
                         jnp.full(b, n, jnp.int32),
                         jnp.ones(b, bool), jnp.zeros(b, bool))
         return fn

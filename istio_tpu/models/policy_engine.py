@@ -207,6 +207,7 @@ class PolicyEngine:
         # (rbac lowering) err routinely on requests missing instance
         # attrs, which maps to adapter-level INTERNAL, not a predicate
         # resolve error (host parity: RESOLVE_ERRORS vs DISPATCH_ERRORS)
+        self.count_rules = count_rules
         if count_rules is None or count_rules >= R:
             err_rule_mask = None
         else:
@@ -474,7 +475,10 @@ class PolicyEngine:
                         # prefix hit is definitive; anything else on a
                         # truncated value is undecidable → err the rule's
                         # row, suppress the deny (fail-open, counted)
-                        trunc = (s_lens >= max_len)[:, None]
+                        # (the wide plane's rows are whole up to its
+                        # width: tensor_expr._cap)
+                        trunc = (s_lens >= max(
+                            max_len, s_data.shape[1]))[:, None]
                         member = member.at[:, bank["pos"]].set(
                             jnp.where(trunc, dec, hit))
                         und = und.at[:, bank["pos"]].set(trunc & ~dec)

@@ -280,23 +280,36 @@ static int32_t fnv1a31(const Key& k) {
 //   map_present uint8 [n, max(n_maps,1)]
 //   str_bytes  uint8 [n, max(n_byte,1), max_str_len]
 //   str_lens   int32 [n, max(n_byte,1)]
+// The wide rows (layout.WideRows; wide_bytes null: none kept). A row
+// with a string of max_str_len bytes or more claims the next row of
+//   wide_bytes uint8 [n, max(n_byte,1), wide_len]   NOT zeroed by the
+//   wide_lens  int32 [n, max(n_byte,1)]             caller: a claim
+//   wide_row   int32 [n]   the claimed row, -1      zeroes its row
+// and holds every byte slot of the request there, the long ones whole
+// up to wide_len; *n_wide counts the claims.
 // Returns 0 on success, <0 on parse error (row index encoded).
 int32_t shim_tensorize(void* h, const uint8_t* const* msgs,
                        const int64_t* msg_lens, int32_t n,
                        int32_t* ids, int32_t* hash_ids,
                        uint8_t* present,
                        uint8_t* map_present, uint8_t* str_bytes,
-                       int32_t* str_lens) {
+                       int32_t* str_lens, uint8_t* wide_bytes,
+                       int32_t* wide_lens, int32_t* wide_row,
+                       int32_t wide_len, int32_t* n_wide) {
   auto* sh = static_cast<Shim*>(h);
   const Layout& L = sh->layout;
   const size_t ncol = L.n_columns;
   const size_t nmap = L.n_maps ? L.n_maps : 1;
   const size_t nbyte = L.n_byte ? L.n_byte : 1;
   const size_t slen = L.max_str_len;
+  const size_t wlen = static_cast<size_t>(wide_len);
+  int32_t claimed = 0;
+  if (n_wide) *n_wide = 0;
 
   CompressedAttributes msg;
   for (int32_t i = 0; i < n; i++) {
     msg.Clear();
+    if (wide_bytes) wide_row[i] = -1;
     if (!msg.ParseFromArray(msgs[i], static_cast<int>(msg_lens[i]))) {
       sh->error = "parse failure at record " + std::to_string(i);
       return -(i + 1);
@@ -319,6 +332,19 @@ int32_t shim_tensorize(void* h, const uint8_t* const* msgs,
       size_t m = value.size() < slen ? value.size() : slen;
       memcpy(row_sb + bcol * slen, value.data(), m);
       row_sl[bcol] = static_cast<int32_t>(m);
+      if (!wide_bytes || value.size() < slen) return;
+      if (wide_row[i] < 0) {
+        wide_row[i] = claimed++;
+        memset(wide_bytes + wide_row[i] * nbyte * wlen, 0, nbyte * wlen);
+        memset(wide_lens + wide_row[i] * nbyte, 0,
+               nbyte * sizeof(int32_t));
+      }
+      uint8_t* w = wide_bytes + (wide_row[i] * nbyte + bcol) * wlen;
+      int32_t* wl = wide_lens + wide_row[i] * nbyte + bcol;
+      if (*wl) memset(w, 0, wlen);          // the slot set twice
+      size_t mw = value.size() < wlen ? value.size() : wlen;
+      memcpy(w, value.data(), mw);
+      *wl = static_cast<int32_t>(mw);
     };
     // 8-byte big-endian order key (layout.order_key_bytes parity)
     auto set_key8 = [&](int32_t bcol, uint64_t bits) {
@@ -455,7 +481,19 @@ int32_t shim_tensorize(void* h, const uint8_t* const* msgs,
         if (bit != L.byte_pair.end()) set_bytes_slot(bit->second, *value);
       }
     }
+    if (wide_bytes && wide_row[i] >= 0) {
+      // the row's other slots, as the narrow plane holds them
+      for (size_t bcol = 0; bcol < nbyte; bcol++) {
+        if (static_cast<size_t>(row_sl[bcol]) >= slen) continue;
+        uint8_t* w = wide_bytes + (wide_row[i] * nbyte + bcol) * wlen;
+        int32_t* wl = wide_lens + wide_row[i] * nbyte + bcol;
+        if (*wl) memset(w, 0, wlen);        // set long, then short
+        memcpy(w, row_sb + bcol * slen, row_sl[bcol]);
+        *wl = row_sl[bcol];
+      }
+    }
   }
+  if (n_wide) *n_wide = claimed;
   return 0;
 }
 

@@ -10,6 +10,7 @@ entries are mirrored back into the Python InternTable after every batch
 from __future__ import annotations
 
 import ctypes
+import dataclasses
 import datetime
 import struct
 from typing import Any, Sequence
@@ -19,7 +20,7 @@ import numpy as np
 from istio_tpu.attribute.global_dict import GLOBAL_WORD_LIST
 from istio_tpu.attribute.types import ValueType
 from istio_tpu.compiler.layout import (AttributeBatch, BatchLayout,
-                                       InternTable, _normalize,
+                                       InternTable, WideRows, _normalize,
                                        canonical_bytes)
 from istio_tpu.native.build import ensure_built
 
@@ -154,7 +155,9 @@ class NativeTensorizer:
             ctypes.POINTER(ctypes.c_char_p),
             ctypes.POINTER(ctypes.c_int64), ctypes.c_int32,
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p]
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+            ctypes.c_int32, ctypes.POINTER(ctypes.c_int32)]
         self._lib = lib
         if layout.extern_slots:
             raise RuntimeError(
@@ -204,7 +207,7 @@ class NativeTensorizer:
         nmap = max(lay.n_maps, 1)
         nbyte = max(lay.n_byte_slots, 1)
         alloc = self._aligned_zeros if aligned else np.zeros
-        return {
+        bufs = {
             "ids": alloc((n, lay.n_columns), np.int32),
             "hash_ids": alloc((n, lay.n_columns), np.int32),
             "present_u8": alloc((n, max(lay.n_columns, 0)), np.uint8),
@@ -212,6 +215,17 @@ class NativeTensorizer:
             "str_bytes": alloc((n, nbyte, lay.max_str_len), np.uint8),
             "str_lens": alloc((n, nbyte), np.int32),
         }
+        if lay.wide_str_len:
+            # the wide rows' planes (layout.WideRows). Never zeroed
+            # here: the shim zeroes a row as it claims it, so the pages
+            # of rows no batch has claimed are never touched, and a
+            # deployment whose strings all fit holds them as address
+            # space alone
+            bufs["wide"] = WideRows(
+                row=np.zeros(n, np.int32),
+                data=np.zeros((n, nbyte, lay.wide_str_len), np.uint8),
+                lens=np.zeros((n, nbyte), np.int32), count=0)
+        return bufs
 
     def _buffers_for(self, n: int) -> dict:
         """Staging-ring slot for batch shape `n` (zeroed, ready for
@@ -249,8 +263,9 @@ class NativeTensorizer:
             ring["slots"].append(slot)
         else:
             slot = ring["slots"][idx]
-            for arr in slot.values():
-                arr[...] = 0
+            for name, arr in slot.items():
+                if name != "wide":
+                    arr[...] = 0
         self._staged_decodes += 1
         return slot
 
@@ -274,6 +289,8 @@ class NativeTensorizer:
 
         bufs = (ctypes.c_char_p * n)(*records)
         lens = (ctypes.c_int64 * n)(*[len(r) for r in records])
+        wide = buf_set.get("wide")
+        n_wide = ctypes.c_int32(0)
         rc = self._lib.shim_tensorize(
             self._h, bufs, lens, n,
             ids.ctypes.data_as(ctypes.c_void_p),
@@ -281,7 +298,11 @@ class NativeTensorizer:
             present_u8.ctypes.data_as(ctypes.c_void_p),
             map_present_u8.ctypes.data_as(ctypes.c_void_p),
             str_bytes.ctypes.data_as(ctypes.c_void_p),
-            str_lens.ctypes.data_as(ctypes.c_void_p))
+            str_lens.ctypes.data_as(ctypes.c_void_p),
+            *([None] * 3 if wide is None else
+              [a.ctypes.data_as(ctypes.c_void_p)
+               for a in (wide.data, wide.lens, wide.row)]),
+            lay.wide_str_len, ctypes.byref(n_wide))
         if rc != 0:
             raise ValueError(self._lib.shim_error(self._h).decode())
         self._sync_interns()
@@ -305,7 +326,9 @@ class NativeTensorizer:
                               map_present=map_present_u8.view(bool),
                               str_bytes=str_bytes, str_lens=str_lens,
                               hash_ids=hash_ids,
-                              ephemeral_values=ephemeral)
+                              ephemeral_values=ephemeral,
+                              wide=wide and dataclasses.replace(
+                                  wide, count=n_wide.value))
 
     def _sync_interns(self) -> None:
         """Extend the shim→python id remap with newly observed values.

@@ -253,7 +253,10 @@ class Dispatcher:
                                            for w in wires):
             with monitor.span("tensorize.decode"):
                 batch = plan.native.tensorize_wire(wires)
-            if self.overlap_h2d:
+            # a batch with long rows is staged a part at a time
+            # (_split_by_length), or, on the paths that do not split,
+            # transferred by the launch
+            if self.overlap_h2d and not self._has_long_rows(plan, batch):
                 # h2d begins NOW — the transfer runs while
                 # _ns_ids_from_batch does its host-side decode
                 with monitor.span("tensorize.stage_put"):
@@ -264,6 +267,146 @@ class Dispatcher:
             batch = self.snapshot.tensorizer.tensorize(bags)
             ns_ids = self._request_ns_ids(bags)
         return batch, ns_ids
+
+    @staticmethod
+    def _has_long_rows(plan, batch) -> bool:
+        """Whether the length split has anything to cut: the plan has a
+        wide program and the tensorizer kept a long row."""
+        wide = batch.wide
+        return bool(plan.wide_width) and wide is not None \
+            and wide.count > 0
+
+    def _split_by_length(self, plan, batch, ns_ids: np.ndarray,
+                         n_real: int) -> list | None:
+        """The length split: a served batch with a row whose subject
+        reaches the narrow byte plane's cap is cut into parts, →
+        [(rows, part batch, part ns_ids)], `rows` the part's rows as
+        indices into `batch`. Rows under the cap keep the programs and
+        shapes every batch had (one part, padded to its own bucket at
+        its own byte tier); the long rows, sorted by their longest
+        subject so a launch scans as far as its own rows ask, go
+        FusedPlan.wide_bucket a part onto the wide plane, their bytes
+        from the tensorizer's WideRows. None, at the cost of one
+        comparison, for a batch without such a row: the path every
+        batch took before."""
+        if not self._has_long_rows(plan, batch):
+            return None
+        from istio_tpu.runtime.batcher import bucket_size
+
+        with monitor.span("tensorize.split", on=self.observe,
+                          batch=n_real):
+            wide = batch.wide
+            at = wide.row[:n_real]
+            long_rows = np.flatnonzero(at >= 0)
+            longest = wide.lens[at[long_rows]].max(axis=1)
+            long_rows = long_rows[np.argsort(longest, kind="stable")]
+            short_rows = np.flatnonzero(at < 0)
+            buckets = self.buckets
+
+            def take(plane, rows, size):
+                out = np.zeros((size,) + plane.shape[1:], plane.dtype)
+                out[:len(rows)] = plane[rows]
+                return out
+
+            def part(rows, size, str_bytes, str_lens):
+                return rows, dataclasses.replace(
+                    batch, wide=None, str_bytes=str_bytes,
+                    str_lens=str_lens,
+                    **{name: take(np.asarray(getattr(batch, name)),  # hotpath: sync-ok host planes
+                                  rows, size)
+                       for name in ("ids", "present", "map_present",
+                                    "hash_ids")}), take(ns_ids, rows, size)
+
+            parts = []
+            if len(short_rows):
+                size = bucket_size(len(short_rows), buckets) \
+                    if buckets else len(short_rows)
+                lens = take(batch.str_lens, short_rows, size)
+                # cut at the part's own byte tier (narrow_batch then
+                # finds nothing left to slice)
+                width = plan._serve_width(dataclasses.replace(
+                    batch, str_lens=lens))
+                parts.append(part(
+                    short_rows, size,
+                    take(batch.str_bytes[:, :, :width], short_rows, size),
+                    lens))
+            step = plan.wide_bucket(buckets) if buckets else len(long_rows)
+            for lo in range(0, len(long_rows), step):
+                rows = long_rows[lo:lo + step]
+                size = step if buckets else len(rows)
+                parts.append(part(rows, size,
+                                  take(wide.data, at[rows], size),
+                                  take(wide.lens, at[rows], size)))
+            if self.overlap_h2d:
+                # every part's byte plane in ONE put: a put hands the
+                # interpreter lock away, and taking it back from the
+                # other pump costs more than the copy (PERF.md §6, PR 35)
+                import jax
+                with monitor.span("tensorize.stage_put", on=self.observe):
+                    staged = jax.device_put(
+                        [cut.str_bytes for _, cut, _ in parts])
+                parts = [(rows, dataclasses.replace(cut, str_bytes=put), ns)
+                         for (rows, cut, ns), put in zip(parts, staged)]
+            return parts
+
+    @staticmethod
+    def _join_parts(plan, batch, parts: list, outs: list, n_real: int
+                    ) -> tuple[np.ndarray, np.ndarray]:
+        """The parts' packed arrays → (one packed array [., n_real] in
+        the rows' own order, the rows left undecided). Everything
+        after it (fold, respond, tags, exemplars, rule telemetry's host
+        half) reads that array as it reads an unsplit batch's. Row 4
+        holds the batch's evaluation errors again: a narrow part's
+        count, a wide part's per-row counts (_base_packer), but for
+        the UNDECIDED rows: those that saturate the wide plane too and
+        had a visible rule err. Whether that err is truncation's or an
+        evaluation error the host finds out, and counts
+        (_decide_on_host)."""
+        packed = np.empty((outs[0].shape[0], n_real), np.int32)
+        wide = batch.wide
+        n_err = 0
+        undecided = []
+        for (rows, cut, _), out in zip(parts, outs):
+            k = len(rows)
+            packed[:, rows] = out[:, :k]
+            width = int(cut.str_bytes.shape[2])
+            if width != plan.wide_width:
+                n_err += int(out[4, 0])
+                continue
+            flag = out[4, :k]
+            left = (flag < 0) & \
+                (wide.lens[wide.row[rows]] >= width).any(axis=1)
+            n_err += int(-(flag[(flag < 0) & ~left] + 1).sum())
+            undecided.append(rows[left])
+        packed[4] = n_err
+        return packed, np.concatenate(undecided)
+
+    def _decide_on_host(self, plan, batch, b: int, bag: Bag,
+                        ns_id: int) -> tuple[CheckResponse, int]:
+        """→ (the host oracle's response for row `b` of `batch`, its
+        evaluation errors). Only the rules that can match the row are
+        evaluated (RuleSetProgram.host_candidates: its host's blocks,
+        not its namespace's rules), each by the snapshot oracle's own
+        program; the response is the generic path's (_check_one: every
+        action on the host), so it carries no quota activity bits, as
+        an oracle-bridged response carries none."""
+        rs = self.snapshot.ruleset
+        n_cfg = len(self.snapshot.rules)
+        seen = (rs.ns_ids[""], ns_id)
+        oracle = self._oracle()
+        active, errs = [], 0
+        for ridx in rs.host_candidates(batch, b):
+            if ridx >= n_cfg or rs.rule_ns[ridx] not in seen:
+                continue
+            try:
+                if oracle.evaluate(ridx, bag):
+                    active.append(ridx)
+            except Exception:
+                errs += 1
+        resp = self._check_one(bag, active, (), attribute=True,
+                               referenced=plan.pred_attrs_for_ns(ns_id))
+        self._apply_grants([bag], [resp])
+        return resp, errs
 
     @staticmethod
     def _stage_h2d(plan, batch):
@@ -469,6 +612,7 @@ class Dispatcher:
         bridged = False
         with (monitor.resolve_timer() if observe
               else contextlib.nullcontext()):
+            parts = undecided = None
             if pre_tensorized is not None:
                 batch, ns_ids = pre_tensorized
             else:
@@ -477,6 +621,9 @@ class Dispatcher:
                 with monitor.stage("tensorize", on=observe,
                                    batch=len(bags)):
                     batch, ns_ids = self._tensorize_for_device(bags)
+                    if instep is None:
+                        parts = self._split_by_length(plan, batch,
+                                                      ns_ids, n_real)
             # swap-warm oracle bridge: while a background warm is
             # still compiling this shape's program (a config swap
             # deferred the shapes live traffic was NOT serving), the
@@ -487,8 +634,10 @@ class Dispatcher:
             # oracle equivalent and compiles through). Bridged
             # responses carry no device activity bits, so a quota
             # riding one falls back to the host adapter path.
-            if observe and instep is None \
-                    and plan.swap_warm_pending(batch):
+            if observe and instep is None and any(
+                    plan.swap_warm_pending(cut) for cut in
+                    ([batch] if parts is None else
+                     [cut for _, cut, _ in parts])):
                 bridged = True
             else:
                 # ONE device→host pull for the whole verdict: every
@@ -515,6 +664,12 @@ class Dispatcher:
                         # everything the overlay decode reads sits
                         # before them
                         on_pull(packed[-2], packed[-1] != 0)
+                    elif parts is not None:
+                        packed, undecided = self._join_parts(
+                            plan, batch, parts, plan.packed_check_parts(
+                                [(cut, cut_ns, len(rows))
+                                 for rows, cut, cut_ns in parts],
+                                observe=observe), n_real)
                     else:
                         packed = plan.packed_check(batch, ns_ids,
                                                    observe=observe,
@@ -524,11 +679,12 @@ class Dispatcher:
         with monitor.span("overlay", on=grouped, batch=n_real):
             return self._fold_respond(snap, plan, packed, batch,
                                       bags[:n_real], ns_ids[:n_real],
-                                      observe, deadline)
+                                      observe, deadline, undecided)
 
     def _fold_respond(self, snap, plan, packed: np.ndarray, batch,
                       bags: Sequence[Bag], ns_ids: np.ndarray,
-                      observe: bool, deadline: float | None
+                      observe: bool, deadline: float | None,
+                      undecided: np.ndarray | None = None
                       ) -> ClassedResponses:
         """The host half of the fused check after the pull: stage
         `fold` (packed-plane decode: overlay bits, host-action
@@ -538,7 +694,11 @@ class Dispatcher:
         (the batcher appends PadBags at the tail and zips results
         against real requests), and at small arrival rates a
         512-bucket batch is mostly padding: per-row python here is
-        the serving CPU budget."""
+        the serving CPU budget. `undecided`: the rows a length split
+        left to the host (_join_parts); `fold` gives them the oracle's
+        verdict (span fold.undecided), in `packed` too, so what reads
+        the pulled planes (decided-by counts, exemplars, the canary
+        tap) reads the verdict that was served."""
         from istio_tpu.runtime.fused import (
             class_int_rows, dedup_bit_rows, unpack_word_rows)
         from istio_tpu.utils import tracing
@@ -578,6 +738,33 @@ class Dispatcher:
                 # fused report path).
                 active_sub, col_pos = self._overlay_active(
                     packed, bags, ns_ids, observe=observe)
+                held: dict[int, CheckResponse] = {}
+                if undecided is not None and len(undecided):
+                    with monitor.span("fold.undecided", on=observe,
+                                      batch=len(undecided)):
+                        host_errs = 0
+                        for b in undecided.tolist():
+                            held[b], errs = self._decide_on_host(
+                                plan, batch, b, bags[b], int(ns_ids[b]))
+                            host_errs += errs
+                            status[b] = held[b].status_code
+                            dur[b] = held[b].valid_duration_s
+                            uses[b] = held[b].valid_use_count
+                            deny_rule[b] = held[b].deny_rule \
+                                if held[b].status_code != OK \
+                                else np.iinfo(np.int32).max
+                        # the host ran their every action: none is
+                        # left to overlay
+                        active_sub[undecided] = False
+                    if observe:
+                        if host_errs:
+                            monitor.RESOLVE_ERRORS.inc(host_errs)
+                        wide = batch.wide
+                        monitor.note_undecided_rows(
+                            list(snap.ruleset.layout.byte_slots),
+                            np.argmax(wide.lens[wide.row[undecided]]
+                                      >= wide.data.shape[2],
+                                      axis=1).tolist())
                 # hotpath: sync-ok x2 — tensorizer planes are host numpy
                 present_np = np.asarray(   # hotpath: sync-ok
                     batch.present)[:n_real]
@@ -733,6 +920,8 @@ class Dispatcher:
                     with the row's host actions, lowest rule index
                     first. The one copy of the merge rules: a verdict
                     class takes its response from its first row."""
+                    if b in held:
+                        return held[b]
                     resp = CheckResponse()
                     resp.valid_duration_s = min(resp.valid_duration_s,
                                                 float(dur[b]))
@@ -828,9 +1017,13 @@ class Dispatcher:
                         dedup_bit_rows((active_sub,))[1]]
                     if grant_of is not None:
                         columns.append(grant_of[1])
-                    if host_rows.any():
+                    own = host_rows
+                    if held:    # a host-decided row: its own object
+                        own = host_rows.copy()
+                        own[undecided] = True
+                    if own.any():
                         columns.append(np.where(
-                            host_rows, np.arange(1, n_real + 1), 0))
+                            own, np.arange(1, n_real + 1), 0))
                     first, class_of = class_int_rows(columns)
                 classes = [respond_row(b) for b in first.tolist()]
                 out = ClassedResponses(
@@ -931,14 +1124,19 @@ class Dispatcher:
         return cached
 
     def _check_one(self, bag: Bag, rule_idxs: list[int],
-                   visible: list[int]) -> CheckResponse:
+                   visible: list[int], referenced=(),
+                   attribute: bool = False) -> CheckResponse:
+        """`referenced`: the visible rules' attribute uses where the
+        caller holds them already (FusedPlan.pred_attrs_for_ns).
+        `attribute`: name the rule whose action gave the response its
+        status in `deny_rule`, as the fused path does."""
         snap = self.snapshot
         resp = CheckResponse()
         # ReferencedAttributes: every namespace-visible rule's predicate
         # was EVALUATED for this request (protoBag.go:117 tracking →
         # compile-time bitmaps, SURVEY.md §2.2); matched rules add their
         # instances' attribute uses below.
-        referenced: set = set()
+        referenced = set(referenced)
         for ridx in visible:
             referenced |= snap.ruleset.attr_names[ridx]
         for ridx in rule_idxs:
@@ -952,6 +1150,9 @@ class Dispatcher:
                     referenced |= ib.referenced_attrs
                     result = self._safe_check(handler, template, ib, bag)
                     self._combine(resp, result)
+                    if attribute and resp.deny_rule < 0 \
+                            and resp.status_code != OK:
+                        resp.deny_rule = int(ridx)
         resp.referenced = tuple(sorted(referenced, key=str))
         return resp
 

@@ -49,6 +49,14 @@ _FUSABLE_LIST_TYPES = ("STRINGS", "REGEX", "IP_ADDRESSES")
 STR_TIER_MIN = 32
 
 
+# The bucket of the wide program (FusedPlan.wide_bucket): rows with a
+# subject at the narrow cap are served this many a launch, as many
+# launches as a batch's long rows need, through ONE more step shape a
+# snapshot. Sorted by their longest subject first, so a launch's scan
+# runs as long as its own rows ask.
+WIDE_BUCKET = 256
+
+
 _BY = {name: i for i, name in enumerate(monitor.CHECK_DECIDED_BY)}
 
 
@@ -230,6 +238,9 @@ class FusedPlan:
     mesh: Any = None
     # jit of _base_step: THE program of a served Check batch off a mesh
     _step: Any = None
+    # jit of _base_step(wide=True), `step_wide`: the program of the
+    # rows a length split sends to the wide byte plane
+    _step_wide: Any = None
     _packer: Any = None
     # compiled REPORT instance-field programs (runtime/report_lower.py)
     # — None when no report instance lowered; the dispatcher then keeps
@@ -293,6 +304,20 @@ class FusedPlan:
     def n_overlay_words(self) -> int:
         return (len(self.overlay_cols) + 31) // 32
 
+    @property
+    def wide_width(self) -> int:
+        """Width of the byte plane `step_wide` serves long rows at; 0
+        where the snapshot has no such program (no byte slot to
+        truncate, or a mesh: its step is shard_engine_check's jit)."""
+        return 0 if self.mesh is not None \
+            else self.engine.ruleset.layout.wide_str_len
+
+    @staticmethod
+    def wide_bucket(buckets) -> int:
+        """Rows a launch of the wide program: WIDE_BUCKET, or the
+        largest serving bucket where that is smaller."""
+        return min(WIDE_BUCKET, max(buckets))
+
     def packed_check(self, batch, ns_ids, observe: bool = True,
                      n_real: int | None = None) -> np.ndarray:
         """The engine step + device-side packing into ONE int32 array
@@ -320,14 +345,44 @@ class FusedPlan:
         the program. Under a mesh the engine step is
         shard_engine_check's own jit, so the same closures are
         launched apart behind it (_launch_apart)."""
-        batch = self.narrow_batch(batch)   # latency-tier byte plane
-        ns_arr = np.asarray(ns_ids)        # hotpath: sync-ok (host ids)
-        b = ns_arr.shape[0]
+        return self.packed_check_parts([(batch, ns_ids, n_real)],
+                                       observe)[0]
+
+    def packed_check_parts(self, parts, observe: bool = True) -> list:
+        """packed_check for a served batch in one part or, split by
+        subject length (Dispatcher._split_by_length), several: `parts`
+        holds (batch, ns_ids, n_real) each, a part on the wide byte
+        plane served by `step_wide`. Every part is launched before any
+        is pulled, under ONE stage `h2d` (span dispatch.step) and ONE
+        stage `device_step`, so the stages tile a pump's cycle as they
+        do for a batch of one program; → the parts' packed arrays, in
+        order. A wide part's row 4 is per row (_base_packer)."""
+        staged = []
+        by_width: dict = {}     # byte-plane width → rows served at it
+        for batch, ns_ids, n_real in parts:
+            batch = self.narrow_batch(batch)   # latency-tier byte plane
+            ns_arr = np.asarray(ns_ids)    # hotpath: sync-ok (host ids)
+            b = ns_arr.shape[0]
+            if observe:
+                w = int(batch.str_bytes.shape[2])
+                self._tier_served[w] = self._tier_served.get(w, 0) + 1
+                key = (int(batch.ids.shape[0]), w)
+                self._shape_served[key] = \
+                    self._shape_served.get(key, 0) + 1
+                by_width[w] = by_width.get(w, 0) + \
+                    (b if n_real is None else n_real)
+            # rows the rule telemetry counts. `observe=False` (prewarm
+            # dummy batches — a compile would dwarf every real
+            # observation — and the fused report fallback, which is
+            # REPORT traffic) runs the same executable with none:
+            # prewarm compiles exactly what is served, and counts
+            # nothing. Only check trips feed the Check() decomposition
+            # either.
+            counted = np.int32((b if n_real is None else n_real)
+                               if observe else 0)
+            staged.append((batch, ns_arr, counted))
         if observe:
-            w = int(batch.str_bytes.shape[2])
-            self._tier_served[w] = self._tier_served.get(w, 0) + 1
-            key = (int(batch.ids.shape[0]), w)
-            self._shape_served[key] = self._shape_served.get(key, 0) + 1
+            monitor.note_rows_by_width(by_width)
             # fault-injection seam at the device boundary (chaos suite
             # + scripts/chaos_smoke.py): an injected exception here
             # unwinds exactly like a real device-step failure. Gated
@@ -335,33 +390,28 @@ class FusedPlan:
             # fallback never trip the breaker.
             from istio_tpu.runtime.resilience import CHAOS
             CHAOS.device_step()
-        # rows the rule telemetry counts. `observe=False` (prewarm
-        # dummy batches — a compile would dwarf every real
-        # observation — and the fused report fallback, which is
-        # REPORT traffic) runs the same executable with none: prewarm
-        # compiles exactly what is served, and counts nothing. Only
-        # check trips feed the Check() decomposition either.
-        counted = np.int32((b if n_real is None else n_real)
-                           if observe else 0)
         # stage `h2d` = the async launch with the implicit transfer
-        # of every jit argument; stage `device_step` = the one
-        # blocking pull (waits the program out, then D2H).
+        # of every jit argument; stage `device_step` = the blocking
+        # pull (waits the programs out, then D2H).
         with monitor.stage("h2d", on=observe):
             if self.mesh is not None:
-                dev = self._launch_apart(batch, ns_arr, counted, observe)
+                devs = [self._launch_apart(batch, ns_arr, counted, observe)
+                        for batch, ns_arr, counted in staged]
             else:
                 with monitor.span("dispatch.step", on=observe):
-                    dev = self._launch_step(batch, ns_arr, counted)
+                    devs = [self._launch_step(batch, ns_arr, counted)
+                            for batch, ns_arr, counted in staged]
                 if observe:
-                    monitor.note_device_programs("check", 1)
+                    monitor.note_device_programs("check", len(devs))
         with monitor.stage("device_step", on=observe):
-            # the single host<->device sync — hotpath: sync-ok
-            out = np.asarray(dev)              # hotpath: sync-ok
-            # this (bucket, width) shape's programs are compiled now —
-            # the swap-warm oracle bridge stops routing it away
-            self._warmed_shapes.add((int(batch.ids.shape[0]),
-                                     int(batch.str_bytes.shape[2])))
-        return out
+            # the host<->device sync, one a program — hotpath: sync-ok
+            outs = [np.asarray(dev) for dev in devs]   # hotpath: sync-ok
+            # these (bucket, width) shapes' programs are compiled now —
+            # the swap-warm oracle bridge stops routing them away
+            for batch, _, _ in staged:
+                self._warmed_shapes.add((int(batch.ids.shape[0]),
+                                         int(batch.str_bytes.shape[2])))
+        return outs
 
     def _launch_step(self, batch, ns_arr, counted):
         """Launch the one program of a Check batch (async) and return
@@ -371,9 +421,15 @@ class FusedPlan:
         stay exact under concurrent pumps and a drain's swap."""
         import jax
 
-        if self._step is None:
-            self._step = jax.jit(self._base_step())
-        step, eng, tele = self._step, self.engine, self.telemetry
+        if int(batch.str_bytes.shape[2]) == self.wide_width:
+            if self._step_wide is None:
+                self._step_wide = jax.jit(self._base_step(wide=True))
+            step = self._step_wide
+        else:
+            if self._step is None:
+                self._step = jax.jit(self._base_step())
+            step = self._step
+        eng, tele = self.engine, self.telemetry
         if tele is None:
             return step(eng.params, batch, ns_arr, eng.quota_counts,
                         counted)
@@ -414,7 +470,7 @@ class FusedPlan:
             monitor.note_device_programs("check", programs)
         return dev
 
-    def _base_step(self):
+    def _base_step(self, wide: bool = False):
         """The step(params, batch, req_ns, quota_counts, n_real[,
         acc_hit, acc_deny, acc_err]) closure _launch_step jits: the
         engine's raw step, RuleTelemetry's pure delta folded into the
@@ -430,12 +486,20 @@ class FusedPlan:
         the three closures (match … combine, rulestats, pack) ride
         inside it. The served engine carries no device quota
         (build_fused_plan passes quotas=()), so the counts the raw
-        step returns are the dummy it was handed."""
+        step returns are the dummy it was handed.
+
+        `wide`: the same trace over the wide byte plane, NAMED
+        `step_wide` (the profiler's `jit_step_wide`: the readers that
+        look for `jit_step` do not count it) with the same scopes
+        inside, its packer's row 4 per row. The byte predicates take
+        their truncation cap from the plane they are traced on
+        (tensor_expr._cap) and their scans stop at the launch's longest
+        subject (a while_loop on max(lens)), so nothing else differs."""
         import jax
         import jax.numpy as jnp
 
         raw_step = self.engine.raw_step
-        pack = self._base_packer()
+        pack = self._base_packer(row_errs=wide)
         delta = None if self.telemetry is None else self.telemetry.delta
 
         def step(params, batch, req_ns, quota_counts, n_real, *accs):
@@ -450,11 +514,22 @@ class FusedPlan:
             with jax.named_scope("rulestats"):
                 return packed, tuple(a + d for a, d in zip(accs, deltas))
 
+        if wide:
+            step.__name__ = step.__qualname__ = "step_wide"
         return step
 
-    def _base_packer(self):
+    def _base_packer(self, row_errs: bool = False):
         """The pack(verdict, req_ns) closure shared by packed_check and
-        packed_report (which appends report-field planes)."""
+        packed_report (which appends report-field planes).
+
+        `row_errs` (the wide program): row 4 says of each row what the
+        broadcast count says of the batch. 0 where no rule visible to
+        the row erred, else -(n + 1), n the row's share of err_count
+        (config rules; an rbac pseudo-rule's err makes the row
+        negative and counts nothing). The host reads it beside what
+        it knows already, which rows saturate the plane: such a row
+        with an err is undecided, any other err is an evaluation
+        error as upstream means it (Dispatcher._join_parts)."""
         import jax
         import jax.numpy as jnp
         from jax import lax
@@ -474,6 +549,15 @@ class FusedPlan:
         n_cols = rs.layout.n_columns
         n_maps_used = self.pred_map_mask.shape[1]
         dims = (((1,), (0,)), ((), ()))
+        n_counted = self.engine.count_rules
+
+        def errs_by_row(verdict, req_ns):
+            seen = verdict.err & ((rule_ns[None, :] == default_ns) |
+                                  (rule_ns[None, :] == req_ns[:, None]))
+            counted = seen if n_counted is None else \
+                seen & (jnp.arange(seen.shape[1]) < n_counted)[None, :]
+            n = jnp.sum(counted.astype(jnp.int32), axis=1)
+            return jnp.where(jnp.any(seen, axis=1), -(n + 1), 0)
 
         def pack(verdict, req_ns):
             b = verdict.status.shape[0]
@@ -482,6 +566,7 @@ class FusedPlan:
             head = jnp.stack([
                 verdict.status, dur_bits, verdict.valid_use_count,
                 verdict.deny_rule,
+                errs_by_row(verdict, req_ns) if row_errs else
                 jnp.broadcast_to(verdict.err_count.astype(jnp.int32),
                                  (b,))])
             parts = [head]
@@ -738,7 +823,7 @@ class FusedPlan:
         means the next batch at that shape pays an in-band XLA
         trace."""
         out: dict[str, Any] = {}
-        for name in ("_step", "_packer", "_report_packer",
+        for name in ("_step", "_step_wide", "_packer", "_report_packer",
                      "_instep_packer"):
             f = getattr(self, name, None)
             if f is None:
@@ -771,7 +856,11 @@ class FusedPlan:
         a served batch to — the full shape product prewarm compiles."""
         lay = self.engine.ruleset.layout
         tiers = sorted(set(self.str_tiers or (lay.max_str_len,)))
-        return [(b, t) for b in sorted(set(buckets)) for t in tiers]
+        pairs = [(b, t) for b in sorted(set(buckets)) for t in tiers]
+        if self.wide_width and pairs:
+            # the one shape of the wide program
+            pairs.append((self.wide_bucket(buckets), self.wide_width))
+        return pairs
 
     def served_shapes(self) -> set:
         """(bucket rows, byte width) pairs live traffic has actually
@@ -786,15 +875,19 @@ class FusedPlan:
         pairs = self.all_warm_shapes(buckets)
         if not served:
             return pairs
-        tiers = sorted({t for _, t in pairs})
-        bset = {b for b, _ in pairs}
+        lay = self.engine.ruleset.layout
+        tiers = sorted(set(self.str_tiers or (lay.max_str_len,)))
+        bset = set(buckets)
         out: list = []
         for b, w in sorted(served):
-            if b not in bset:
+            if w == self.wide_width and w:   # the wide program's shape
+                pair = (self.wide_bucket(buckets), w)
+            elif b not in bset:
                 continue
-            t = next((t for t in tiers if t >= w), tiers[-1])
-            if (b, t) not in out:
-                out.append((b, t))
+            else:
+                pair = (b, next((t for t in tiers if t >= w), tiers[-1]))
+            if pair not in out:
+                out.append(pair)
         return out or pairs
 
     def warm_shapes(self, pairs, should_stop=None,
@@ -856,7 +949,8 @@ class FusedPlan:
         only."""
         w = int(batch.str_bytes.shape[2])
         tiers = self.str_tiers
-        if len(tiers) < 2 or not isinstance(batch.str_bytes, np.ndarray) \
+        if w == self.wide_width or len(tiers) < 2 \
+                or not isinstance(batch.str_bytes, np.ndarray) \
                 or not isinstance(batch.str_lens, np.ndarray):
             return w
         t = tiers[0]
@@ -883,8 +977,9 @@ class FusedPlan:
             ids=np.zeros((b, lay.n_columns), np.int32),
             present=np.zeros((b, lay.n_columns), bool),
             map_present=np.zeros((b, max(lay.n_maps, 1)), bool),
+            # the wide tier is a plane of its own width
             str_bytes=np.zeros((b, max(lay.n_byte_slots, 1),
-                                lay.max_str_len), np.uint8),
+                                max(lay.max_str_len, tier)), np.uint8),
             str_lens=np.full((b, max(lay.n_byte_slots, 1)),
                              lens, np.int32),
             hash_ids=np.zeros((b, lay.n_columns), np.int32))

@@ -17,6 +17,7 @@ import contextlib
 import gc
 import threading
 import time
+from typing import Mapping
 
 import prometheus_client
 
@@ -231,6 +232,60 @@ def device_program_counters() -> dict:
     """{path: programs launched} as one JSON-able dict."""
     return {path: int(DEVICE_PROGRAMS.labels(path=path)._value.get())
             for path in DEVICE_PROGRAM_PATHS}
+
+
+# -- the length split of the fused Check path (Dispatcher.
+# _split_by_length). `width`: the byte-plane width a served row's
+# program ran at, a narrow tier or the wide plane, added once a
+# batch; `subject`: the byte slot that saturated the wide plane on a
+# row the host then decided (Dispatcher._decide_on_host), the row's
+# first where several did.
+CHECK_ROWS_BY_WIDTH = hostmetrics.default_registry.counter(
+    "mixer_check_rows_by_width_total",
+    "served check rows by the byte-plane width of the program that "
+    "served them (label: width)")
+CHECK_UNDECIDED_ROWS = hostmetrics.default_registry.counter(
+    "mixer_check_undecided_rows_total",
+    "served check rows the device left undecided (a subject past the "
+    "widest byte plane under a rule that reads it) and the host "
+    "decided (label: subject)")
+
+
+# zero-series before the first batch: the default layout's widest
+# narrow tier and wide plane (compiler/layout.py), the request line
+for _w in ("128", "2048"):
+    CHECK_ROWS_BY_WIDTH.inc(0, width=_w)
+CHECK_UNDECIDED_ROWS.inc(0, subject="request.path")
+
+
+def note_rows_by_width(rows_by_width: Mapping) -> None:
+    """One batch: {byte-plane width: rows served at it}."""
+    for width, rows in rows_by_width.items():
+        if rows:
+            CHECK_ROWS_BY_WIDTH.inc(int(rows), width=str(width))
+
+
+def note_undecided_rows(byte_slots: list, firsts: list) -> None:
+    """One batch's host-decided rows: `firsts[i]` indexes, in the
+    layout's `byte_slots`, the first subject of row i that fills the
+    wide plane."""
+    for at in firsts:
+        src = byte_slots[at]
+        CHECK_UNDECIDED_ROWS.inc(subject=src if isinstance(src, str)
+                                 else f"{src[0]}[{src[1]}]")
+
+
+def length_split_counters() -> dict:
+    """{"rows_by_width": {width: rows}, "undecided": {subject: rows}}
+    as one JSON-able dict."""
+    return {
+        "rows_by_width": {labels["width"]: int(
+            CHECK_ROWS_BY_WIDTH.value(**labels))
+            for labels in CHECK_ROWS_BY_WIDTH.label_sets()},
+        "undecided": {labels["subject"]: int(
+            CHECK_UNDECIDED_ROWS.value(**labels))
+            for labels in CHECK_UNDECIDED_ROWS.label_sets()},
+    }
 
 
 # -- the resident DFA banks of the plan in force, set at plan build

@@ -65,6 +65,10 @@ class Counter:
     def value(self, **labels: str) -> float:
         return self._values.get(_label_key(labels), 0.0)
 
+    def label_sets(self) -> list[dict]:
+        with self._lock:
+            return [dict(k) for k in self._values]
+
     def expose(self) -> Iterable[str]:
         if self.help:
             yield f"# HELP {self.name} {self.help}"

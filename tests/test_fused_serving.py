@@ -657,11 +657,16 @@ def test_map_served_shapes_prioritizes_live_traffic():
         sel = plan.map_served_shapes((8, 32), {(8, small_tier)})
         assert sel == [(8, small_tier)]
         # a width no tier holds maps to the largest; foreign buckets
-        # are dropped
-        big = max(t for _, t in pairs)
+        # are dropped (the wide program's one pair, the last, aside)
+        assert pairs[-1] == (32, plan.wide_width)
+        big = max(t for _, t in pairs[:-1])
         sel = plan.map_served_shapes((8, 32), {(8, big + 1),
                                                (999, small_tier)})
-        assert sel == [(8, max(t for _, t in pairs))]
+        assert sel == [(8, big)]
+        # the wide program's shape maps onto itself, whatever bucket
+        # the old plan launched it at
+        assert plan.map_served_shapes(
+            (8, 32), {(8, plan.wide_width)}) == [pairs[-1]]
     finally:
         srv.close()
 

@@ -59,8 +59,8 @@ def _server(sizes: dict, config) -> RuntimeServer:
 
 def _at_the_cap(sizes: dict, config) -> dict:
     """A request line of 128 bytes or more that its block's pattern
-    full-matches: undecidable on the device, so it ought to be the
-    host's to answer (it is not: the xfail below)."""
+    full-matches: undecidable on the 128-byte plane, so the length
+    split serves it on the wide one."""
     r = next(r for r in range(sizes["rules"])
              if config.family_of(sizes, r) == 3 and r % 3 == 0)   # denies
     return {"destination.service":
@@ -220,11 +220,6 @@ def test_rows_without_a_cookie_and_the_row_at_the_cap(served):
     assert served["expected"][ROWS] == served["oracle"][ROWS] == 7
 
 
-@pytest.mark.xfail(strict=True, reason=(
-    "a subject at the 128-byte cap is undecidable on the device and its "
-    "rule errs; no such row goes to the host, so the wire answers OK "
-    "where the snapshot denies (guarantee 1 broken past the cap: the "
-    "err plane's handling predates the deployment, ROADMAP Reach 8)"))
 def test_the_wire_answers_the_row_at_the_cap_as_the_snapshot_does(served):
     assert served["wire"][WIRE] == served["expected"][ROWS] == 7
 

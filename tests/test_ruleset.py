@@ -171,6 +171,37 @@ def test_attribute_masks():
     assert all(prog.attr_mask[0, c] for c in cols)
 
 
+def test_rule_guards_bound_the_rules_a_row_can_match():
+    """The id-equality column every M-conjunction of a rule asserts
+    (RuleSetProgram.guards): a row is a candidate of the rules guarded
+    under the id it reads there and of the unguarded ones, of every
+    rule where it lacks the column (a guard then errs, it does not
+    miss)."""
+    rules = [Rule(name="r0", match='as == "x" && a == 1'),
+             Rule(name="r1", match='as == "y" && (a == 1 || b == 2)'),
+             Rule(name="r2", match='(as == "x" && a == 1) || '
+                                   '(as == "z" && b == 2)'),
+             Rule(name="r3", match='b == 2'),            # no guard on `as`
+             Rule(name="r4", match='as != "x" && b == 2'),   # NEQ: none
+             Rule(name="r5", match='as == "x" || a == 1')]   # one side only
+    prog = compile_ruleset(rules, FINDER)
+    col, by_id, free = prog.guards
+    assert col == prog.layout.slot_of("as")
+    ids = {v: prog.interner.lookup(v) for v in "xyz"}
+    assert by_id == {ids["x"]: [0, 2], ids["y"]: [1], ids["z"]: [2]}
+    assert free == [3, 4, 5]
+    bags = [bag_from_mapping(d) for d in (
+        {"as": "x", "a": 1}, {"as": "y"}, {"as": "other"}, {"a": 1})]
+    batch = Tensorizer(prog.layout, prog.interner).tensorize(bags)
+    assert [prog.host_candidates(batch, b) for b in range(4)] == [
+        [0, 2, 3, 4, 5], [1, 3, 4, 5], [3, 4, 5], [0, 1, 2, 3, 4, 5]]
+    # a candidate set never loses a rule that matches
+    m, _, _ = eval_ruleset(prog, bags)
+    for b in range(4):
+        assert set(np.flatnonzero(m[b])) <= set(
+            prog.host_candidates(batch, b))
+
+
 def test_atom_dedup_across_rules():
     rules = [Rule(name=f"r{i}", match=f'a == 2 && b == {i}') for i in range(20)]
     prog = compile_ruleset(rules, FINDER)

@@ -211,6 +211,27 @@ def test_candidate_tier_step_compiles_at_10k_automata(route_plan,
     assert code < 32 * 1024 ** 2, code
 
 
+def test_wide_step_compiles_at_10k_automata(route_plan, one_chip):
+    """`step_wide` (FusedPlan._base_step(wide=True)): the route table's
+    program over the wide byte plane, at the one shape the length split
+    launches it in (256 rows x 2 048 bytes)."""
+    plan = route_plan
+    bucket, width = plan.all_warm_shapes((64, 256, 2048))[-1]
+    assert (bucket, width) == (256, plan.wide_width) and width == 2048
+    tele = plan.telemetry
+    n_real = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    accs = _on(one_chip, (tele._acc_hit, tele._acc_deny, tele._acc_err))
+    step = plan._base_step(wide=True)
+    assert step.__name__ == "step_wide"       # the profiler's name
+    lowered = jax.jit(step).lower(
+        *_step_args(plan, one_chip, bucket, width), n_real, *accs)
+    assert len(lowered.as_text()) < 4 * 1024 ** 2
+    compiled = lowered.compile()
+    _fits(compiled)
+    code = compiled.memory_analysis().generated_code_size_in_bytes
+    assert code < 32 * 1024 ** 2, code
+
+
 @pytest.mark.parametrize("variant", ("fast", "unit", "seg"))
 def test_rolling_quota_alloc_compiles(one_chip, variant):
     """The three alloc kernels DeviceQuotaPool._flush selects between,
