@@ -369,7 +369,7 @@ class MixerGrpcServer:
                     time.perf_counter() >= deadline:
                 monitor.CHECK_DEADLINE_EXPIRED.inc(len(chunk))
                 parts.append([self._expired_response()
-                              for _ in chunk])
+                              for _ in range(len(chunk))])
                 continue
             padded = pad_to_bucket(chunk, buckets)
             parts.append(_real_rows(
@@ -403,7 +403,7 @@ class MixerGrpcServer:
                     time.perf_counter() >= deadline:
                 monitor.CHECK_DEADLINE_EXPIRED.inc(len(chunk))
                 parts.append([self._expired_response()
-                              for _ in chunk])
+                              for _ in range(len(chunk))])
                 continue
             padded = pad_to_bucket(chunk, buckets)
             qrows = [(i, qspecs[lo + i][0], qspecs[lo + i][1])

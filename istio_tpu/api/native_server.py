@@ -15,7 +15,15 @@ Pump threads block in h2srv_take (ctypes releases the GIL, so the C++
 wire keeps running), run the batch through
 RuntimeServer.check_batch_preprocessed / report, resolve quotas via
 the device pools, and hand serialized CheckResponse bytes back for
-C++ to frame. Response serialization is memoized per verdict signature
+C++ to frame. A taken batch stays one buffer from the C++ queue to the
+C++ tensorizer: take_impl writes a fixed-width row index in front of
+the rows' bytes, the pump reads it with one np.frombuffer over its own
+buffer (api/take.TakenRows: no copy of the take, no object a row), the
+tensorizer gets the payloads' spans, and a row becomes a LazyWireBag
+only where something on the host asks for it (a host action, a quota,
+a row the host decides, a response whose bytes depend on its bag;
+every row under an APA) — mixer_front_bags_materialised_total counts
+them. Response serialization is memoized per verdict signature
 (uniform traffic → a handful of distinct responses per snapshot), and
 a batch that comes with its verdict classes (ClassedResponses) is
 serialised and framed once a class, not once a row.
@@ -33,8 +41,7 @@ import numpy as np
 from istio_tpu.adapters.sdk import QuotaArgs
 from istio_tpu.api import mixer_pb2 as pb
 from istio_tpu.api.grpc_server import MixerGrpcServer
-from istio_tpu.api.wire import LazyWireBag
-from istio_tpu.attribute.global_dict import GLOBAL_WORD_LIST
+from istio_tpu.api.take import CHECK, REPORT, TakenRows
 from istio_tpu.native.build import ensure_httpd_built
 from istio_tpu.runtime import forensics, monitor
 from istio_tpu.runtime.server import RuntimeServer
@@ -458,9 +465,9 @@ class NativeMixerServer(MixerGrpcServer):
             while True:
                 with monitor.span("pump_cycle"):
                     with monitor.span("take_wait"):
-                        n = self._take(take)
+                        self._take(take)
                     try:
-                        self._run_batch(take[0], n)
+                        self._run_batch(take[0])
                     except Exception:
                         log.exception("native pump batch failed")
         except _PumpStopped:
@@ -483,69 +490,47 @@ class NativeMixerServer(MixerGrpcServer):
                 take[0] = ctypes.create_string_buffer(-int(n) * 2)
         raise _PumpStopped
 
-    @staticmethod
-    def _parse_take(blob: bytes) -> list[tuple]:
-        """→ [(tag, kind, payload, gwc, dedup, quotas{name: (amount,
-        best_effort)}, traceparent)]."""
-        items = []
-        (_, n) = struct.unpack_from("<II", blob, 0)
-        off = 8
-        for _ in range(n):
-            (tag,) = struct.unpack_from("<Q", blob, off)
-            off += 8
-            kind = blob[off]
-            off += 1
-            (plen,) = struct.unpack_from("<I", blob, off)
-            off += 4
-            payload = blob[off:off + plen]
-            off += plen
-            (gwc, dlen) = struct.unpack_from("<II", blob, off)
-            off += 8
-            dedup = blob[off:off + dlen].decode("utf-8", "replace")
-            off += dlen
-            (tplen,) = struct.unpack_from("<I", blob, off)
-            off += 4
-            traceparent = blob[off:off + tplen].decode(
-                "utf-8", "replace")
-            off += tplen
-            (nq,) = struct.unpack_from("<H", blob, off)
-            off += 2
-            quotas = {}
-            for _q in range(nq):
-                (nlen,) = struct.unpack_from("<I", blob, off)
-                off += 4
-                qname = blob[off:off + nlen].decode("utf-8", "replace")
-                off += nlen
-                amount, be = struct.unpack_from("<qB", blob, off)
-                off += 9
-                quotas[qname] = (amount, bool(be))
-            items.append((tag, kind, payload, gwc, dedup, quotas,
-                          traceparent))
-        return items
+    _read_take = staticmethod(TakenRows.read)
 
-    def _run_batch(self, buf, n: int) -> None:
-        """One taken batch: the first `n` bytes of the pump's take
-        buffer `buf`."""
-        items: list = []
+    @staticmethod
+    def _unanswered(tags: np.ndarray, completions: _Completions,
+                    deferred: set) -> list[int]:
+        """The tags of a take that no completion names and no deferred
+        quota row holds: exact, by tag and not by count (a fault may
+        answer one row twice and another never)."""
+        done = [np.fromiter((c[0] for c in completions), np.uint64,
+                            len(completions)),
+                np.fromiter(deferred, np.uint64, len(deferred)),
+                *completions.framed_tags]
+        return tags[~np.isin(tags, np.concatenate(done))].tolist()
+
+    def _run_batch(self, buf) -> None:
+        """One taken batch: the blob h2srv_take wrote into the pump's
+        buffer `buf` (api/take.py: a row index in front of the rows'
+        bytes), read where it lies. The pump does not take again
+        before this batch's completions are sent, so the buffer is
+        stable for the batch's life; what outlives it (the bag of a
+        deferred quota row, of a tapped row) owns a copy of its
+        bytes. Checks and reports are split by the index's `kind`
+        column; a row becomes a python object only where something on
+        the host asks for it."""
+        taken = None
         completions = _Completions()
         deferred: set[int] = set()
         try:
             with monitor.span("wire_decode") as decode:
-                items = self._parse_take(buf.raw[:n])
-                checks = [it for it in items if it[1] == 0]
-                bags = []
-                for _, _, payload, gwc, _, _, _ in checks:
-                    native = gwc in (0, len(GLOBAL_WORD_LIST))
-                    bags.append(self.runtime.preprocess(
-                        LazyWireBag(payload, gwc or None,
-                                    native_ok=native)))
-            if checks:
+                taken = self._read_take(buf)
+                checks = taken.of_kind(CHECK)
+                # the batch itself when the snapshot has no APA; with
+                # one, every row's bag goes through it
+                bags = self.runtime.preprocess_batch(checks)
+            if len(checks):
                 # flight-recorder pre-mark: the wire→bag decode wall
                 # joins the next batch tape on this pump thread
                 # (httpd.cpp's t_decode_ns covers the C++ side; this
                 # is the python envelope's share)
                 forensics.RECORDER.note_wire_decode(decode.seconds)
-            self._run_batch_inner(items, checks, bags, completions,
+            self._run_batch_inner(taken, checks, bags, completions,
                                   deferred)
         except Exception:
             # belt: NO failure may abandon a row — an unanswered tag
@@ -554,23 +539,30 @@ class NativeMixerServer(MixerGrpcServer):
             # batch-mates' connections)
             log.exception("native pump batch failed")
         with monitor.span("send"):
-            done = {tag for tag, _, _ in completions} | deferred
-            for tags in completions.framed_tags:
-                done.update(tags.tolist())
-            for item in items:
-                if item[0] not in done:
-                    completions.append(
-                        (item[0], 13,
-                         b"internal: batch processing failed"))
+            if taken is not None:
+                completions.extend(
+                    (tag, 13, b"internal: batch processing failed")
+                    for tag in self._unanswered(taken.tags, completions,
+                                                deferred))
             self._send_completions(completions)
 
-    def _run_batch_inner(self, items: list, checks: list, bags: list,
-                         completions: list, deferred: set) -> None:
+    def _run_batch_inner(self, taken: TakenRows, checks: TakenRows,
+                         bags, completions: list,
+                         deferred: set) -> None:
         from istio_tpu.utils import tracing
 
-        reports = [it for it in items if it[1] == 1]
+        def first_parent(rows: TakenRows):
+            # first row whose header PARSES (a malformed header in an
+            # earlier row must not suppress a valid one behind it);
+            # only rows that sent one are looked at
+            return next(
+                (p for p in map(tracing.parent_from_traceparent,
+                                rows.traceparents())
+                 if p is not None), None)
 
-        if checks:
+        reports = taken.of_kind(REPORT)
+
+        if len(checks):
             # ROOT span at wire decode (API-layer root, same role as
             # the grpc fronts' rpc.check): downstream engine spans on
             # this pump thread parent under it via the thread-local
@@ -579,33 +571,23 @@ class NativeMixerServer(MixerGrpcServer):
             # parents under the FIRST row's W3C traceparent (wire
             # header, decoded in C++) when one was sent — the same
             # oldest-request attribution rule the batcher uses.
-            # first row whose header PARSES (a malformed header in an
-            # earlier row must not suppress a valid one behind it)
-            parent = next(
-                (p for p in (tracing.parent_from_traceparent(it[6])
-                             for it in checks if it[6])
-                 if p is not None), None)
             span_ctx = tracing.get_tracer().span(
-                "rpc.check", parent=parent, transport="native",
-                batch=len(checks))
+                "rpc.check", parent=first_parent(checks),
+                transport="native", batch=len(checks))
             with span_ctx as span:
                 self._run_checks(checks, bags, completions, deferred,
                                  span=span)
 
-        if reports:
+        if len(reports):
             # rpc.report root at the wire (same role as rpc.check
             # above): parents under the first report row's W3C
             # traceparent when one was sent
-            parent = next(
-                (p for p in (tracing.parent_from_traceparent(it[6])
-                             for it in reports if it[6])
-                 if p is not None), None)
             with tracing.get_tracer().span(
-                    "rpc.report", parent=parent, transport="native",
-                    rpcs=len(reports)) as span:
+                    "rpc.report", parent=first_parent(reports),
+                    transport="native", rpcs=len(reports)) as span:
                 self._run_reports(reports, completions, span=span)
 
-    def _run_reports(self, reports: list, completions: list,
+    def _run_reports(self, reports: TakenRows, completions: list,
                      span: dict | None = None) -> None:
         """ACK-AFTER-ENQUEUE report serving (the ingestion plane's
         native leg): each RPC's records are decoded, admitted into the
@@ -625,11 +607,11 @@ class NativeMixerServer(MixerGrpcServer):
 
         n_records = 0
         first_bad = 0
-        for tag, _, payload, _, _, _, _ in reports:
+        for row, tag in enumerate(reports.tags.tolist()):
             monitor.REPORT_REQUESTS.inc()
             try:
                 t0 = _time.perf_counter()
-                req = pb.ReportRequest.FromString(payload)
+                req = pb.ReportRequest.FromString(reports.payload(row))
                 bags = self._decode_report(req)
                 monitor.observe_report_stage(
                     "wire_decode", _time.perf_counter() - t0)
@@ -679,10 +661,10 @@ class NativeMixerServer(MixerGrpcServer):
             span["tags"]["status"] = "ok" if first_bad == 0 \
                 else str(first_bad)
 
-    def _run_checks(self, checks: list, bags: list, completions: list,
+    def _run_checks(self, checks: TakenRows, bags, completions: list,
                     deferred: set, span: dict | None = None) -> None:
-        """`bags`: the preprocessed LazyWireBag of each row of
-        `checks` (_run_batch built them under the wire_decode span)."""
+        """`bags`: the rows of `checks` as the dispatcher takes them
+        (`checks` itself, or its preprocessed bags under an APA)."""
         monitor.CHECK_REQUESTS.inc(len(checks))
         # the C++ wire carries no per-RPC deadline — apply the
         # server-side default (--default-check-deadline-ms) from the
@@ -699,17 +681,17 @@ class NativeMixerServer(MixerGrpcServer):
         qspecs = None
         if target is not None:
             _, by_name = target
-            qspecs = []
-            for _, _, _, _, dedup, quotas, _ in checks:
-                spec = None
+            qspecs = [None] * len(checks)
+            for row in checks.asking():
+                quotas = checks.quotas(row)
                 if len(quotas) == 1:
                     (qname, (amount, be)), = quotas.items()
                     if qname in by_name:
-                        spec = (qname, QuotaArgs(
+                        dedup = checks.dedup_id(row)
+                        qspecs[row] = (qname, QuotaArgs(
                             quota_amount=amount, best_effort=be,
                             dedup_id=dedup + ":" + qname
                             if dedup else ""))
-                qspecs.append(spec)
             if not any(qspecs):
                 qspecs = None
         from istio_tpu.runtime.resilience import CheckRejected
@@ -726,8 +708,8 @@ class NativeMixerServer(MixerGrpcServer):
             # answer every row with the honest status code instead of
             # letting the belt degrade it to a blanket INTERNAL
             msg = str(exc).encode()
-            for tag, _, _, _, _, _, _ in checks:
-                completions.append((tag, exc.grpc_code, msg))
+            completions.extend((tag, exc.grpc_code, msg)
+                               for tag in checks.tags.tolist())
             if span is not None:
                 span["tags"]["status"] = str(exc.grpc_code)
             return
@@ -771,7 +753,7 @@ class NativeMixerServer(MixerGrpcServer):
         self._resp_memo[key] = raw
         return raw, False
 
-    def _frame_classes(self, checks: list, results,
+    def _frame_classes(self, checks: TakenRows, results,
                        completions: _Completions) -> list[int]:
         """Serialise once a verdict class (ClassedResponses) and frame
         the rows that share bytes together: one record array a wire
@@ -779,7 +761,7 @@ class NativeMixerServer(MixerGrpcServer):
         below it. → the rows left to the per-row code: those that ask
         for a quota, and those whose bytes depend on their bag."""
         class_of = results.class_of
-        asking = [row for row, item in enumerate(checks) if item[5]]
+        asking = checks.asking()
         here = range(len(results.classes))
         if asking:
             # a class all of whose rows ask for a quota is not looked
@@ -807,8 +789,7 @@ class NativeMixerServer(MixerGrpcServer):
             wire_of[asking] = -1
         order = np.argsort(wire_of, kind="stable")
         counts = np.bincount(wire_of + 1, minlength=len(raws) + 1)
-        tags = np.fromiter((item[0] for item in checks), np.uint64,
-                           len(checks))
+        tags = checks.tags
         left = at = int(counts[0])
         for raw, rows in zip(raws, counts[1:].tolist()):
             mine = tags[order[at:at + rows]]
@@ -823,9 +804,12 @@ class NativeMixerServer(MixerGrpcServer):
         monitor.CHECK_RESPONSES.inc(len(checks) - left - built)
         return order[:left].tolist()
 
-    def _serialize_rows(self, checks: list, bags: list, results: list,
+    def _serialize_rows(self, checks: TakenRows, bags, results: list,
                         inres: dict, completions: _Completions,
                         deferred: set, span: dict | None) -> None:
+        """The per-row code: rows no verdict class answered. A row's
+        bag (`bags[row]`) is asked for only by a quota row and by a
+        response whose bytes depend on it."""
         # `status` tag (batch-level: ok or the first non-OK code) so
         # /debug/traces can filter failing check spans on this front
         if span is not None:
@@ -838,9 +822,11 @@ class NativeMixerServer(MixerGrpcServer):
                 and len(results.classes) < len(checks) and not inres:
             rows = self._frame_classes(checks, results, completions)
         memo_hits = 0
+        tags = checks.tags
+        asking = set(checks.asking())
         for row in rows:
-            item, bag, result = checks[row], bags[row], results[row]
-            tag, _, _, _, dedup, quotas, _ = item
+            result, tag = results[row], int(tags[row])
+            quotas = checks.quotas(row) if row in asking else {}
             try:
                 if row in inres:
                     # quota already allocated in the check trip;
@@ -853,7 +839,7 @@ class NativeMixerServer(MixerGrpcServer):
                         (qname, _), = quotas.items()
                         qpair = [(qname, inres[row])]
                     raw = self._check_response(
-                        None, bag, result,
+                        None, bags[row], result,
                         quotas=qpair).SerializeToString()
                     completions.append((tag, 0, raw))
                     continue
@@ -863,10 +849,11 @@ class NativeMixerServer(MixerGrpcServer):
                     # wait out the quota flush window + device
                     # trip (that added ~2 serialized trips to
                     # EVERY row's latency)
-                    req = _RowRequest(dedup, {
+                    req = _RowRequest(checks.dedup_id(row), {
                         name: pb.CheckRequest.QuotaParams(
                             amount=amount, best_effort=be)
                         for name, (amount, be) in quotas.items()})
+                    bag = bags[row]
                     self._defer_quota_row(
                         tag, bag, result,
                         self._submit_quotas(req, bag, result))
@@ -880,7 +867,7 @@ class NativeMixerServer(MixerGrpcServer):
             raw, held = self._memo_response(result)
             if raw is None:
                 raw = self._check_response(
-                    None, bag, result,
+                    None, bags[row], result,
                     quotas=[]).SerializeToString()
             memo_hits += held
             completions.append((tag, 0, raw))

@@ -402,6 +402,22 @@ struct PendingItem {
   int64_t t_enq_ns;
 };
 
+// One row of the index take_impl writes in front of a take's heap;
+// api/take.py TAKE_ROW mirrors it field for field. Offsets count from
+// the blob's first byte; a length of 0 is an absent field.
+struct TakeRow {
+  uint64_t tag;
+  uint32_t payload_off, payload_len;
+  uint32_t global_word_count;
+  uint32_t dedup_off, dedup_len;
+  uint32_t traceparent_off, traceparent_len;
+  uint32_t quota_off;
+  uint16_t quota_count;
+  uint8_t kind;   // 0 Check, 1 Report
+  uint8_t reserved[5];
+};
+static_assert(sizeof(TakeRow) == 48, "api/take.py mirrors this layout");
+
 struct Completion {
   uint64_t tag;
   int32_t grpc_status;
@@ -1108,13 +1124,6 @@ void io_loop(Server* srv) {
   for (Conn* c : all) close_conn(srv, c);
 }
 
-void put_u32(std::string* s, uint32_t v) {
-  s->append(reinterpret_cast<char*>(&v), 4);
-}
-void put_u64(std::string* s, uint64_t v) {
-  s->append(reinterpret_cast<char*>(&v), 8);
-}
-
 // Ordered teardown (the graceful-lifecycle plane's native leg):
 //   1. stop intake + mark stopping (pumps in take return -1, the IO
 //      loop exits its poll cycle);
@@ -1307,46 +1316,70 @@ int64_t take_impl(Server* srv, int32_t timeout_ms, uint8_t* buf,
 
   int32_t n = static_cast<int32_t>(srv->queue.size());
   if (n > srv->max_batch) n = srv->max_batch;
-  // size pass
-  int64_t need = 8;
+  // size pass: the index, then each row's bytes on the heap. Offsets
+  // are 32 bits, so a take stops at the row that would pass them (a
+  // message is 16 MiB at most: the first row always fits)
+  int64_t need = 8 + static_cast<int64_t>(n) * sizeof(TakeRow);
   for (int32_t i = 0; i < n; i++) {
     const PendingItem& it = srv->queue[i];
-    need += 8 + 1 + 4 + 4 + 4 + 4 + 2;
-    need += it.kind ? it.report_raw.size() : it.env.attributes.size();
-    need += it.env.dedup.size();
-    need += it.traceparent.size();
-    for (const auto& q : it.env.quotas) need += 4 + q.name.size() + 9;
+    int64_t row =
+        (it.kind ? it.report_raw.size() : it.env.attributes.size()) +
+        it.env.dedup.size() + it.traceparent.size();
+    for (const auto& q : it.env.quotas) row += 4 + q.name.size() + 9;
+    if (need + row > UINT32_MAX) {
+      need -= static_cast<int64_t>(n - i) * sizeof(TakeRow);
+      n = i;
+      break;
+    }
+    need += row;
   }
   if (need > cap) return -need;
 
-  std::string out;
-  out.reserve(need);
-  put_u32(&out, static_cast<uint32_t>(srv->counters[2]));
-  put_u32(&out, static_cast<uint32_t>(n));
+  // Blob: u32 batch number, u32 n, n TakeRow, then the heap the rows
+  // point into, written straight into the pump's buffer.
+  auto put = [buf](int64_t at, const void* p, size_t len) {
+    memcpy(buf + at, p, len);
+    return static_cast<uint32_t>(at);
+  };
+  const uint32_t head[2] = {static_cast<uint32_t>(srv->counters[2]),
+                            static_cast<uint32_t>(n)};
+  put(0, head, 8);
+  int64_t heap = 8 + static_cast<int64_t>(n) * sizeof(TakeRow);
   const int64_t t_take_ns = mono_ns();
   int64_t waited_ns = 0;
   for (int32_t i = 0; i < n; i++) {
     PendingItem& it = srv->queue.front();
     waited_ns += t_take_ns - it.t_enq_ns;
-    put_u64(&out, it.tag);
-    out.push_back(static_cast<char>(it.kind));
     const std::string& payload =
         it.kind ? it.report_raw : it.env.attributes;
-    put_u32(&out, static_cast<uint32_t>(payload.size()));
-    out += payload;
-    put_u32(&out, it.env.global_word_count);
-    put_u32(&out, static_cast<uint32_t>(it.env.dedup.size()));
-    out += it.env.dedup;
-    put_u32(&out, static_cast<uint32_t>(it.traceparent.size()));
-    out += it.traceparent;
-    uint16_t nq = static_cast<uint16_t>(it.env.quotas.size());
-    out.append(reinterpret_cast<char*>(&nq), 2);
+    TakeRow row = {};
+    row.tag = it.tag;
+    row.kind = it.kind;
+    row.global_word_count = it.env.global_word_count;
+    row.payload_off = put(heap, payload.data(), payload.size());
+    row.payload_len = static_cast<uint32_t>(payload.size());
+    heap += payload.size();
+    row.dedup_off = put(heap, it.env.dedup.data(), it.env.dedup.size());
+    row.dedup_len = static_cast<uint32_t>(it.env.dedup.size());
+    heap += it.env.dedup.size();
+    row.traceparent_off =
+        put(heap, it.traceparent.data(), it.traceparent.size());
+    row.traceparent_len = static_cast<uint32_t>(it.traceparent.size());
+    heap += it.traceparent.size();
+    // quota section: per quota u32 name length, name, i64 amount,
+    // u8 best_effort
+    row.quota_off = static_cast<uint32_t>(heap);
+    row.quota_count = static_cast<uint16_t>(it.env.quotas.size());
     for (const auto& q : it.env.quotas) {
-      put_u32(&out, static_cast<uint32_t>(q.name.size()));
-      out += q.name;
-      put_u64(&out, static_cast<uint64_t>(q.amount));
-      out.push_back(static_cast<char>(q.best_effort));
+      const uint32_t nlen = static_cast<uint32_t>(q.name.size());
+      put(heap, &nlen, 4);
+      put(heap + 4, q.name.data(), nlen);
+      put(heap + 4 + nlen, &q.amount, 8);
+      put(heap + 12 + nlen, &q.best_effort, 1);
+      heap += 4 + nlen + 9;
     }
+    put(8 + static_cast<int64_t>(i) * sizeof(TakeRow), &row,
+        sizeof(TakeRow));
     srv->queue.pop_front();
   }
   if (!srv->queue.empty()) srv->first_enq_ns = mono_ns();
@@ -1357,8 +1390,7 @@ int64_t take_impl(Server* srv, int32_t timeout_ms, uint8_t* buf,
   int b = 0;
   while ((1 << b) < n && b < 15) b++;
   srv->hist[b]++;
-  memcpy(buf, out.data(), out.size());
-  return static_cast<int64_t>(out.size());
+  return heap;
 }
 
 }  // namespace

@@ -182,12 +182,34 @@ class NativeTensorizer:
         self._staged_decodes = 0
 
     def tensorize_wire(self, records: Sequence[bytes]) -> AttributeBatch:
+        """Records held as `bytes`, one a row (the gRPC and BatchCheck
+        fronts, the batcher)."""
+        n = len(records)
+        return self._tensorize(
+            n, (ctypes.c_char_p * n)(*records),
+            (ctypes.c_int64 * n)(*[len(r) for r in records]))
+
+    def tensorize_spans(self, base: int, offsets: np.ndarray,
+                        lengths: np.ndarray) -> AttributeBatch:
+        """Records that lie in one buffer at address `base` (a taken
+        batch, api/take.TakenRows.wire_spans): row i is `lengths[i]`
+        (int64) bytes at `offsets[i]` (uint64); an empty row is a
+        padding row. The pointer array is one numpy add, laid out as
+        the shim's `const uint8_t* const*`; the caller keeps the
+        buffer alive and unwritten for the call."""
+        ptrs = offsets + np.uint64(base)
+        return self._tensorize(
+            len(ptrs),
+            ptrs.ctypes.data_as(ctypes.POINTER(ctypes.c_char_p)),
+            lengths.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)))
+
+    def _tensorize(self, n: int, bufs, lens) -> AttributeBatch:
         # one decode at a time: the shim handle's intern table and the
         # remap array are shared mutable state (pipelined batches may
         # arrive concurrently from the batcher pool) — and the lock is
         # what makes the staging-ring rotation race-free
         with self._call_lock:
-            return self._tensorize_wire_locked(records)
+            return self._tensorize_locked(n, bufs, lens)
 
     @staticmethod
     def _aligned_zeros(shape: tuple, dtype) -> np.ndarray:
@@ -275,10 +297,10 @@ class NativeTensorizer:
                 "depth": self.staging_depth,
                 "staged_decodes": self._staged_decodes}
 
-    def _tensorize_wire_locked(self, records: Sequence[bytes]
-                               ) -> AttributeBatch:
+    def _tensorize_locked(self, n: int, bufs, lens) -> AttributeBatch:
+        """`bufs` / `lens`: the n records' addresses and lengths, as
+        the shim takes them."""
         lay = self.layout
-        n = len(records)
         buf_set = self._buffers_for(n)
         ids = buf_set["ids"]
         hash_ids = buf_set["hash_ids"]
@@ -287,8 +309,6 @@ class NativeTensorizer:
         str_bytes = buf_set["str_bytes"]
         str_lens = buf_set["str_lens"]
 
-        bufs = (ctypes.c_char_p * n)(*records)
-        lens = (ctypes.c_int64 * n)(*[len(r) for r in records])
         wide = buf_set.get("wide")
         n_wide = ctypes.c_int32(0)
         rc = self._lib.shim_tensorize(

@@ -50,18 +50,24 @@ def bucket_size(n: int, buckets: tuple[int, ...]) -> int:
     return buckets[-1]
 
 
-def pad_to_bucket(bags, buckets: tuple[int, ...]) -> list:
+def pad_to_bucket(bags, buckets: tuple[int, ...]):
     """`bags` + PadBags up to the bucket for len(bags) — the single
     home of bucket padding (batcher, BatchCheck front, fused report
     resolve). Caller chunks to buckets[-1] first; an over-bucket
-    length returns the bags unpadded."""
+    length returns the bags unpadded. A batch that counts its padding
+    (api/take.TakenRows) pads itself and makes no PadBag."""
     target = bucket_size(len(bags), buckets)
+    pad_to = getattr(bags, "pad_to", None)
+    if pad_to is not None:
+        return pad_to(target)
     return list(bags) + [PadBag() for _ in range(target - len(bags))]
 
 
 def trim_pads(bags):
     """`bags` without their trailing PadBag rows — the single inverse
     of pad_to_bucket (padding is always appended at the tail)."""
+    if hasattr(bags, "pad_to"):
+        return bags.real
     n = len(bags)
     while n and isinstance(bags[n - 1], PadBag):
         n -= 1

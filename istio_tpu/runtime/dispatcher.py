@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import logging
 import time
 from typing import Any, Mapping, Sequence
@@ -248,11 +249,23 @@ class Dispatcher:
         """(batch, ns_ids) via the C++ wire decoder when every bag
         carries wire bytes, else the python tensorizer."""
         plan = self.fused
-        wires = [getattr(bag, "wire", None) for bag in bags]
-        if plan.native is not None and all(w is not None
-                                           for w in wires):
+        # a taken batch (api/take.TakenRows) hands its rows over where
+        # they lie in the pump's buffer; other fronts hold bytes a bag
+        decode = None
+        if plan.native is not None:
+            spans = bags.wire_spans() \
+                if hasattr(bags, "wire_spans") else None
+            if spans is not None:
+                decode = functools.partial(plan.native.tensorize_spans,
+                                           *spans)
+            else:
+                wires = [getattr(bag, "wire", None) for bag in bags]
+                if all(w is not None for w in wires):
+                    decode = functools.partial(
+                        plan.native.tensorize_wire, wires)
+        if decode is not None:
             with monitor.span("tensorize.decode"):
-                batch = plan.native.tensorize_wire(wires)
+                batch = decode()
             # a batch with long rows is staged a part at a time
             # (_split_by_length), or, on the paths that do not split,
             # transferred by the launch
@@ -711,7 +724,7 @@ class Dispatcher:
         deny_rule = packed[3]
         rs = snap.ruleset
         ex = self.executor
-        host_pending: list[list] | None = None
+        host_pending: dict[int, list] | None = None
         # Any exception from here to the claims must not leak
         # submitted-but-unclaimed actions: the conservation ledger
         # (submitted == resolved) is a smoke/bench gate, and a
@@ -789,15 +802,17 @@ class Dispatcher:
                 # handler bulkhead lanes WHILE the fold below decodes
                 # the referenced/presence planes — the response loop
                 # then claims results in rule order, bounded by the
-                # request deadline. One list per row, entries (rule
+                # request deadline. One list per row under a host
+                # action (only their bags are asked for), entries (rule
                 # idx, HostAction | final CheckResult) in exactly the
                 # order the inline loop would have executed them, so
                 # lowest-rule-index-wins merging is byte-identical.
                 if ex is not None and len(ha):
                     from istio_tpu.runtime.config import _qualify
                     from istio_tpu.runtime.executor import check_fallback
-                    host_pending = []
-                    for b, bag in enumerate(bags):
+                    host_pending = {}
+                    for b in np.flatnonzero(host_rows).tolist():
+                        bag = bags[b]
                         row: list = []
                         for ridx in ha[active_sub[b, ha_pos]]:
                             ridx = int(ridx)
@@ -826,7 +841,7 @@ class Dispatcher:
                                         self._bound_check(
                                             handler, template, instance),
                                         check_fallback)))
-                        host_pending.append(row)
+                        host_pending[b] = row
 
                 # Referenced/presence construction deduplicated across
                 # the batch: uniform traffic produces a handful of
@@ -931,7 +946,7 @@ class Dispatcher:
                     dev_applied = False
                     host_active = ha[active_sub[b, ha_pos]] \
                         if host_rows[b] else ()
-                    pend = host_pending[b] \
+                    pend = host_pending.get(b) \
                         if host_pending is not None else None
                     pi = 0
                     for ridx in host_active:
@@ -1039,9 +1054,8 @@ class Dispatcher:
                     rows = np.flatnonzero(denied)
                     if len(rows):
                         tele.sample_rows(
-                            deny_rule[rows].tolist(),
-                            status[rows].tolist(),
-                            [bags[b] for b in rows.tolist()], tele_span)
+                            rows.tolist(), deny_rule[rows].tolist(),
+                            status[rows].tolist(), bags, tele_span)
             if self.recorder is not None:
                 # canary tap: bags/out are already padding-trimmed; one
                 # stride check per batch, bounded appends for sampled rows
@@ -1055,7 +1069,7 @@ class Dispatcher:
             return out
         except BaseException:
             if host_pending is not None:
-                for _row in host_pending:
+                for _row in host_pending.values():
                     for _ridx, _item in _row:
                         if not isinstance(_item, CheckResult):
                             ex.abandon(_item)
@@ -1090,7 +1104,7 @@ class Dispatcher:
         histogram still covers these requests via the batcher)."""
         from istio_tpu.runtime.batcher import trim_pads
 
-        bags = trim_pads(list(bags))
+        bags = trim_pads(bags)
         oracle = self._oracle()
         out: list[CheckResponse] = []
         n_err = 0

@@ -65,6 +65,23 @@ CHECK_RESPONSES = prometheus_client.Counter(
     "mixer_grpc_check_responses", "Check responses sent",
     registry=REGISTRY)
 
+# -- the native front's taken batches (api/take.TakenRows): a row is a
+# column entry of the pump's buffer until something on the host asks
+# for its bag. Beside CHECK_REQUESTS (the rows the front took) it says
+# how often that happens.
+FRONT_BAGS_MATERIALISED = prometheus_client.Counter(
+    "mixer_front_bags_materialised_total",
+    "rows of taken batches made into a bag object (asked for by a host "
+    "action, a quota, the host oracle, the per-row response, a tap)",
+    registry=REGISTRY)
+
+
+def front_bag_counters() -> dict:
+    """Bags made and Check rows decoded, as one JSON-able dict."""
+    return {"materialised": int(FRONT_BAGS_MATERIALISED._value.get()),
+            "rows": int(CHECK_REQUESTS._value.get())}
+
+
 # -- overload-resilience counters (runtime/resilience.py + batcher
 # admission control). Per-REQUEST counts except batch_failures (per
 # batch); label series are pre-touched below so every reason exposes
@@ -899,12 +916,15 @@ def gc_pause_snapshot(since: dict | None = None) -> dict:
                         for at in HEAP_SETTLE_SITES}}
 
 
-def observe_check_e2e(seconds: float) -> None:
-    """Per-request end-to-end observation; gauges refresh lazily via
+def observe_check_e2e(seconds: float, n: int = 1) -> None:
+    """End-to-end observation of `n` requests that waited `seconds`
+    each (a pre-formed batch's rows share its wall: one call a batch,
+    counted a row a request); gauges refresh lazily via
     refresh_latency_gauges() (sorting the window per request would put
     an O(n log n) on the hot path)."""
-    CHECK_E2E_SECONDS.observe(seconds)
-    CHECK_WINDOW.observe(seconds)
+    if n > 0:
+        CHECK_E2E_SECONDS.observe_key((), seconds, n)
+        CHECK_WINDOW.observe(seconds, n)
 
 
 def refresh_latency_gauges() -> dict:

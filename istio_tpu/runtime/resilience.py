@@ -472,7 +472,7 @@ class ResilientChecker:
 
     def _n_real(self, bags: Sequence[Any]) -> int:
         from istio_tpu.runtime.batcher import trim_pads
-        return len(trim_pads(list(bags)))
+        return len(trim_pads(bags))
 
     def _device_call(self, bags: Sequence[Any],
                      deadline: float | None) -> Sequence[Any]:

@@ -264,18 +264,19 @@ class RuleTelemetry:
             for ridx, n in err_counts.items():
                 self._host_err[ridx] += n
 
-    def sample_rows(self, ridxs, statuses, bags, span) -> None:
-        """Reservoir-sample one batch's denied/errored requests, row i
-        decided by rule `ridxs[i]`, under one lock: keep the bag
-        (compressed attribute bag — decoded at drain, never here) and
-        the active trace span ids so the exemplar links straight to a
-        RingReporter trace. Every row is drawn for, a uniform
-        reservoir a rule; an entry is built only for a row it keeps."""
+    def sample_rows(self, rows, ridxs, statuses, bags, span) -> None:
+        """Reservoir-sample one batch's denied/errored requests, row
+        `rows[i]` of `bags` decided by rule `ridxs[i]`, under one
+        lock: keep the bag (compressed attribute bag — decoded at
+        drain, never here) and the active trace span ids so the
+        exemplar links straight to a RingReporter trace. Every row is
+        drawn for, a uniform reservoir a rule; an entry is built, and
+        the row's bag asked for, only for a row it keeps."""
         trace_id = span.get("traceId") if span else None
         span_id = span.get("id") if span else None
         now = time.time()
         with self._lock:
-            for ridx, status, bag in zip(ridxs, statuses, bags):
+            for row, ridx, status in zip(rows, ridxs, statuses):
                 seen = self._ex_seen.get(ridx, 0) + 1
                 self._ex_seen[ridx] = seen
                 bucket = self._ex.setdefault(ridx, [])
@@ -286,7 +287,7 @@ class RuleTelemetry:
                     j = self._rng.randrange(seen)
                     if j >= self._ex_cap:
                         continue
-                bucket[j] = {"status": status, "bag": bag,
+                bucket[j] = {"status": status, "bag": bags[row],
                              "trace_id": trace_id, "span_id": span_id,
                              "t": now}
 

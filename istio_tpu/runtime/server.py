@@ -1021,6 +1021,15 @@ class RuntimeServer:
             return bag
         return d.preprocess(bag)
 
+    def preprocess_batch(self, bags: Sequence[Bag]) -> Sequence[Bag]:
+        """preprocess() over a pre-formed batch. Whether an APA is
+        configured is a property of the snapshot, read once a batch:
+        without one the batch comes back as it is, no row touched."""
+        d = self.controller.dispatcher
+        if not d.has_apa:
+            return bags
+        return [d.preprocess(bag) for bag in bags]
+
     def _run_check_batch(self, bags: Sequence[Bag],
                          deadline: float | None = None
                          ) -> Sequence[CheckResponse]:
@@ -1095,11 +1104,10 @@ class RuntimeServer:
         t0 = _time.perf_counter()
         with _monitor.stage("queue_wait"):
             forensics.RECORDER.batch_begin()
-            pre = [self.preprocess(b) for b in bags]
+            pre = self.preprocess_batch(bags)
         out = list(self._run_check_batch(pre))
         e2e = _time.perf_counter() - t0
-        for _ in bags:
-            _monitor.observe_check_e2e(e2e)
+        _monitor.observe_check_e2e(e2e, len(bags))
         forensics.RECORDER.note_direct(e2e, len(bags))
         return out
 
@@ -1124,10 +1132,9 @@ class RuntimeServer:
         if not isinstance(out, list):   # a ClassedResponses stays one
             out = list(out)
         e2e = _time.perf_counter() - t0
-        real = trim_pads(bags)
-        for _ in real:                 # padding rows carry no caller
-            _monitor.observe_check_e2e(e2e)
-        forensics.RECORDER.note_direct(e2e, len(real))
+        n_real = len(trim_pads(bags))   # padding rows carry no caller
+        _monitor.observe_check_e2e(e2e, n_real)
+        forensics.RECORDER.note_direct(e2e, n_real)
         return out
 
     def submit_report(self, bags: Sequence[Bag]) -> list:
@@ -1363,10 +1370,9 @@ class RuntimeServer:
         forensics.RECORDER.batch_begin()
         out = self._check_batch_quota_instep_inner(bags, qrows, target)
         e2e = _time.perf_counter() - t0
-        real = trim_pads(bags)
-        for _ in real:
-            _monitor.observe_check_e2e(e2e)
-        forensics.RECORDER.note_direct(e2e, len(real))
+        n_real = len(trim_pads(bags))
+        _monitor.observe_check_e2e(e2e, n_real)
+        forensics.RECORDER.note_direct(e2e, n_real)
         return out
 
     def _check_batch_quota_instep_inner(self, bags: Sequence[Bag],

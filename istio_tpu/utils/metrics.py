@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import bisect
 import collections
+import itertools
 import threading
 from typing import Iterable
 
@@ -119,19 +120,21 @@ class Histogram:
     def observe(self, value: float, **labels: str) -> None:
         self.observe_key(_label_key(labels), value)
 
-    def observe_key(self, key: tuple, value: float) -> None:
+    def observe_key(self, key: tuple, value: float, n: int = 1) -> None:
         """observe() for a caller that keeps its label key (the
         sorted (name, value) pairs) — per-batch span sites must not
-        rebuild and sort it on every observation."""
+        rebuild and sort it on every observation. `n`: that many
+        observations of the one value (a batch's rows share its
+        wall), as n calls would count them."""
         idx = bisect.bisect_left(self._buckets, value)
         with self._lock:
             if key not in self._counts:
                 self._counts[key] = [0] * (len(self._buckets) + 1)
                 self._sum[key] = 0.0
                 self._n[key] = 0
-            self._counts[key][idx] += 1
-            self._sum[key] += value
-            self._n[key] += 1
+            self._counts[key][idx] += n
+            self._sum[key] += value * n
+            self._n[key] += n
 
     @property
     def buckets(self) -> tuple[float, ...]:
@@ -206,10 +209,13 @@ class SlidingWindow:
         self._lock = threading.Lock()
         self._total = 0
 
-    def observe(self, value: float) -> None:
+    def observe(self, value: float, n: int = 1) -> None:
+        """`n` observations of the one value: the window holds its
+        capacity at most, so no more copies than that are appended."""
         with self._lock:
-            self._buf.append(value)
-            self._total += 1
+            self._buf.extend(
+                itertools.repeat(value, min(n, self._buf.maxlen)))
+            self._total += n
 
     def __len__(self) -> int:
         with self._lock:

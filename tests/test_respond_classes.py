@@ -22,6 +22,7 @@ import pytest
 from istio_tpu.api.grpc_server import _joined
 from istio_tpu.api.native_server import (_FRAME_CLASS_MIN_ROWS,
                                          NativeMixerServer, _Completions)
+from istio_tpu.api.take import TakenRows
 from istio_tpu.api.wire import LazyWireBag, bag_to_compressed
 from istio_tpu.attribute.global_dict import GLOBAL_MANIFEST
 from istio_tpu.runtime import RuntimeServer, ServerArgs, dispatcher, monitor
@@ -31,6 +32,7 @@ from istio_tpu.runtime.dispatcher import (CheckResponse, ClassedResponses,
 from istio_tpu.runtime.fused import class_int_rows
 from istio_tpu.sharding.router import ShardRouter
 from istio_tpu.testing import workloads
+from istio_tpu.testing.take_blob import encode_take
 
 CONFIGS = Path(__file__).resolve().parent.parent / "benchmark" / "configs"
 ROWS = 256                  # one bucket of every smoke configuration
@@ -153,8 +155,8 @@ def served(request):
         # the process's ledger stays balanced: the rows serialised
         # here, and again for `want`, as requests a front decoded
         monitor.CHECK_REQUESTS.inc(2 * ROWS)
-        checks = [(1000 + row, 0, b"", 0, "", {}, "")
-                  for row in range(ROWS)]
+        checks = TakenRows.read(encode_take(
+            [(1000 + row, 0, b"", 0, "", {}, "") for row in range(ROWS)]))
         completions = _Completions()
         native._serialize_rows(checks, bags, classed, {}, completions,
                                set(), None)
@@ -329,8 +331,9 @@ def test_rows_that_ask_a_quota_keep_their_path(quota_front, asks, memo):
               "two-in-three": [row % 3 != 0 for row in range(ROWS)],
               "ok-rows": ok,
               "one": [row == ok.index(True) for row in range(ROWS)]}[asks]
-    checks = [(1000 + row, 0, b"", 0, "", {"q": (1, True)} if asking[row]
-               else {}, "") for row in range(ROWS)]
+    checks = TakenRows.read(encode_take(
+        [(1000 + row, 0, b"", 0, "", {"q": (1, True)} if asking[row]
+          else {}, "") for row in range(ROWS)]))
     subs_of = {}
     defer = native._defer_quota_row
 
@@ -346,8 +349,9 @@ def test_rows_that_ask_a_quota_keep_their_path(quota_front, asks, memo):
         completions, deferred = _Completions(), set()
         if memo == "warm":
             native._serialize_rows(
-                [(0, 0, b"", 0, "", {}, "")] * ROWS, q.bags, q.classed,
-                {}, _Completions(), set(), None)
+                TakenRows.read(encode_take(
+                    [(0, 0, b"", 0, "", {}, "")] * ROWS)), q.bags,
+                q.classed, {}, _Completions(), set(), None)
             monitor.CHECK_REQUESTS.inc(ROWS)
         monitor.CHECK_REQUESTS.inc(2 * ROWS)
         before = monitor.CHECK_RESPONSES._value.get()
@@ -379,10 +383,11 @@ def test_the_belt_answers_a_row_left_out_beside_one_answered_twice(
     the count of completions; the belt names tags, so the row left out
     still gets its INTERNAL and its client does not hang."""
     native, patch = quota_front.native, pytest.MonkeyPatch()
-    items = [(tag, 1, b"", 0, "", {}, "") for tag in range(2000, 2012)]
+    taken = TakenRows.read(encode_take(
+        [(tag, 1, b"", 0, "", {}, "") for tag in range(2000, 2012)]))
     sent = []
 
-    def inner(items, checks, bags, completions, deferred):
+    def inner(taken, checks, bags, completions, deferred):
         completions.frame(np.arange(2000, 2008, dtype=np.uint64), b"ok")
         completions.extend((tag, 0, b"ok") for tag in (2008, 2009, 2009))
         deferred.add(2010)
@@ -398,11 +403,11 @@ def test_the_belt_answers_a_row_left_out_beside_one_answered_twice(
         assert off == n == len(blob)
 
     try:
-        patch.setattr(native, "_parse_take", lambda raw: items)
+        patch.setattr(native, "_read_take", lambda buf: taken)
         patch.setattr(native, "_run_batch_inner", inner)
         patch.setattr(native, "_lib",
                       types.SimpleNamespace(h2srv_complete=complete))
-        native._run_batch(types.SimpleNamespace(raw=b""), 0)
+        native._run_batch(b"")
     finally:
         patch.undo()
     assert len(sent) == 12          # 11 answers and the belt's one
