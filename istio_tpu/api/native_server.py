@@ -107,6 +107,8 @@ class _Completions(list):
 # must mirror Server::kLatBuckets in httpd.cpp: the wire latency
 # histogram's log-bucket count (bucket i covers ≤ 1µs·2^(i/8))
 _LAT_BUCKETS = 192
+# the order of Server::gaps in httpd.cpp: {count, sum_ns} a kind
+_GAP_KINDS = ("starved", "silent", "io")
 
 
 def _load_lib() -> ctypes.CDLL:
@@ -134,6 +136,9 @@ def _load_lib() -> ctypes.CDLL:
     lib.h2srv_queue_wait.restype = None
     lib.h2srv_queue_wait.argtypes = [ctypes.c_void_p,
                                      ctypes.POINTER(ctypes.c_int64)]
+    lib.h2srv_gaps.restype = None
+    lib.h2srv_gaps.argtypes = [ctypes.c_void_p,
+                               ctypes.POINTER(ctypes.c_int64)]
     lib.h2srv_stop.restype = None
     lib.h2srv_stop.argtypes = [ctypes.c_void_p]
     lib.h2srv_quiesce.restype = None
@@ -190,6 +195,9 @@ class NativeMixerServer(MixerGrpcServer):
         self._final_counters: dict | None = None
         self._final_latency: dict | None = None
         self._final_queue_wait = {"sum_ns": 0, "rows": 0}
+        self._final_gaps = {kind: {"count": 0, "sum_ns": 0}
+                            for kind in _GAP_KINDS}
+        self._watched = False
         # serializes h2srv_complete against stop(): deferred quota
         # completions fire from pool-worker threads and must never
         # race the server teardown into a freed handle
@@ -205,6 +213,8 @@ class NativeMixerServer(MixerGrpcServer):
         # whatever was built since the runtime's constructor (a
         # blocking prewarm, this front) outlives every request
         monitor.settle_heap("start")
+        monitor.pump_watch_start(self.gaps)
+        self._watched = True
         for t in self._pumps:
             t.start()
         if self._tls_certs is not None:
@@ -261,6 +271,10 @@ class NativeMixerServer(MixerGrpcServer):
         self._final_counters = self.counters()
         self._final_latency = self.latency_raw()
         self._final_queue_wait = self.queue_wait()
+        self._final_gaps = self.gaps()
+        if self._watched:
+            self._watched = False
+            monitor.pump_watch_stop(self.gaps)
         if any(t.is_alive() for t in self._pumps):
             # a pump is wedged mid-batch (device stall): freeing the
             # handle under it would turn a stall into a segfault —
@@ -301,6 +315,25 @@ class NativeMixerServer(MixerGrpcServer):
             out = (ctypes.c_int64 * 2)()
             self._lib.h2srv_queue_wait(self._h, out)
         return {"sum_ns": int(out[0]), "rows": int(out[1])}
+
+    def gaps(self) -> dict:
+        """Gaps of 0.2 s or more that the C++ front saw, with no
+        python involved: {"starved" | "silent" | "io": {"count",
+        "sum_ns"}}, cumulative. starved: rows waited in the queue
+        and no pump took them (measured at the handover that ended
+        it); silent: the server had answered everything and the client
+        sent nothing (measured at the enqueue that ended it: the
+        client's stall, not the server's); io: the IO thread itself
+        did not run between two polls. The pump watch
+        (monitor.pump_watch_start) reads it once a tick."""
+        with self._comp_lock:
+            if self._h is None:
+                return self._final_gaps
+            out = (ctypes.c_int64 * 6)()
+            self._lib.h2srv_gaps(self._h, out)
+        return {kind: {"count": int(out[2 * i]),
+                       "sum_ns": int(out[2 * i + 1])}
+                for i, kind in enumerate(_GAP_KINDS)}
 
     # -- wire latency (the measured wire-to-verdict plane) --
 
@@ -461,6 +494,7 @@ class NativeMixerServer(MixerGrpcServer):
         what no span covers is one subtraction (the benchmark's
         pump_unaccounted_ms_per_batch)."""
         take = [ctypes.create_string_buffer(1 << 23)]
+        monitor.pump_enter(self._pumps.index(threading.current_thread()))
         try:
             while True:
                 with monitor.span("pump_cycle"):
@@ -472,6 +506,8 @@ class NativeMixerServer(MixerGrpcServer):
                         log.exception("native pump batch failed")
         except _PumpStopped:
             return
+        finally:
+            monitor.pump_leave()
 
     def _take(self, take: list) -> int:
         """Block in h2srv_take until it hands this pump a batch: empty

@@ -492,6 +492,23 @@ struct Server {
   // ns, [1] rows. Written under `mu` by the taking pump; read by
   // h2srv_queue_wait.
   std::atomic<int64_t> queue_wait[2] = {};
+  // Gaps of kGapNs or more that the front sees with no python running
+  // (h2srv_gaps): three kinds x {count, sum of ns}, cumulative.
+  //  [0..1] starved: at a handover in take_impl, the time since
+  //         first_enq_ns (the first row's arrival, or the previous
+  //         handover that left rows queued): rows waited and no pump
+  //         came. Written under `mu` by the taking pump.
+  //  [2..3] silent: at an enqueue with nothing in flight, the time
+  //         since the last response was handed to a connection
+  //         (last_resp_ns): the server had answered everything and
+  //         the client sent nothing. IO thread only.
+  //  [4..5] io: the time between two returns of io_loop's poll less
+  //         its time-out (kPollMs): the IO thread itself did not run,
+  //         and it holds no python lock. IO thread only.
+  static constexpr int64_t kGapNs = 200 * 1000000LL;
+  static constexpr int kPollMs = 100;   // io_loop's poll time-out
+  std::atomic<int64_t> gaps[6] = {};
+  int64_t last_resp_ns = 0;   // IO thread; 0: no response written yet
   // wire-to-verdict latency histogram: 192 log-spaced buckets, bucket
   // i covers latencies up to 1µs·2^(i/8) (ratio 2^(1/8) ≈ 1.09, so a
   // quantile read interpolates within ±4.5%); covers 1µs .. ~16s.
@@ -589,6 +606,12 @@ void put_data_frames(Conn* c, uint32_t stream_id,
 // draining UNAVAILABLE) answer in microseconds and would drag the
 // served-verdict quantiles toward zero — the histogram's one job is
 // the wire-to-VERDICT number.
+void note_gap(Server* srv, int kind, int64_t ns) {
+  if (ns < Server::kGapNs) return;
+  srv->gaps[2 * kind].fetch_add(1, std::memory_order_relaxed);
+  srv->gaps[2 * kind + 1].fetch_add(ns, std::memory_order_relaxed);
+}
+
 void record_latency(Server* srv, Stream* st) {
   if (!st->t_decode_ns || !st->dispatched) return;
   int64_t ns = mono_ns() - st->t_decode_ns;
@@ -750,6 +773,9 @@ void enqueue_request(Server* srv, Conn* c, uint32_t stream_id,
   item.kind = kind;
   item.traceparent = st->traceparent;
   item.t_enq_ns = mono_ns();
+  if (srv->last_resp_ns &&
+      srv->counters[4].load(std::memory_order_relaxed) == 0)
+    note_gap(srv, 1, item.t_enq_ns - srv->last_resp_ns);
   {
     std::lock_guard<std::mutex> lk(srv->mu);
     if (srv->queue.empty()) srv->first_enq_ns = item.t_enq_ns;
@@ -959,6 +985,7 @@ void close_conn(Server* srv, Conn* c) {
 void io_loop(Server* srv) {
   std::vector<pollfd> pfds;
   std::vector<Conn*> order;
+  int64_t polled_ns = mono_ns();
   while (!srv->stopping.load(std::memory_order_relaxed)) {
     pfds.clear();
     order.clear();
@@ -970,8 +997,11 @@ void io_loop(Server* srv) {
       pfds.push_back({kv.second->fd, ev, 0});
       order.push_back(kv.second);
     }
-    int rc = poll(pfds.data(), pfds.size(), 100);
+    int rc = poll(pfds.data(), pfds.size(), Server::kPollMs);
     if (rc < 0 && errno != EINTR) break;
+    const int64_t now_ns = mono_ns();
+    note_gap(srv, 2, now_ns - polled_ns - Server::kPollMs * 1000000LL);
+    polled_ns = now_ns;
 
     // batch-window wakeups: a pump waiting out a window needs a
     // notify when the window expires even with no IO
@@ -997,6 +1027,7 @@ void io_loop(Server* srv) {
           write_response(srv, it->second, sid, comp.grpc_status,
                          comp.msg);
       }
+      if (!done.empty()) srv->last_resp_ns = mono_ns();
     }
     if (pfds[0].revents & POLLIN) {
       while (true) {
@@ -1346,6 +1377,7 @@ int64_t take_impl(Server* srv, int32_t timeout_ms, uint8_t* buf,
   put(0, head, 8);
   int64_t heap = 8 + static_cast<int64_t>(n) * sizeof(TakeRow);
   const int64_t t_take_ns = mono_ns();
+  note_gap(srv, 0, t_take_ns - srv->first_enq_ns);
   int64_t waited_ns = 0;
   for (int32_t i = 0; i < n; i++) {
     PendingItem& it = srv->queue.front();
@@ -1461,6 +1493,17 @@ void h2srv_queue_wait(void* h, int64_t* out) {
   if (!abi_enter(srv)) return;
   out[0] = srv->queue_wait[0].load(std::memory_order_relaxed);
   out[1] = srv->queue_wait[1].load(std::memory_order_relaxed);
+  abi_exit(srv);
+}
+
+// The front's gaps (Server::gaps): out[6] = starved, silent, io, each
+// {count, sum of ns}; cumulative.
+void h2srv_gaps(void* h, int64_t* out) {
+  Server* srv = static_cast<Server*>(h);
+  memset(out, 0, 6 * sizeof(int64_t));
+  if (!abi_enter(srv)) return;
+  for (int i = 0; i < 6; i++)
+    out[i] = srv->gaps[i].load(std::memory_order_relaxed);
   abi_exit(srv);
 }
 

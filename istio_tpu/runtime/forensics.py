@@ -22,8 +22,12 @@ Three legs, all bounded and lock-light:
     of control-plane events — config publish generations, canary
     verdicts, bank rebuild/reuse, prewarm start/end per shape,
     breaker state transitions, quota flushes, grant revocations,
-    provider refreshes, chaos arms, drains/quiesce — recorded by the
-    planes that own them. Served at /debug/events; every slow-request
+    provider refreshes, chaos arms, drains/quiesce, a device batch's
+    absorbed retry (`device.retry`, with the first exception) and a
+    pump's stall (`pump.stall`: monitor's pump watch, a residence of
+    monitor.STALL_S = 0.2 s or more in one top-level span, with its
+    `cause`: process | lock | client | device | front | host, see
+    monitor.stall_cause) — recorded by the planes that own them. Served at /debug/events; every slow-request
     exemplar is annotated with the events that overlapped its
     lifetime (plus a short pre-window: the breaker that opened 50ms
     before a request explains it), so "why slow" is one HTTP GET.
@@ -453,10 +457,12 @@ def capture_profile(directory: str | None, seconds: float) -> dict:
         _PROFILE_LOCK.release()
 
 
-def thread_stacks() -> dict:
+def thread_stacks(idents=None) -> dict:
     """Every live thread's python stack (sys._current_frames) keyed
     by thread name — the /debug/threads payload. A wedged pump or
-    executor lane names its blocking frame here without gdb."""
+    executor lane names its blocking frame here without gdb.
+    `idents`: only these threads (the pump watch asks for the pumps
+    and the threads that are no daemons)."""
     import sys
     import traceback
 
@@ -465,6 +471,8 @@ def thread_stacks() -> dict:
              for t in threading.enumerate()}
     threads = []
     for ident, frame in frames.items():
+        if idents is not None and ident not in idents:
+            continue
         name, daemon = names.get(ident, (f"unknown-{ident}", None))
         stack = [f"{f.filename}:{f.lineno} {f.name}"
                  + (f" — {f.line.strip()}" if f.line else "")

@@ -15,14 +15,17 @@ from __future__ import annotations
 import collections
 import contextlib
 import gc
+import logging
 import threading
 import time
-from typing import Mapping
+from typing import Callable, Mapping
 
 import prometheus_client
 
 from istio_tpu.utils import metrics as hostmetrics
 from istio_tpu.utils import tracing
+
+log = logging.getLogger("istio_tpu.runtime.monitor")
 
 REGISTRY = prometheus_client.CollectorRegistry()
 
@@ -626,6 +629,10 @@ GC_PAUSE_SECONDS = hostmetrics.default_registry.histogram(
     "mixer_gc_pause_seconds",
     "stop-the-world wall of full (generation 2) garbage collections "
     "of the serving process")
+GC_YOUNG_SECONDS = hostmetrics.default_registry.histogram(
+    "mixer_gc_young_seconds",
+    "wall of young (generation 0 and 1) garbage collections of the "
+    "serving process")
 GC_FROZEN_OBJECTS = hostmetrics.default_registry.gauge(
     "mixer_gc_frozen_objects",
     "objects in the collector's permanent generation after the last "
@@ -715,8 +722,43 @@ _OFF = _Off()
 _SPAN_META: dict = {}
 
 
+class _PumpLocal(threading.local):
+    slot = None     # what a thread that is no pump reads
+
+
+class _PumpSlot:
+    """One pump's open spans, for the pump watch below: `open` is the
+    innermost one as (name, t0, the entry it is nested in), None
+    between spans. Written by the pump alone, read by the watch."""
+    __slots__ = ("pump", "thread", "open")
+
+    def __init__(self, pump: int):
+        self.pump = pump
+        self.thread = threading.current_thread()
+        self.open = None
+
+
+_PUMP = _PumpLocal()
+_PUMP_SLOTS: dict[int, _PumpSlot] = {}      # by thread ident
+
+
+def pump_enter(pump: int) -> None:
+    """The calling thread is pump number `pump` of a native front from
+    here to pump_leave(): its spans write their name and start into a
+    slot the pump watch reads while they are still open."""
+    slot = _PUMP.slot = _PumpSlot(pump)
+    _PUMP_SLOTS[slot.thread.ident] = slot
+
+
+def pump_leave() -> None:
+    slot, _PUMP.slot = _PUMP.slot, None
+    if slot is not None:
+        _PUMP_SLOTS.pop(slot.thread.ident, None)
+
+
 class _Span:
-    __slots__ = ("_meta", "_tags", "_ann", "_t0", "seconds")
+    __slots__ = ("_meta", "_tags", "_ann", "_t0", "_slot", "_outer",
+                 "seconds")
 
     def __init__(self, meta: tuple, tags: dict):
         self._meta = meta
@@ -726,7 +768,11 @@ class _Span:
     def __enter__(self):
         self._ann = _trace_annotation(self._meta[2])
         self._ann.__enter__()
-        self._t0 = time.perf_counter()
+        t0 = self._t0 = time.perf_counter()
+        slot = self._slot = _PUMP.slot
+        if slot is not None:
+            outer = self._outer = slot.open
+            slot.open = (self._meta[3], t0, outer)
         return self
 
     def tag(self, **tags) -> None:
@@ -735,6 +781,8 @@ class _Span:
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         seconds = self.seconds = time.perf_counter() - self._t0
+        if self._slot is not None:
+            self._slot.open = self._outer
         self._ann.__exit__(exc_type, exc, tb)
         hist, key, _, name, tap, zipkin = self._meta
         if exc_type is None:
@@ -812,6 +860,7 @@ def span(name: str, on: bool = True, tap: bool = False, **tags):
 _GC_LOCK = threading.Lock()
 _GC_USERS = 0
 _GC_OPEN: list = []     # [(annotation, t0)] of the collection running
+_GC_YOUNG_T0 = 0.0      # start of the young collection running
 # the rows in flight alone cost a full collection 2-5 ms; at about two
 # collections a second this bounds what is left at ~2 % of the wall
 RESETTLE_PAUSE_S = 0.010
@@ -819,6 +868,15 @@ RESETTLE_PAUSE_S = 0.010
 
 def _on_gc(phase: str, info: dict) -> None:
     if info["generation"] != 2:
+        # a young collection: its wall and nothing else (some 300 a
+        # second in a deep cell). Collections do not nest: one stamp
+        global _GC_YOUNG_T0
+        if phase == "start":
+            _GC_YOUNG_T0 = time.perf_counter()
+        elif _GC_YOUNG_T0:
+            GC_YOUNG_SECONDS.observe_key(
+                (), time.perf_counter() - _GC_YOUNG_T0)
+            _GC_YOUNG_T0 = 0.0
         return
     if phase == "start":
         ann = _trace_annotation(_ANNOTATION_PREFIX + "gc")
@@ -903,17 +961,362 @@ def _settle_locked(at: str, reclaim: bool = False,
 
 def gc_pause_snapshot(since: dict | None = None) -> dict:
     """Full collections seen by the hook ({"count", "sum_s"}, or the
-    delta against an earlier snapshot `since`), and the heap as the
-    last settle at a named site left it: "frozen" (objects in the
-    permanent generation, 0 when not frozen; the hook's own settles
-    add to them uncounted) and "settles" ({at: calls})."""
+    delta against an earlier snapshot `since`), the young ones under
+    "young" (the same two keys), and the heap as the last settle at a
+    named site left it: "frozen" (objects in the permanent generation,
+    0 when not frozen; the hook's own settles add to them uncounted)
+    and "settles" ({at: calls})."""
     _, total, n = GC_PAUSE_SECONDS.state()
+    _, young_total, young_n = GC_YOUNG_SECONDS.state()
     if since is not None:
         total, n = total - since["sum_s"], n - since["count"]
+        young = since.get("young", {"count": 0, "sum_s": 0.0})
+        young_total -= young["sum_s"]
+        young_n -= young["count"]
     return {"count": n, "sum_s": total,
+            "young": {"count": young_n, "sum_s": young_total},
             "frozen": int(GC_FROZEN_OBJECTS.value()),
             "settles": {at: int(HEAP_SETTLES.value(at=at))
                         for at in HEAP_SETTLE_SITES}}
+
+
+# -- the pump watch: a stall that no closed span can hold --------------
+#
+# A histogram of closed spans keeps a sum and a count: one residence of
+# 2 s disappears into 1 900 ordinary ones, and a span that never closes
+# is not there at all. Three clocks that do not need each other see it
+# while it lasts, all on CLOCK_MONOTONIC (time.perf_counter() here is
+# h2_frame.h:mono_ns() in the C++ front):
+#   * each pump's open spans, in a slot (_PumpSlot; _Span writes it):
+#     a pump whose top-level span is older than STALL_S is stalled.
+#     Not so in take_wait, whose residence has no bound by design: a
+#     pump with nothing to take is idle. There the front's gaps decide;
+#   * a heartbeat (thread mixer-pump-watch, one a process while a
+#     native front serves): it sleeps _TICK_S with the interpreter lock
+#     released and observes how late it woke into
+#     mixer_lock_wait_seconds: the wait of a ready thread for the lock.
+#     A wake STALL_S late means nobody ran python for that long: the
+#     first thing it does then is take every thread's stack, the
+#     holder's as it stands just after it let go. (faulthandler's
+#     watchdog would write them DURING the stall, with no lock, and
+#     that is why it is not used: its walk of the thread list races
+#     with threads that start and end, and a server's do: two of two
+#     runs on the chip died of it, PERF.md PR 37.)
+#   * the C++ front's gap counters (NativeMixerServer.gaps()): rows
+#     that waited for a pump, a client that sent nothing, an IO thread
+#     that did not run. The watch reads them once a tick, so a gap is
+#     dated to the tick after it ended.
+# When a stall closes the watch joins them into ONE forensics event
+# "pump.stall" with a cause (stall_cause), one log line, and an
+# observation of mixer_pump_stall_seconds{span}.
+
+STALL_S = 0.2
+_TICK_S = 0.05
+# the ten spans that tile a pump's cycle
+PUMP_TOP_LEVEL = ("take_wait", "wire_decode") + CHECK_STAGES + (
+    "serialize", "send")
+
+LOCK_WAIT_SECONDS = hostmetrics.default_registry.histogram(
+    "mixer_lock_wait_seconds",
+    "how late the pump watch's heartbeat woke from its sleep: the wait "
+    "of a ready thread for the interpreter lock")
+PUMP_STALL_SECONDS = hostmetrics.default_registry.histogram(
+    "mixer_pump_stall_seconds",
+    "residences of a pump in one top-level span that passed the stall "
+    "threshold, whole; under take_wait the seconds rows waited or the "
+    "client was silent, not the idle residence (label: span)")
+for _name in PUMP_TOP_LEVEL:    # zero-series before the first stall
+    PUMP_STALL_SECONDS.observe_key((("span", _name),), 0.0, 0)
+
+# (wake time, lateness) of the last heartbeats (100 s of them): the
+# beats that overlap a stall, and the exact maximum since a snapshot,
+# which histogram buckets cannot give
+_BEATS: collections.deque = collections.deque(maxlen=2048)
+_STACK_FRAMES = 12      # innermost frames kept of a thread
+_STACK_THREADS = 24
+
+
+def stall_cause(span: str | None, lock_late_s: float, io_s: float,
+                silent_s: float, starved_s: float,
+                taking: bool) -> str:
+    """Why a pump sat in `span` past STALL_S, first row that holds.
+    `lock_late_s`: the heartbeat's largest lateness over the stall;
+    `io_s` / `silent_s` / `starved_s`: the C++ front's gaps over it;
+    `taking`: a pump was in take_wait."""
+    if lock_late_s >= STALL_S:
+        # nobody ran python. The IO thread needs no lock: if it stood
+        # still too, the process was not scheduled (steal, compaction,
+        # a stopped process); else a thread held the lock: the stacks
+        # show where it stood as it let go
+        return "process" if io_s > 0 else "lock"
+    if span == "take_wait" and silent_s > 0:
+        return "client"     # everything answered, nothing sent
+    if span in ("h2d", "device_step"):
+        return "device"     # retry and compile deltas say which
+    if span == "send" or (starved_s > 0 and taking):
+        return "front"      # rows waited while a pump asked for them
+    return "host"   # python with the lock on offer: faults, a blocking call
+
+
+def _open_spans(entry) -> tuple:
+    """(the top-level entry, the innermost name) of a slot's chain."""
+    inner = entry[0] if entry is not None else None
+    top = None
+    while entry is not None:
+        if entry[0] in PUMP_TOP_LEVEL:
+            top = entry
+        entry = entry[2]
+    return top, inner
+
+
+def _live_stacks(everyone: bool = False) -> list:
+    """[{"thread", "frames"}], innermost frame first, of the pumps and
+    the threads that are no daemons; of `everyone` after a late wake,
+    when any thread may have been the holder."""
+    from istio_tpu.runtime import forensics
+
+    idents = None if everyone else set(_PUMP_SLOTS) | {
+        t.ident for t in threading.enumerate() if not t.daemon}
+    return [{"thread": t["name"],
+             "frames": t["stack"][::-1][:_STACK_FRAMES]}
+            for t in forensics.thread_stacks(idents)["threads"]
+            ][:_STACK_THREADS]
+
+
+class _PumpWatch:
+    """The heartbeat thread and what it keeps between ticks."""
+
+    def __init__(self):
+        self.users = 0
+        self.fronts: tuple = ()     # the gaps() of the fronts that serve
+        self._stop = threading.Event()
+        # (time, counters) of the last ticks: what a stall's deltas are
+        # taken against, back to before it began (6 s at 50 ms)
+        self._marks: collections.deque = collections.deque(maxlen=128)
+        # (wake time, every thread's stack) of the ticks that woke late
+        self._late_stacks: collections.deque = collections.deque(maxlen=16)
+        from istio_tpu.compiler import cache as compile_cache
+        self._compiles = compile_cache.cache_event_counts
+        self._stalls: dict = {}     # slot -> what detection saw
+        self._spans: dict = {}      # slot -> (top, inner) a tick ago
+        self._ann = None
+        self._thread = threading.Thread(
+            target=self._run, name="mixer-pump-watch", daemon=True)
+        self._thread.start()
+
+    def close(self) -> None:
+        self._stop.set()
+        self._thread.join()
+
+    def _run(self) -> None:
+        try:
+            while True:
+                asleep = time.perf_counter()
+                if self._stop.wait(_TICK_S):
+                    return
+                now = time.perf_counter()
+                late = max(now - asleep - _TICK_S, 0.0)
+                # first, before anybody moves on: where the pumps are
+                # and, after a late wake, where every thread stands
+                # (reading source lines lends the lock)
+                spans = {slot: _open_spans(slot.open)
+                         for slot in list(_PUMP_SLOTS.values())}
+                if late >= STALL_S:
+                    self._late_stacks.append((now, _live_stacks(True)))
+                LOCK_WAIT_SECONDS.observe_key((), late)
+                _BEATS.append((now, late))
+                try:
+                    self._tick(now, late, spans)
+                except Exception:   # the watch observes: it never ends
+                    log.exception("pump watch tick failed")
+        finally:
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
+
+    def _counters(self) -> dict:
+        full, young = GC_PAUSE_SECONDS.state(), GC_YOUNG_SECONDS.state()
+        compiles = self._compiles()
+        out = {"gc_full": full[2], "gc_full_s": full[1],
+               "gc_young": young[2], "gc_young_s": young[1],
+               "cache_hits": compiles["hits"],
+               "cache_misses": compiles["misses"],
+               "device_retries": int(CHECK_DEVICE_RETRIES._value.get()),
+               "starved_s": 0.0, "silent_s": 0.0, "io_s": 0.0}
+        for gaps in self.fronts:
+            for kind, gap in gaps().items():
+                out[kind + "_s"] += gap["sum_ns"] / 1e9
+        return out
+
+    @staticmethod
+    def _seen(slot, top, inner, spans: dict, now: float,
+              stacks: tuple = ()) -> dict:
+        """What a stall's event says of the tick that detected it."""
+        return {"top": top, "pump": slot.pump if slot else None,
+                "nested": inner, "stacks": list(stacks),
+                "others": [{"pump": other.pump, "span": t[0],
+                            "age_s": max(now - t[1], 0.0)}
+                           for other, (t, _) in spans.items()
+                           if other is not slot and t is not None]}
+
+    def _tick(self, now: float, late: float, spans: dict) -> None:
+        counters = self._counters()
+        before = self._marks[-1][1] if self._marks else counters
+        self._marks.append((now, counters))
+        was, self._spans = self._spans, spans
+        ended = []
+        for slot, seen in list(self._stalls.items()):
+            if spans.get(slot, (None, None))[0] is not seen["top"]:
+                ended.append(self._stalls.pop(slot))
+        for slot, (top, inner) in spans.items():
+            if top is not None and top[0] != "take_wait" \
+                    and slot not in self._stalls \
+                    and now - top[1] >= STALL_S:
+                # a late tick has taken everyone's stacks already
+                self._stalls[slot] = self._seen(
+                    slot, top, inner, spans, now,
+                    () if late >= STALL_S else _live_stacks())
+        # take_wait: not its age but a gap of the front's that ended
+        # since the last tick, for the gap's own seconds. A silence:
+        # every pump was idle. Rows that waited: only if no other
+        # span's stall tells of them and a pump sat in take_wait all
+        # the while (handed rows it could not come back with, or never
+        # woken); pumps away in one short span after another are no
+        # stall of any span (the front's `starved` counter has them)
+        silent_s = counters["silent_s"] - before["silent_s"]
+        starved_s = counters["starved_s"] - before["starved_s"]
+        if silent_s > 0:
+            ended.append(self._seen(None, ("take_wait", now - silent_s),
+                                    None, spans, now))
+        if starved_s > 0 and not ended and not self._stalls:
+            for slot, (top, inner) in was.items():
+                if top is not None and top[0] == "take_wait" \
+                        and top[1] <= now - starved_s:
+                    ended.append(self._seen(
+                        slot, ("take_wait", now - starved_s), inner,
+                        spans, now))
+                    break
+        for seen in ended:
+            self._report(seen, now, counters)
+        if late >= STALL_S and not ended and not self._stalls:
+            # nobody ran python for that long, and no pump was in a
+            # span old enough to say so
+            self._report(self._seen(None, (None, now - late), None,
+                                    spans, now), now, counters)
+        if self._stalls and self._ann is None:
+            self._ann = _trace_annotation(_ANNOTATION_PREFIX + "stall")
+            self._ann.__enter__()
+        elif not self._stalls and self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+
+    def _report(self, seen: dict, now: float, counters: dict) -> None:
+        from istio_tpu.runtime import forensics
+
+        span, t0 = seen["top"][:2]
+        base = next((c for t, c in reversed(self._marks) if t <= t0),
+                    self._marks[0][1])
+        moved = {k: max(v - base[k], 0) for k, v in counters.items()}
+        # the heartbeat that woke latest of those that overlap, and the
+        # stacks it took as it woke
+        lock_late, at = max(((late, t) for t, late in tuple(_BEATS)
+                             if t >= t0 and t - late <= now),
+                            default=(0.0, None))
+        stacks = dict(self._late_stacks).get(at, seen["stacks"])
+        taking = span == "take_wait" or any(
+            other["span"] == "take_wait" for other in seen["others"])
+        cause = stall_cause(span, lock_late, moved["io_s"],
+                            moved["silent_s"], moved["starved_s"], taking)
+        seconds = now - t0
+        if span is not None:
+            PUMP_STALL_SECONDS.observe_key((("span", span),), seconds)
+        detail = {"cause": cause, "span": span, "seconds": seconds,
+                  "lock_late_s": lock_late, **moved}
+        if cause == "client":
+            # a quiet front reads the same as a client that froze: a
+            # short, all-numeric detail folds its events into one
+            # entry, so they cannot push a publish out of the ring;
+            # and no warning for what may be a quiet night
+            forensics.record_event("pump.stall", coalesce_s=60.0, **detail)
+            log.info("pump.stall cause=client span=take_wait "
+                     "seconds=%.3f silent_s=%.3f", seconds,
+                     moved["silent_s"])
+            return
+        forensics.record_event(
+            "pump.stall", pump=seen["pump"], nested=seen["nested"],
+            t0_ns=int(t0 * 1e9), t1_ns=int(now * 1e9),
+            others=seen["others"], stacks=stacks, **detail)
+        log.warning(
+            "pump.stall cause=%s pump=%s span=%s nested=%s seconds=%.3f "
+            "lock_late_s=%.3f starved_s=%.3f silent_s=%.3f io_s=%.3f "
+            "gc_full_s=%.3f device_retries=%d compiles=%d",
+            cause, seen["pump"], span, seen["nested"], seconds, lock_late,
+            moved["starved_s"], moved["silent_s"], moved["io_s"],
+            moved["gc_full_s"], moved["device_retries"],
+            moved["cache_hits"] + moved["cache_misses"])
+
+
+_WATCH_LOCK = threading.Lock()
+_WATCH: _PumpWatch | None = None
+
+
+def pump_watch_start(gaps: Callable[[], dict] | None = None) -> None:
+    """A native front starts serving: the watch runs from the first
+    such call to the last pump_watch_stop (tests run several servers).
+    `gaps`: the front's NativeMixerServer.gaps, read once a tick."""
+    global _WATCH
+    with _WATCH_LOCK:
+        if _WATCH is None:
+            _WATCH = _PumpWatch()
+        _WATCH.users += 1
+        if gaps is not None:
+            _WATCH.fronts += (gaps,)
+
+
+def pump_watch_stop(gaps: Callable[[], dict] | None = None) -> None:
+    """The last one joins the thread."""
+    global _WATCH
+    with _WATCH_LOCK:
+        watch = _WATCH
+        if watch is None:
+            return
+        watch.fronts = tuple(g for g in watch.fronts if g != gaps)
+        watch.users -= 1
+        if not watch.users:
+            _WATCH = None
+            watch.close()
+
+
+def pump_watch_snapshot(since: dict | None = None) -> dict:
+    """What the watch has seen, or the delta against an earlier
+    snapshot `since`: {"lock_wait": {"count", "sum_s", "max_s"} (the
+    heartbeat's lateness; `max_s` of the last 2 048 beats at most),
+    "stalls": {span: {"count", "sum_s"}} (mixer_pump_stall_seconds),
+    "events": the pump.stall events' details, oldest first (`n` > 1: a
+    quiet front's, folded)}."""
+    from istio_tpu.runtime import forensics
+
+    t0 = since["t"] if since is not None else 0.0
+    now = time.perf_counter()
+    _, total, n = LOCK_WAIT_SECONDS.state()
+    stalls = {}
+    for name in PUMP_TOP_LEVEL:
+        _, span_total, span_n = PUMP_STALL_SECONDS.state(span=name)
+        stalls[name] = {"count": span_n, "sum_s": span_total}
+    if since is not None:
+        total -= since["lock_wait"]["sum_s"]
+        n -= since["lock_wait"]["count"]
+        for name, was in since["stalls"].items():
+            stalls[name]["count"] -= was["count"]
+            stalls[name]["sum_s"] -= was["sum_s"]
+    return {
+        "t": now,
+        "lock_wait": {"count": n, "sum_s": total,
+                      "max_s": max((late for t, late in tuple(_BEATS)
+                                    if t > t0), default=0.0)},
+        "stalls": stalls,
+        "events": [dict(e["detail"], n=e["n"])
+                   for e in forensics.EVENTS.snapshot("pump.stall", limit=0)
+                   if e["t"] > t0]}
 
 
 def observe_check_e2e(seconds: float, n: int = 1) -> None:

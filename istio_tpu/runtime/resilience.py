@@ -482,7 +482,7 @@ class ResilientChecker:
 
     def run_batch(self, bags: Sequence[Any],
                   deadline: float | None = None) -> Sequence[Any]:
-        from istio_tpu.runtime import monitor
+        from istio_tpu.runtime import forensics, monitor
 
         if not self.breaker.allow_device():
             return self._fallback(bags, "breaker_open",
@@ -514,6 +514,14 @@ class ResilientChecker:
                     except Exception as exc2:
                         first = exc2
                     else:
+                        # absorbed: say what it was (a pump.stall
+                        # event's retry delta has this text behind it)
+                        log.warning("device check batch failed once "
+                                    "(%s: %s); the retry succeeded",
+                                    type(first).__name__, first)
+                        forensics.record_event(
+                            "device.retry",
+                            error=f"{type(first).__name__}: {first}")
                         self.breaker.record_success()
                         recorded = True
                         return out

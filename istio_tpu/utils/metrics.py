@@ -115,7 +115,11 @@ class Histogram:
         self._counts: dict[tuple, list[int]] = {}
         self._sum: dict[tuple, float] = {}
         self._n: dict[tuple, int] = {}
-        self._lock = threading.Lock()
+        # reentrant: a gc callback observes (monitor._on_gc), and a
+        # collection can start on a thread that is inside this
+        # histogram's own state(): a plain lock would have that thread
+        # wait for itself
+        self._lock = threading.RLock()
 
     def observe(self, value: float, **labels: str) -> None:
         self.observe_key(_label_key(labels), value)

@@ -127,6 +127,27 @@ def test_a_long_full_collection_freezes_what_survived_it(
     assert gc.get_freeze_count() == 0
 
 
+@pytest.mark.parametrize("generation", [0, 1])
+def test_a_young_collection_moves_the_young_histogram_alone(own_heap,
+                                                            generation):
+    monitor.install_gc_hook()
+    try:
+        before = monitor.gc_pause_snapshot()
+        gc.collect(generation)
+        moved = monitor.gc_pause_snapshot(since=before)
+        assert moved["young"]["count"] >= 1     # others may run beside
+        assert moved["young"]["sum_s"] > 0.0
+        assert moved["count"] == 0 and moved["sum_s"] == 0.0
+        assert monitor.GC_PAUSE_SECONDS.state()[2] == before["count"]
+        gc.collect()
+        both = monitor.gc_pause_snapshot(since=before)
+        assert both["count"] == 1
+        # the keys gc_pause_share and gc_frozen_objects read are there
+        assert {"count", "sum_s", "frozen", "settles"} <= set(both)
+    finally:
+        monitor.remove_gc_hook()
+
+
 def test_settles_from_many_threads_lose_no_garbage_and_do_not_deadlock(
         own_heap, monkeypatch):
     # the hook settles on whichever thread's allocation set a full

@@ -92,6 +92,41 @@ def test_retry_absorbs_transient_device_fault():
     assert int(monitor.CHECK_DEVICE_RETRIES._value.get()) == before + 1
 
 
+def test_an_absorbed_device_fault_is_logged_and_recorded():
+    """The retry that succeeds says what it absorbed: one device.retry
+    event and one log record with the first exception's type."""
+    import logging
+
+    from istio_tpu.runtime import forensics
+
+    records = []
+    handler = logging.Handler(level=logging.WARNING)
+    handler.emit = records.append
+    logger = logging.getLogger("istio_tpu.runtime.resilience")
+
+    def device(bags):
+        CHAOS.device_step()
+        return ["dev"] * len(bags)
+
+    rc = ResilientChecker(device, lambda bags: ["oracle"] * len(bags),
+                          config=_fast_config())
+    CHAOS.device_failures = 1
+    seen = len(forensics.EVENTS.snapshot("device.retry", limit=0))
+    logger.addHandler(handler)
+    try:
+        assert rc.run_batch(["a", "b"]) == ["dev", "dev"]
+        assert rc.run_batch(["c"]) == ["dev"]       # a clean batch: silent
+    finally:
+        logger.removeHandler(handler)
+    events = forensics.EVENTS.snapshot("device.retry", limit=0)[seen:]
+    assert [e["detail"]["error"] for e in events] == [
+        "RuntimeError: chaos: injected device-step failure"]
+    (record,) = records
+    assert "RuntimeError" in record.getMessage()
+    assert "retry succeeded" in record.getMessage()
+    assert rc.breaker.state == "closed"
+
+
 def test_double_failure_falls_back_to_oracle_and_counts():
     from istio_tpu.runtime.batcher import trim_pads
 
