@@ -1,4 +1,5 @@
-"""On-demand build of the native shim (protoc --cpp_out + g++).
+"""On-demand build of the native libraries (g++; the shim reads the
+wire itself and links no protobuf).
 
 Build artifacts live in _build/ which is NOT under version control
 (reviewable source only — a committed binary can't be audited);
@@ -19,7 +20,6 @@ _HTTPD_SO = os.path.join(_BUILD, "libmixer_httpd.so")
 _HTTPD_HASH = os.path.join(_BUILD, ".httpd_srchash")
 _H2LOAD = os.path.join(_BUILD, "h2load")
 _H2LOAD_HASH = os.path.join(_BUILD, ".h2load_srchash")
-_PROTO_DIR = os.path.join(_DIR, "..", "api", "proto")
 _lock = threading.Lock()
 
 
@@ -33,38 +33,6 @@ def _source_hash(*paths: str) -> str:
         with open(p, "rb") as f:
             h.update(f.read())
     return h.hexdigest()
-
-
-def ensure_built() -> str:
-    """Compile (once) and return the shared-library path."""
-    src = os.path.join(_DIR, "shim.cpp")
-    proto_src = os.path.join(_PROTO_DIR, "mixer.proto")
-    want = _source_hash(src, proto_src)
-    with _lock:
-        if os.path.exists(_SO) and os.path.exists(_HASH):
-            with open(_HASH, encoding="ascii") as f:
-                if f.read().strip() == want:
-                    return _SO
-        os.makedirs(_BUILD, exist_ok=True)
-        try:
-            subprocess.run(
-                ["protoc", f"-I{_PROTO_DIR}", "-I/usr/include",
-                 f"--cpp_out={_BUILD}", proto_src],
-                check=True, capture_output=True, text=True)
-            subprocess.run(
-                ["g++", "-O2", "-fPIC", "-shared", "-std=c++17",
-                 f"-I{_BUILD}", src,
-                 os.path.join(_BUILD, "mixer.pb.cc"),
-                 "-lprotobuf", "-o", _SO],
-                check=True, capture_output=True, text=True)
-        except subprocess.CalledProcessError as exc:
-            raise NativeBuildError(
-                f"native shim build failed:\n{exc.stderr}") from exc
-        except FileNotFoundError as exc:
-            raise NativeBuildError(f"toolchain missing: {exc}") from exc
-        with open(_HASH, "w", encoding="ascii") as f:
-            f.write(want + "\n")
-        return _SO
 
 
 def _build_one(srcs: list[str], out: str, hash_path: str,
@@ -91,6 +59,12 @@ def _build_one(srcs: list[str], out: str, hash_path: str,
         with open(hash_path, "w", encoding="ascii") as f:
             f.write(want + "\n")
         return out
+
+
+def ensure_built() -> str:
+    """Compile the wire→tensor shim (shim.cpp) → .so path."""
+    return _build_one([os.path.join(_DIR, "shim.cpp")], _SO, _HASH,
+                      ["-fPIC", "-shared"])
 
 
 def ensure_httpd_built() -> str:

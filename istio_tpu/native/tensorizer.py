@@ -3,9 +3,13 @@
 Drop-in accelerated replacement for compiler/layout.Tensorizer on the
 serving path: input is serialized istio.mixer.v1.CompressedAttributes
 records (what Check RPCs carry), output is the same AttributeBatch the
-device step consumes. The shim owns the authoritative intern table; new
-entries are mirrored back into the Python InternTable after every batch
-(so compiled constants and verdict decode stay consistent).
+device step consumes; the python Tensorizer stays the conformance
+oracle. The shim reads each record where it lies (no protobuf message,
+no copy of a word or a value it has seen before) and owns the
+authoritative intern table of runtime values: compile-time constants
+seed it in the python InternTable's id order, and what a batch adds is
+exported after the batch into `_runtime_values` under negative
+per-batch ids, never into the InternTable.
 """
 from __future__ import annotations
 
@@ -128,6 +132,8 @@ class NativeTensorizer:
     def __init__(self, layout: BatchLayout, interner: InternTable,
                  staging_depth: int = 8):
         import threading
+        from istio_tpu.runtime import monitor   # lazy: runtime imports us
+        self._monitor = monitor
         self.layout = layout
         self.interner = interner
         self.staging_depth = max(int(staging_depth), 2)
@@ -196,7 +202,8 @@ class NativeTensorizer:
         (int64) bytes at `offsets[i]` (uint64); an empty row is a
         padding row. The pointer array is one numpy add, laid out as
         the shim's `const uint8_t* const*`; the caller keeps the
-        buffer alive and unwritten for the call."""
+        buffer alive and unwritten for the call (the shim reads words
+        and values in place)."""
         ptrs = offsets + np.uint64(base)
         return self._tensorize(
             len(ptrs),
@@ -204,12 +211,18 @@ class NativeTensorizer:
             lengths.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)))
 
     def _tensorize(self, n: int, bufs, lens) -> AttributeBatch:
-        # one decode at a time: the shim handle's intern table and the
-        # remap array are shared mutable state (pipelined batches may
-        # arrive concurrently from the batcher pool) — and the lock is
-        # what makes the staging-ring rotation race-free
-        with self._call_lock:
+        # one decode at a time. The lock guards the shim handle (its
+        # intern table and the scratch it reads a record into), the
+        # remap array and `_runtime_values` that follow the table, and
+        # the staging-ring rotation. Both pumps (and the batcher pool's
+        # pipelined batches) share this tensorizer, so they queue here:
+        # span `tensorize.call_wait` is that wait and nothing else
+        with self._monitor.span("tensorize.call_wait"):
+            self._call_lock.acquire()
+        try:
             return self._tensorize_locked(n, bufs, lens)
+        finally:
+            self._call_lock.release()
 
     @staticmethod
     def _aligned_zeros(shape: tuple, dtype) -> np.ndarray:

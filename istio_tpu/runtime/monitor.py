@@ -575,7 +575,10 @@ def identity_counters() -> dict:
 #   tensorize   — wire bytes / bags -> AttributeBatch, host side, AND
 #                 (native wire path, overlap_h2d) the one explicit
 #                 device_put of the byte plane, AND the ns ids; split
-#                 by the spans tensorize.decode / .stage_put / .ns_ids
+#                 by the spans tensorize.decode / .stage_put / .ns_ids;
+#                 inside .decode, tensorize.call_wait is the wait for
+#                 the tensorizer's one call lock (both pumps decode
+#                 through one NativeTensorizer)
 #   h2d         — misnamed, kept for its readers: the LAUNCH of the
 #                 batch's device program(s) with the implicit transfer
 #                 of every jit argument. One program (span
