@@ -144,32 +144,32 @@ def test_roofline_reader_takes_the_sizes_of_the_served_snapshot():
 
 
 def test_the_manifest_gained_one_deployment_and_lost_nothing():
+    """What PR 35 added is there and as it was added: the deployment,
+    its cell, its five readers and the cell's name on the metrics it
+    joined. What later PRs add or take away is theirs to pin."""
     manifest = json.loads((ROOT / "BENCHMARK.json").read_text())
-    assert [c["name"] for c in manifest["configs"]] == [
-        "mixer10k", "rbac1k", "fullmesh5k", "routematch10k", "routelong10k"]
-    assert [w["name"] for w in manifest["workloads"]] == OLD_CELLS + [CELL]
-    assert manifest["workloads"][-1] == {
+    configs = {c["name"]: c for c in manifest["configs"]}
+    assert configs["routelong10k"]["file"] == \
+        "benchmark/configs/routelong10k.json"
+    assert configs["routelong10k"]["reduced"] == ["route_selection"]
+    cells = {w["name"]: w for w in manifest["workloads"]}
+    assert set(OLD_CELLS) <= set(cells)
+    assert cells[CELL] == {
         "name": CELL, "config": "routelong10k", "traffic": "check-deep",
-        "chips": 1, "why": manifest["workloads"][-1]["why"]}
-    assert manifest["configs"][-1]["reduced"] == ["route_selection"]
+        "chips": 1, "why": cells[CELL]["why"]}
     assert manifest["run_seconds"] == 50
-    assert [(m["name"], m["bound"]) for m in manifest["end_to_end"]] == [
-        ("check_rate", 0.12), ("check_p50_ms", 0.08),
-        ("check_p99_ms", 0.12), ("setup_s", 0.25)]
-    per_layer = manifest["per_layer"]
-    assert len(per_layer) == 38 + len(READERS)
-    assert [m["name"] for m in per_layer[-5:]] == list(READERS)
-    for m in per_layer[-5:]:
+    bounds = {m["name"]: m["bound"] for m in manifest["end_to_end"]}
+    assert (bounds["check_p99_ms"], bounds["setup_s"]) == (0.12, 0.25)
+    # loosened in PR 39 (PERF.md 2): 0.12 and 0.08 until then
+    assert (bounds["check_rate"], bounds["check_p50_ms"]) == (0.22, 0.16)
+    per_layer = {m["name"]: m for m in manifest["per_layer"]}
+    for name in READERS:
+        m = per_layer[name]
         assert m["workloads"] == [CELL] and m["moves"] == "check_rate"
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
-    for m in per_layer[:-5]:
-        cells = m.get("workloads")
-        if m["name"] in APPENDED:
-            assert cells[-1] == CELL
-            assert cells[:-1] == [c for c in OLD_CELLS if c in cells]
-        else:
-            assert cells is None or CELL not in cells, m["name"]
+    for name in APPENDED:
+        assert CELL in per_layer[name]["workloads"], name
 
 
 def test_the_new_cell_resolves_to_its_files():
@@ -188,8 +188,7 @@ def test_the_new_cell_resolves_to_its_files():
         assert cell.sizes[key] == other[key], key
     names = {m["name"] for m in cell.per_layer}
     assert set(READERS) | set(APPENDED) <= names
-    assert not {"dfa_roofline_share", "dispatch_rulestats_ms_per_batch",
-                "dispatch_pack_ms_per_batch", "device_rbac_ms"} & names
+    assert not {"dfa_roofline_share", "device_rbac_ms"} & names
     assert [m["name"] for m in cell.end_to_end] == ["check_rate", "setup_s"]
     for name in READERS:
         assert callable(reader(name).read)

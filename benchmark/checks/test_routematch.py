@@ -133,9 +133,6 @@ def test_new_manifest_entries_resolve():
     assert set(READERS) <= set(names)
     # it runs the wire front under the deep mix and the `lists` section
     assert {"wire_p99_ms.deep", "device_lists_ms"} <= set(names)
-    # the two readers that read nothing since PR 32 are not asked of it
-    assert not {"dispatch_rulestats_ms_per_batch",
-                "dispatch_pack_ms_per_batch"} & set(names)
     assert [m["name"] for m in cell.end_to_end] == ["check_rate", "setup_s"]
     for other in ("mixer10k-check-deep", "fullmesh5k-check-deep"):
         assert not set(READERS) & {

@@ -15,9 +15,13 @@ in plain string operations — nothing of istio_tpu.
 """
 from __future__ import annotations
 
+import importlib.util
+from pathlib import Path
+
 import numpy as np
 
 NOT_FOUND, DENIED = 5, 7
+QUOTA_MAX = 1 << 24    # rq.istio-system: no key of the traffic exhausts it
 # make_requests: cumulative shares of the four traffic classes
 CLASSES = ("conformant", "wrong_san", "no_role", "plain_text")
 CLASS_EDGES = (0.7, 0.8, 0.9, 1.0)
@@ -70,7 +74,7 @@ def make_store(sizes: dict):
     s.set(("handler", "istio-system", "mq"), {
         "adapter": "memquota",
         "params": {"quotas": [{"name": "rq.istio-system",
-                               "max_amount": 1 << 24}]}})
+                               "max_amount": QUOTA_MAX}]}})
     s.set(("instance", "istio-system", "rq"), {
         "template": "quota",
         "params": {"dimensions": {"user": 'source.user | "anon"'}}})
@@ -200,3 +204,23 @@ def reference(sizes: dict):
         return list_status(request) or rbac_status(request)
 
     return expected_status
+
+
+def _sibling(name: str):
+    spec = importlib.util.spec_from_file_location(
+        f"bench_config_{name}", Path(__file__).with_name(f"{name}.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def quota_reference(sizes: dict):
+    """A fresh plain memquota for `sizes["quota_name"]`, as make_store
+    configures it: the rule serves mTLS requests alone (a plain-text
+    one that passed its precondition would be granted freely), one
+    counter a `source.user | "anon"`, QUOTA_MAX and no valid_duration:
+    an exact counter that never expires."""
+    return _sibling("memquota_plain").MemQuota(
+        sizes["quota_name"], QUOTA_MAX, reference(sizes),
+        key_of=lambda request: request.get("source.user", "anon"),
+        rule_matches=lambda request: request["connection.mtls"])

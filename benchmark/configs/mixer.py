@@ -10,12 +10,15 @@ parser, nothing of istio_tpu.
 """
 from __future__ import annotations
 
+import importlib.util
 import re
+from pathlib import Path
 
 import numpy as np
 
 WHITELIST = frozenset(f"ns{j}" for j in range(0, 23, 2))
 DENIED, NOT_FOUND = 7, 5
+QUOTA_MAX = 1 << 30    # rq.istio-system: no key of the traffic exhausts it
 
 # predicate kind -> (match expression, plain evaluation of it)
 PREDICATES = {
@@ -77,7 +80,7 @@ def make_store(sizes: dict):
     s.set(("handler", "istio-system", "mq"), {
         "adapter": "memquota",
         "params": {"quotas": [{"name": "rq.istio-system",
-                               "max_amount": 1 << 30}]}})
+                               "max_amount": QUOTA_MAX}]}})
     s.set(("instance", "istio-system", "rq"), {
         "template": "quota",
         "params": {"dimensions": {"user": 'source.user | "anon"'}}})
@@ -169,3 +172,21 @@ def reference(sizes: dict):
         return 0
 
     return expected_status
+
+
+def _sibling(name: str):
+    spec = importlib.util.spec_from_file_location(
+        f"bench_config_{name}", Path(__file__).with_name(f"{name}.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def quota_reference(sizes: dict):
+    """A fresh plain memquota for `sizes["quota_name"]`, as make_store
+    configures it: the mesh-wide rule (match "", so it serves every
+    request), one counter a `source.user | "anon"`, QUOTA_MAX and no
+    valid_duration: an exact counter that never expires."""
+    return _sibling("memquota_plain").MemQuota(
+        sizes["quota_name"], QUOTA_MAX, reference(sizes),
+        key_of=lambda request: request.get("source.user", "anon"))

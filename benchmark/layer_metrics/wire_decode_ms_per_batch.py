@@ -1,5 +1,7 @@
-"""Host wall per batch of _parse_take and the per-row LazyWireBag +
-preprocess loop (span `wire_decode`, NativeMixerServer._run_batch)."""
+"""Host wall per batch of span `wire_decode`
+(NativeMixerServer._run_batch): one np.frombuffer over the pump's own
+take buffer (api/take.TakenRows.read), the split of checks from reports
+by the `kind` column and, only under an APA, a bag + preprocess a row."""
 from istio_tpu.runtime import monitor
 
 from spans import span_ms_per_batch
