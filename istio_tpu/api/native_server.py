@@ -38,7 +38,7 @@ import threading
 
 import numpy as np
 
-from istio_tpu.adapters.sdk import QuotaArgs
+from istio_tpu.adapters.sdk import QuotaArgs, QuotaResult
 from istio_tpu.api import mixer_pb2 as pb
 from istio_tpu.api.grpc_server import MixerGrpcServer
 from istio_tpu.api.take import CHECK, REPORT, TakenRows
@@ -53,16 +53,6 @@ _COUNTER_NAMES = ("requests_decoded", "responses_sent",
                   "batches_formed", "batch_rows", "in_flight",
                   "conns_opened", "conns_closed", "protocol_errors",
                   "bytes_in", "bytes_out")
-
-
-class _RowRequest:
-    """The slice of RawCheckRequest the quota loop reads."""
-
-    __slots__ = ("deduplication_id", "quotas")
-
-    def __init__(self, dedup: str, quotas: dict):
-        self.deduplication_id = dedup
-        self.quotas = quotas
 
 
 # Rows of a batch that share their response's bytes are framed as one
@@ -102,6 +92,71 @@ class _Completions(list):
         self.framed.append(rec.tobytes())
         self.framed_tags.append(tags)
         self.n_framed += len(tags)
+
+
+class _QuotaGroup:
+    """A batch's OK quota rows on their way through the quota pools,
+    and the sink the pools answer them through (DeviceQuotaPool.
+    alloc_many): each quota of a row resolves under the key (row,
+    quota), and a row is answered once its last quota has. The rows one
+    resolve() completes are answered together, in one completion
+    (NativeMixerServer._answer_quota_rows). Nothing is sent before the
+    pump seals the group: the rows complete by then go out with the
+    batch's own completions, and a group left unsealed (its batch
+    failed, and the belt answered every row) never sends."""
+
+    def __init__(self, front: "NativeMixerServer"):
+        self._front = front
+        self._lock = threading.Lock()
+        self.tags: list[int] = []
+        self.bags: list = []
+        self.results: list = []
+        self._names: list[list[str]] = []
+        self._got: list[list] = []
+        self._left: list[int] = []
+        self._ready: list[int] = []
+        self._sealed = False
+
+    def add(self, tag: int, bag, result, names: list[str]) -> int:
+        """A row and its quotas' names, in the order its response
+        lists them → the row's index in the group."""
+        self.tags.append(tag)
+        self.bags.append(bag)
+        self.results.append(result)
+        self._names.append(names)
+        self._got.append([None] * len(names))
+        self._left.append(len(names))
+        return len(self.tags) - 1
+
+    def quotas(self, row: int) -> list[tuple[str, QuotaResult]]:
+        return list(zip(self._names[row], self._got[row]))
+
+    def resolve(self, pairs: list) -> None:
+        """[((row, quota), QuotaResult)]: from the pump for what
+        resolved without a trip, from a pool's worker once a flush."""
+        done = []
+        with self._lock:
+            for (row, q), result in pairs:
+                self._got[row][q] = result
+                self._left[row] -= 1
+                if not self._left[row]:
+                    done.append(row)
+            if not self._sealed:
+                self._ready += done
+                return
+        if done:
+            self._front._send_quota_rows(self, done)
+
+    def seal(self, completions: "_Completions", deferred: set) -> None:
+        """The pump has submitted every row: those complete by now join
+        `completions`, the others' tags go to `deferred` (the belt
+        leaves them to the flush that completes them)."""
+        with self._lock:
+            self._sealed = True
+            ready, self._ready = self._ready, []
+            deferred.update(tag for tag, left in zip(self.tags, self._left)
+                            if left)
+        self._front._answer_quota_rows(self, ready, completions)
 
 
 # must mirror Server::kLatBuckets in httpd.cpp: the wire latency
@@ -198,6 +253,9 @@ class NativeMixerServer(MixerGrpcServer):
         self._final_gaps = {kind: {"count": 0, "sum_ns": 0}
                             for kind in _GAP_KINDS}
         self._watched = False
+        # the bytes of a quota row's response by its class
+        # (_answer_quota_rows)
+        self._quota_memo: dict = {}
         # serializes h2srv_complete against stop(): deferred quota
         # completions fire from pool-worker threads and must never
         # race the server teardown into a freed handle
@@ -763,22 +821,30 @@ class NativeMixerServer(MixerGrpcServer):
             self._serialize_rows(checks, bags, results, inres,
                                  completions, deferred, span)
 
-    def _memo_response(self, result) -> tuple[bytes | None, bool]:
-        """→ (the serialized response of a quota-less `result`, whether
-        the memo held it). Memoised ONLY where the bytes do not depend
-        on the bag, which is therefore not asked for: presence must
-        COVER the referenced set (incomplete presence makes
-        _referenced_proto fall back to per-bag lookups —
-        grpc_server._referenced_proto applies the same gate); (None,
-        False) where it does not."""
+    @staticmethod
+    def _verdict_key(result) -> tuple | None:
+        """What a response's bytes read of `result`, as a key — where
+        they do not depend on the row's bag: presence must COVER the
+        referenced set (incomplete presence makes _referenced_proto
+        fall back to per-bag lookups — grpc_server._referenced_proto
+        applies the same gate); None where it does not."""
         presence = result.referenced_presence
         if presence is None or \
                 len(presence) != len(result.referenced):
+            return None
+        return (result.status_code, result.status_message,
+                result.valid_duration_s,
+                result.valid_use_count, result.referenced,
+                frozenset(presence.items()))
+
+    def _memo_response(self, result) -> tuple[bytes | None, bool]:
+        """→ (the serialized response of a quota-less `result`, whether
+        the memo held it). Memoised ONLY where the bytes do not depend
+        on the bag (_verdict_key), which is therefore not asked for;
+        (None, False) where they do."""
+        key = self._verdict_key(result)
+        if key is None:
             return None, False
-        key = (result.status_code, result.status_message,
-               result.valid_duration_s,
-               result.valid_use_count, result.referenced,
-               frozenset(presence.items()))
         raw = self._resp_memo.get(key)
         if raw is not None:
             return raw, True
@@ -845,7 +911,9 @@ class NativeMixerServer(MixerGrpcServer):
                         deferred: set, span: dict | None) -> None:
         """The per-row code: rows no verdict class answered. A row's
         bag (`bags[row]`) is asked for only by a quota row and by a
-        response whose bytes depend on it."""
+        response whose bytes depend on it. The OK rows that ask for a
+        quota are collected and go through the pools as one group
+        (_submit_quota_group)."""
         # `status` tag (batch-level: ok or the first non-OK code) so
         # /debug/traces can filter failing check spans on this front
         if span is not None:
@@ -860,45 +928,31 @@ class NativeMixerServer(MixerGrpcServer):
         memo_hits = 0
         tags = checks.tags
         asking = set(checks.asking())
+        quota_rows: list[int] = []
         for row in rows:
             result, tag = results[row], int(tags[row])
-            quotas = checks.quotas(row) if row in asking else {}
-            try:
-                if row in inres:
-                    # quota already allocated in the check trip;
-                    # attach it only on success (a denied row's
-                    # entry is grant-freely noise the gate never
-                    # consumed for — the fronts omit quotas on
-                    # denial, grpcServer.go:188)
+            if row in inres:
+                # quota already allocated in the check trip; attach it
+                # only on success (a denied row's entry is grant-freely
+                # noise the gate never consumed for — the fronts omit
+                # quotas on denial, grpcServer.go:188)
+                try:
                     qpair = []
                     if result.status_code == 0:
-                        (qname, _), = quotas.items()
+                        (qname, _), = checks.quotas(row).items()
                         qpair = [(qname, inres[row])]
                     raw = self._check_response(
                         None, bags[row], result,
                         quotas=qpair).SerializeToString()
-                    completions.append((tag, 0, raw))
+                except Exception as exc:   # row-isolated
+                    monitor.DISPATCH_ERRORS.inc()
+                    completions.append(
+                        (tag, 13, f"quota submit: {exc}".encode()))
                     continue
-                if quotas and result.status_code == 0:
-                    # quota rows complete via pool-future
-                    # callbacks: a batch's non-quota rows must NOT
-                    # wait out the quota flush window + device
-                    # trip (that added ~2 serialized trips to
-                    # EVERY row's latency)
-                    req = _RowRequest(checks.dedup_id(row), {
-                        name: pb.CheckRequest.QuotaParams(
-                            amount=amount, best_effort=be)
-                        for name, (amount, be) in quotas.items()})
-                    bag = bags[row]
-                    self._defer_quota_row(
-                        tag, bag, result,
-                        self._submit_quotas(req, bag, result))
-                    deferred.add(tag)
-                    continue
-            except Exception as exc:   # row-isolated (quota path)
-                monitor.DISPATCH_ERRORS.inc()
-                completions.append(
-                    (tag, 13, f"quota submit: {exc}".encode()))
+                completions.append((tag, 0, raw))
+                continue
+            if row in asking and result.status_code == 0:
+                quota_rows.append(row)
                 continue
             raw, held = self._memo_response(result)
             if raw is None:
@@ -909,6 +963,118 @@ class NativeMixerServer(MixerGrpcServer):
             completions.append((tag, 0, raw))
         if memo_hits:   # memoized rows skip _check_response
             monitor.CHECK_RESPONSES.inc(memo_hits)
+        if quota_rows:
+            self._submit_quota_group(checks, bags, results, quota_rows,
+                                     completions, deferred)
+
+    def _submit_quota_group(self, checks: TakenRows, bags, results,
+                            rows: list[int], completions: _Completions,
+                            deferred: set) -> None:
+        """The batch's OK quota rows through the pools as one group:
+        each quota's target found a row at a time (runtime.quota_target
+        builds the instance from the row's bag), then one alloc_many a
+        pool for every ask bound there. A quota resolved without a pool
+        (rule inactive, an instance error, a non-memquota handler) is
+        in the group as resolved. The rows all of whose quotas resolve
+        here are answered with the batch; the others by the pool
+        worker's flush that completes them — a batch's other rows do
+        not wait out the flush window and its device trip."""
+        group = _QuotaGroup(self)
+        asks: dict = {}   # pool → [((row, quota), name, instance, args)]
+        early: list = []
+        tags = checks.tags
+        for row in rows:
+            tag, result, bag = int(tags[row]), results[row], bags[row]
+            dedup = checks.dedup_id(row)
+            targets = []
+            try:
+                for name, (amount, be) in checks.quotas(row).items():
+                    args = QuotaArgs(
+                        quota_amount=amount, best_effort=be,
+                        dedup_id=dedup + ":" + name if dedup else "")
+                    target = self.runtime.quota_target(bag, name, args,
+                                                       result)
+                    if target is None:   # generic path / host handler
+                        target = self.runtime.quota(bag, name, args,
+                                                    preprocessed=True)
+                    targets.append((name, args, target))
+            except Exception as exc:   # row-isolated
+                monitor.DISPATCH_ERRORS.inc()
+                completions.append(
+                    (tag, 13, f"quota submit: {exc}".encode()))
+                continue
+            at = group.add(tag, bag, result, [t[0] for t in targets])
+            for q, (_, args, target) in enumerate(targets):
+                if isinstance(target, tuple):
+                    pool, inst_q, instance = target
+                    asks.setdefault(pool, []).append(
+                        ((at, q), inst_q, instance, args))
+                else:
+                    early.append(((at, q), target))
+        for pool, items in asks.items():
+            early += pool.alloc_many(items, group)
+        group.resolve(early)
+        group.seal(completions, deferred)
+
+    def _answer_quota_rows(self, group: _QuotaGroup, rows: list[int],
+                           completions: _Completions) -> None:
+        """Answer `rows` of `group`, every quota of each resolved. One
+        response a class of equal bytes: the verdict (_verdict_key)
+        and each quota's name, grant, duration and status, memoised;
+        the rows of a class framed together from _FRAME_CLASS_MIN_ROWS
+        on. A row whose bytes depend on its bag is built alone. Either
+        way the bytes are _check_response's for the row."""
+        if not rows:
+            return
+        by_raw: dict[bytes, list[int]] = {}
+        verdicts: dict[int, tuple | None] = {}
+        built = alone = 0
+        for i in rows:
+            result, quotas = group.results[i], group.quotas(i)
+            if id(result) not in verdicts:
+                verdicts[id(result)] = self._verdict_key(result)
+            verdict = verdicts[id(result)]
+            if verdict is None:
+                raw = self._check_response(
+                    None, group.bags[i], result,
+                    quotas=quotas).SerializeToString()
+                alone += 1
+            else:
+                key = (verdict, tuple(
+                    (name, q.granted_amount, q.valid_duration_s,
+                     q.status_code) for name, q in quotas))
+                raw = self._quota_memo.get(key)
+                if raw is None:
+                    raw = self._check_response(
+                        None, None, result,
+                        quotas=quotas).SerializeToString()
+                    built += 1
+                    if len(self._quota_memo) > 8192:
+                        self._quota_memo.clear()
+                    self._quota_memo[key] = raw
+            by_raw.setdefault(raw, []).append(group.tags[i])
+        for raw, tags in by_raw.items():
+            if len(tags) >= _FRAME_CLASS_MIN_ROWS:
+                completions.frame(np.array(tags, np.uint64), raw)
+            else:
+                completions.extend((tag, 0, raw) for tag in tags)
+        # _check_response counted the responses it built
+        classed = len(rows) - alone
+        monitor.CHECK_RESPONSES.inc(classed - built)
+        monitor.note_quota_rows_answered(classed, alone)
+
+    def _send_quota_rows(self, group: _QuotaGroup,
+                         rows: list[int]) -> None:
+        """A flush's answer to the rows of `group` it completed: one
+        completion, from the pool's worker, no pool lock held."""
+        completions = _Completions()
+        try:
+            self._answer_quota_rows(group, rows, completions)
+        except Exception:
+            log.exception("deferred quota completion failed")
+            completions = [(group.tags[i], 13, b"quota completion failed")
+                           for i in rows]
+        self._send_completions(completions)
 
     def _send_completions(self, completions: list) -> None:
         """A _Completions brings the completions it framed already
@@ -927,39 +1093,3 @@ class NativeMixerServer(MixerGrpcServer):
             if self._h is None:    # torn down under a deferred row
                 return
             self._lib.h2srv_complete(self._h, comp, len(comp))
-
-    def _defer_quota_row(self, tag: int, bag, result,
-                         subs: list) -> None:
-        """Complete one quota-carrying row when its pool futures
-        resolve. All quotas were submitted already (they share a flush
-        window); the LAST future to land builds + sends the response
-        from the pool-worker thread — no pump thread blocks."""
-        futures = [qr for _, qr in subs
-                   if hasattr(qr, "add_done_callback")]
-        remaining = [len(futures)]
-        lock = threading.Lock()
-
-        def finish() -> None:
-            try:
-                raw = self._check_response(
-                    None, bag, result,
-                    quotas=subs).SerializeToString()
-                self._send_completions([(tag, 0, raw)])
-            except Exception:
-                log.exception("deferred quota completion failed")
-                self._send_completions(
-                    [(tag, 13, b"quota completion failed")])
-
-        if not futures:
-            finish()
-            return
-
-        def on_done(_value) -> None:
-            with lock:
-                remaining[0] -= 1
-                if remaining[0]:
-                    return
-            finish()
-
-        for fut in futures:
-            fut.add_done_callback(on_done)

@@ -35,6 +35,7 @@ counter state.
 """
 from __future__ import annotations
 
+import collections
 import threading
 import time
 from typing import Any, Callable, Mapping
@@ -48,11 +49,34 @@ from istio_tpu.adapters.memquota import _key as dims_key
 from istio_tpu.adapters.sdk import QuotaArgs, QuotaResult
 from istio_tpu.models.policy_engine import RESOURCE_EXHAUSTED
 from istio_tpu.models.quota_alloc import make_rolling_alloc_step
+from istio_tpu.runtime import monitor
 from istio_tpu.utils.log import scope
 
 log = scope("runtime.device_quota")
 
 DEFAULT_BUCKETS = 131_072    # BASELINE config 4: 100k-key counter eval
+
+
+def _outcome(granted: int, amount: int, duration: float) -> QuotaResult:
+    """A consumed ask's result: a grant short of a non-zero ask is an
+    error only at 0 (memquota)."""
+    return QuotaResult(granted_amount=granted, valid_duration_s=duration,
+                       status_code=0 if granted > 0 or amount == 0
+                       else RESOURCE_EXHAUSTED)
+
+
+def _one_plane(step, jit: bool):
+    """`step` over a trip's eight host planes stacked in one int32
+    [8, n] array (buckets, amounts, best effort, max, active, ticks,
+    lasts, rolling): one host-to-device transfer a trip where there
+    were eight. A transfer lets go of the interpreter lock, and under
+    busy pumps the worker waits out a switch interval to take it back
+    (a host timing under two pumps: the call's wall about halved)."""
+    def run(counts, planes):
+        b, a, be, mx, active, ticks, lasts, rolling = planes
+        return step(counts, b, a, be != 0, mx, active != 0, ticks,
+                    lasts, rolling != 0)
+    return jax.jit(run, donate_argnums=(0,)) if jit else run
 
 
 class DeviceQuotaPool:
@@ -85,6 +109,11 @@ class DeviceQuotaPool:
         self._lock = threading.Lock()
         self._bucket_of: dict[str, int] = {}
         self._dedup: dict[str, tuple[int, float]] = {}
+        # expiry order of the dedup entries, a FIFO a lifetime (max of
+        # the quota's duration and min_dedup_s): (expiry, id, gate-off)
+        # records in write order, popped from the front once expired
+        # (_expire_dedup), so no ask walks the cache
+        self._dedup_fifos: dict[float, collections.deque] = {}
         # rolling-window bookkeeping (host side): tick length per
         # bucket (0 = exact cell), the last tick each bucket rolled to
         # (absolute), and a per-bucket tick base so device ticks stay
@@ -103,8 +132,12 @@ class DeviceQuotaPool:
         (self._alloc_scan, self._alloc_fast, self._alloc_unit,
          self._alloc_seg) = \
             make_rolling_alloc_step(n_buckets, self.k_ticks, jit=jit)
+        self._trips = {name: _one_plane(step, jit) for name, step in (
+            ("fast", self._alloc_fast), ("unit", self._alloc_unit),
+            ("seg", self._alloc_seg))}
         # pending batched allocations: [(bucket, amount, best_effort,
-        # max, future)]
+        # max, duration, dedup id, sink, key)] — the sink resolves the
+        # ask under `key` (alloc_many)
         self._pending: list = []
         self._window_s = batch_window_s
         self._max_batch = max_batch
@@ -162,54 +195,75 @@ class DeviceQuotaPool:
               args: QuotaArgs) -> "QuotaFuture":
         """Non-blocking; returns a future resolving to QuotaResult."""
         fut = QuotaFuture()
-        lim = self.limits.get(name)
-        if lim is None:
-            fut.set(QuotaResult(granted_amount=0,
-                                status_code=RESOURCE_EXHAUSTED,
-                                status_message=f"unknown quota {name}"))
-            return fut
+        for _key, result in self.alloc_many([(None, name, instance, args)],
+                                            fut):
+            fut.set(result)
+        return fut
+
+    def alloc_many(self, items: list, sink) -> list:
+        """Submit a group of asks under ONE take of the pool's lock.
+        `items`: [(key, quota name, instance, QuotaArgs)]. → [(key,
+        QuotaResult)] for the asks resolved here without a trip: an
+        unknown name, a dedup replay (of a consumed or a gate-off
+        outcome), a closed pool, an exhausted keyspace. Every other ask
+        takes its place in the pending queue in `items`' order (so a
+        flush's in-window replay of a repeated id stays exact) and is
+        answered through `sink.resolve([(key, QuotaResult), ...])`,
+        once a flush that answers any of them, from the worker thread
+        with no pool lock held."""
         now = self._clock()
-        with self._lock:
-            self._gc_dedup(now)
-            if args.dedup_id:
-                hit = self._dedup.get(args.dedup_id)
-                if hit is not None and hit[1] > now:
-                    status = 0 if hit[0] > 0 or args.quota_amount == 0 \
-                        else RESOURCE_EXHAUSTED
-                    fut.set(QuotaResult(granted_amount=hit[0],
-                                        valid_duration_s=lim["duration"],
-                                        status_code=status))
-                    return fut
-                free_exp = self._dedup_free.get(args.dedup_id)
-                if free_exp is not None and free_exp > now:
-                    # first transmission committed GATE-OFF (granted
-                    # freely, nothing consumed): dedup-id semantics
-                    # replay that outcome on EVERY path — consuming
-                    # fresh here would double-book the retransmission
-                    fut.set(QuotaResult(
-                        granted_amount=args.quota_amount))
-                    return fut
-            if self._closed:   # post-swap drain raced the caller
-                fut.set(QuotaResult(
-                    granted_amount=0, status_code=14,  # UNAVAILABLE
-                    status_message="quota pool closed by config swap"))
-                return fut
-            bucket = self._bucket_for(dims_key(instance), lim, now)
-            if bucket < 0:   # keyspace exhausted: fail closed
-                fut.set(QuotaResult(
+        early: list = []
+        asks: list = []
+        for key, name, instance, args in items:
+            lim = self.limits.get(name)
+            if lim is None:
+                early.append((key, QuotaResult(
                     granted_amount=0, status_code=RESOURCE_EXHAUSTED,
-                    status_message="quota keyspace exhausted"))
-                return fut
-            self._pending.append((bucket, int(args.quota_amount),
-                                  bool(args.best_effort), lim["max"],
-                                  lim["duration"], args.dedup_id, fut))
+                    status_message=f"unknown quota {name}")))
+            else:
+                asks.append((key, lim, dims_key(instance), args))
+        with self._lock:
+            self._expire_dedup(now)
+            was_empty = not self._pending
+            for key, lim, dims, args in asks:
+                did = args.dedup_id
+                if did:
+                    hit = self._dedup.get(did)
+                    if hit is not None and hit[1] > now:
+                        early.append((key, _outcome(
+                            hit[0], args.quota_amount, lim["duration"])))
+                        continue
+                    free_exp = self._dedup_free.get(did)
+                    if free_exp is not None and free_exp > now:
+                        # first transmission committed GATE-OFF (granted
+                        # freely, nothing consumed): dedup-id semantics
+                        # replay that outcome on EVERY path — consuming
+                        # fresh here would double-book the retransmission
+                        early.append((key, QuotaResult(
+                            granted_amount=args.quota_amount)))
+                        continue
+                if self._closed:   # post-swap drain raced the caller
+                    early.append((key, QuotaResult(
+                        granted_amount=0, status_code=14,  # UNAVAILABLE
+                        status_message="quota pool closed by config "
+                                       "swap")))
+                    continue
+                bucket = self._bucket_for(dims, lim, now)
+                if bucket < 0:   # keyspace exhausted: fail closed
+                    early.append((key, QuotaResult(
+                        granted_amount=0, status_code=RESOURCE_EXHAUSTED,
+                        status_message="quota keyspace exhausted")))
+                    continue
+                self._pending.append((bucket, int(args.quota_amount),
+                                      bool(args.best_effort), lim["max"],
+                                      lim["duration"], did, sink, key))
             # wake on the empty→non-empty edge (the worker idles in a
             # 100ms poll otherwise — a silent +100ms on every
             # low-rate quota RPC) and when a full batch is ready
-            if len(self._pending) == 1 \
-                    or len(self._pending) >= self._max_batch:
+            if self._pending and (
+                    was_empty or len(self._pending) >= self._max_batch):
                 self._wake.notify()
-        return fut
+        return early
 
     def inline_begin(self, n: int, rows: list, now: float
                      ) -> "InlineQuotaSession | None":
@@ -237,7 +291,7 @@ class DeviceQuotaPool:
                 sess.seq = self._seq_next
                 self._seq_next += 1
                 sess.prev_counts = self.counts
-                self._gc_dedup(now)
+                self._expire_dedup(now)
                 first_of: dict[str, int] = {}
                 for slot, name, instance, args in rows:
                     lim = self.limits.get(name)
@@ -251,13 +305,9 @@ class DeviceQuotaPool:
                     if did:
                         hit = self._dedup.get(did)
                         if hit is not None and hit[1] > now:
-                            status = 0 if hit[0] > 0 or \
-                                args.quota_amount == 0 \
-                                else RESOURCE_EXHAUSTED
-                            sess.early[slot] = QuotaResult(
-                                granted_amount=hit[0],
-                                valid_duration_s=lim["duration"],
-                                status_code=status)
+                            sess.early[slot] = _outcome(
+                                hit[0], args.quota_amount,
+                                lim["duration"])
                             continue
                         free_exp = self._dedup_free.get(did)
                         if free_exp is not None and free_exp > now:
@@ -308,9 +358,10 @@ class DeviceQuotaPool:
         # still queued (worker died) must not hang callers
         with self._lock:
             leftovers, self._pending = self._pending, []
-        for *_x, fut in leftovers:
-            fut.set(QuotaResult(granted_amount=0, status_code=14,
-                                status_message="quota pool closed"))
+        self._deliver([(sink, key, QuotaResult(
+            granted_amount=0, status_code=14,
+            status_message="quota pool closed"))
+            for *_x, sink, key in leftovers])
 
     def audit_view(self) -> dict:
         """Sampled counter-plane reading for the mesh audit plane
@@ -354,16 +405,13 @@ class DeviceQuotaPool:
     def _prewarm(self) -> None:
         # every program the SERVING path can hit; the scan oracle is
         # deliberately absent (never serving-selected, so its compile
-        # would be pure startup cost)
+        # would be pure startup cost). A host plane, as _trip passes
+        # it: a device array is another signature, a retrace mid-serve
         for pn in {self._small_batch, self._max_batch}:
-            zeros_i = jnp.zeros(pn, jnp.int32)
-            zeros_b = jnp.zeros(pn, bool)
-            for fn in (self._alloc_seg, self._alloc_fast,
-                       self._alloc_unit):
+            for trip in self._trips.values():
                 # all-inactive batch: grants nothing, counters unchanged
-                _, self.counts = fn(self.counts, zeros_i, zeros_i,
-                                    zeros_b, zeros_i, zeros_b,
-                                    zeros_i, zeros_i, zeros_b)
+                _, self.counts = trip(self.counts,
+                                      np.zeros((8, pn), np.int32))
         jax.block_until_ready(self.counts)
 
     def _bucket_for(self, key: str, lim: Mapping[str, Any],
@@ -410,12 +458,16 @@ class DeviceQuotaPool:
                     self._flush(batch)
                 except Exception as exc:   # pragma: no cover
                     log.exception("quota flush failed")
-                    for *_x, fut in batch:
-                        fut.set(QuotaResult(
-                            granted_amount=0, status_code=13,
-                            status_message=f"quota alloc failed: {exc}"))
+                    self._deliver([(sink, key, QuotaResult(
+                        granted_amount=0, status_code=13,
+                        status_message=f"quota alloc failed: {exc}"))
+                        for *_x, sink, key in batch])
 
     def _flush(self, batch: list) -> None:
+        """One batch of pending asks: replays and grants are worked out
+        under the locks, and every answer is handed to its sink after
+        the last of them is let go (_deliver)."""
+        t0 = time.perf_counter()
         now = self._clock()
         # mesh event timeline (runtime/forensics.py): a flush trip is
         # a control-plane event a concurrent request's tail can ride
@@ -429,10 +481,9 @@ class DeviceQuotaPool:
         # the first outcome without consuming (buildWithDedup :259)
         first_of: dict[str, int] = {}
         replay_items: list[tuple[Any, int]] = []   # (item, kept index)
-        cache_replays: list = []   # (item, cached granted)
-        free_replays: list = []    # gate-off outcome: grant freely
         deferred: list = []   # dedup id held by an uncommitted session
         kept: list = []
+        done: list = []       # (sink, key, QuotaResult)
         with self._lock:
             for item in batch:
                 dedup_id = item[5]
@@ -443,14 +494,16 @@ class DeviceQuotaPool:
                     # must replay, not re-consume
                     hit = self._dedup.get(dedup_id)
                     if hit is not None and hit[1] > now:
-                        cache_replays.append((item, hit[0]))
+                        done.append((item[6], item[7], _outcome(
+                            hit[0], item[1], item[4])))
                         continue
                     free_exp = self._dedup_free.get(dedup_id)
                     if free_exp is not None and free_exp > now:
                         # gate-off outcome: replay grant-freely (the
                         # deferred-past-a-gate-off-commit case lands
                         # here on its re-flush)
-                        free_replays.append(item)
+                        done.append((item[6], item[7], QuotaResult(
+                            granted_amount=item[1])))
                         continue
                     if dedup_id in self._dedup_pending:
                         # consumed by a dispatched-but-uncommitted
@@ -466,17 +519,26 @@ class DeviceQuotaPool:
                         continue
                     first_of[dedup_id] = len(kept)
                 kept.append(item)
-        for (_, amount, _, _, duration, _, fut), g in cache_replays:
-            status = 0 if g > 0 or amount == 0 else RESOURCE_EXHAUSTED
-            fut.set(QuotaResult(granted_amount=g,
-                                valid_duration_s=duration,
-                                status_code=status))
-        for (_, amount, *_rest, fut) in free_replays:
-            fut.set(QuotaResult(granted_amount=amount))
-        batch = kept
-        if not batch:
-            self._requeue_deferred(deferred)
-            return
+        if kept:
+            granted = self._trip(kept, now)
+            with self._lock:
+                for (_, amount, _, _, duration, dedup_id, sink, key), g \
+                        in zip(kept, granted):
+                    if dedup_id:
+                        self._remember(dedup_id, g, duration, now)
+                    done.append((sink, key, _outcome(g, amount, duration)))
+            for (_, amount, _, _, duration, _, sink, key), k \
+                    in replay_items:
+                done.append((sink, key,
+                             _outcome(granted[k], amount, duration)))
+        self._deliver(done)
+        self._requeue_deferred(deferred)
+        monitor.QUOTA_FLUSH_ROWS.inc(len(done))
+        monitor.QUOTA_FLUSH_SECONDS.observe(time.perf_counter() - t0)
+
+    def _trip(self, batch: list, now: float) -> list[int]:
+        """Stage `batch` (pending asks, no replays among them), run the
+        alloc on the device, pull → the granted amount of each ask."""
         n = len(batch)
         # pad to one of TWO fixed shapes: every distinct shape is its
         # own XLA compile (a second or more each), and a
@@ -485,14 +547,8 @@ class DeviceQuotaPool:
         # fresh compile per arrival-burst size and 60s quota waits)
         pn = self._small_batch if n <= self._small_batch \
             else self._max_batch
-        buckets = np.zeros(pn, np.int32)
-        amounts = np.zeros(pn, np.int32)
-        be = np.zeros(pn, bool)
-        mx = np.zeros(pn, np.int32)
-        active = np.zeros(pn, bool)
-        ticks = np.zeros(pn, np.int32)
-        lasts = np.zeros(pn, np.int32)
-        rolling = np.zeros(pn, bool)
+        planes = np.zeros((8, pn), np.int32)
+        buckets, amounts, be, mx, active, ticks, lasts, rolling = planes
         # The tick/last staging and the roll application MUST happen
         # under _lock INSIDE the _counts_lock critical section, ordered
         # exactly like InlineQuotaSession.stage (ADVICE r5): _last_tick
@@ -534,37 +590,13 @@ class DeviceQuotaPool:
             # it.
             if len(np.unique(buckets[:n])) < n:
                 all_unit = bool((amounts[:n] == 1).all())   # hotpath: sync-ok (host numpy)
-                alloc = self._alloc_unit if all_unit \
-                    else self._alloc_seg
+                trip = self._trips["unit" if all_unit else "seg"]
             else:
-                alloc = self._alloc_fast
-            granted, self.counts = alloc(
-                self.counts, jnp.asarray(buckets),
-                jnp.asarray(amounts), jnp.asarray(be),
-                jnp.asarray(mx), jnp.asarray(active),
-                jnp.asarray(ticks), jnp.asarray(lasts),
-                jnp.asarray(rolling))
+                trip = self._trips["fast"]
+            granted, self.counts = trip(self.counts, planes)
             # the worker's designated pull — hotpath: sync-ok
             granted = np.asarray(granted)   # hotpath: sync-ok
-        with self._lock:
-            for i, (_, amount, _, _, duration, dedup_id, fut) \
-                    in enumerate(batch):
-                g = int(granted[i])
-                if dedup_id:
-                    expiry = now + max(duration, self.min_dedup_s)
-                    self._dedup[dedup_id] = (g, expiry)
-                status = 0 if g > 0 or amount == 0 \
-                    else RESOURCE_EXHAUSTED
-                fut.set(QuotaResult(granted_amount=g,
-                                    valid_duration_s=duration,
-                                    status_code=status))
-        for (_, amount, _, _, duration, _, fut), k in replay_items:
-            g = int(granted[k])
-            status = 0 if g > 0 or amount == 0 else RESOURCE_EXHAUSTED
-            fut.set(QuotaResult(granted_amount=g,
-                                valid_duration_s=duration,
-                                status_code=status))
-        self._requeue_deferred(deferred)
+        return granted[:n].tolist()
 
     def _requeue_deferred(self, deferred: list) -> None:
         """Items whose dedup id was held by a dispatched-but-
@@ -580,19 +612,61 @@ class DeviceQuotaPool:
                 self._pending.extend(deferred)
                 self._wake.notify()
                 return
-        for *_x, fut in deferred:
-            fut.set(QuotaResult(granted_amount=0, status_code=14,
-                                status_message="quota pool closed"))
+        self._deliver([(sink, key, QuotaResult(
+            granted_amount=0, status_code=14,
+            status_message="quota pool closed"))
+            for *_x, sink, key in deferred])
 
-    def _gc_dedup(self, now: float) -> None:
-        if len(self._dedup) > 10_000:
-            for k in [k for k, (_, exp) in self._dedup.items()
-                      if exp <= now]:
-                del self._dedup[k]
-        if len(self._dedup_free) > 10_000:
-            for k in [k for k, exp in self._dedup_free.items()
-                      if exp <= now]:
-                del self._dedup_free[k]
+    @staticmethod
+    def _deliver(done: list) -> None:
+        """Hand each sink its [(key, QuotaResult)] in one resolve(): a
+        future's callbacks and a group's answers (the native front's
+        response build and send) run here, with NO pool lock held, so an
+        ask waiting at the pool's lock never waits them out."""
+        by_sink: dict = {}
+        for sink, key, result in done:
+            by_sink.setdefault(sink, []).append((key, result))
+        for sink, pairs in by_sink.items():
+            try:
+                sink.resolve(pairs)
+            except Exception:   # a sink must not kill the worker
+                log.exception("quota sink failed")
+
+    def _remember(self, dedup_id: str, granted: int | None,
+                  duration: float, now: float) -> None:
+        """Cache a dedup id's outcome (under _lock): `granted` of a
+        consumed ask in `_dedup`, or None for a gate-off one (granted
+        freely, nothing consumed) in `_dedup_free`; its expiry joins
+        its lifetime's FIFO."""
+        life = max(duration, self.min_dedup_s)
+        expiry = now + life
+        if granted is None:
+            self._dedup_free[dedup_id] = expiry
+        else:
+            self._dedup[dedup_id] = (granted, expiry)
+        fifo = self._dedup_fifos.get(life)
+        if fifo is None:
+            fifo = self._dedup_fifos[life] = collections.deque()
+        fifo.append((expiry, dedup_id, granted is None))
+
+    def _expire_dedup(self, now: float) -> None:
+        """Drop the expired dedup entries from the front of each
+        lifetime's FIFO (under _lock): amortised O(1) an entry, no walk
+        of the cache at any size. A record drops only the value it
+        wrote: an id written again since keeps its newer entry. Writes
+        come close to, not exactly in, expiry order (a flush and an
+        in-step commit each stamp their own `now`): a record behind an
+        unexpired one waits for it, and lookups test the expiry."""
+        for fifo in self._dedup_fifos.values():
+            while fifo and fifo[0][0] <= now:
+                expiry, did, free = fifo.popleft()
+                if free:
+                    if self._dedup_free.get(did) == expiry:
+                        del self._dedup_free[did]
+                else:
+                    hit = self._dedup.get(did)
+                    if hit is not None and hit[1] == expiry:
+                        del self._dedup[did]
 
 
 class QuotaFuture:
@@ -618,6 +692,11 @@ class QuotaFuture:
                 cb(value)
             except Exception:   # callbacks must not kill the worker
                 log.exception("quota future callback failed")
+
+    def resolve(self, pairs: list) -> None:
+        """The pool's sink (DeviceQuotaPool.alloc_many): one ask."""
+        (_key, value), = pairs
+        self.set(value)
 
     def add_done_callback(self, cb) -> None:
         """cb(QuotaResult) — fires immediately if already resolved,
@@ -752,31 +831,19 @@ class InlineQuotaSession:
                         # grant-freely too, like a serialized memquota
                         # would
                         if did:
-                            p._dedup_free[did] = self.now + max(
-                                duration, p.min_dedup_s)
+                            p._remember(did, None, duration, self.now)
                         out[slot] = QuotaResult(granted_amount=amount)
                         continue
                     g = int(granted[slot])
                     if did:
-                        expiry = self.now + max(duration,
-                                                p.min_dedup_s)
-                        p._dedup[did] = (g, expiry)
-                    status = 0 if g > 0 or amount == 0 \
-                        else RESOURCE_EXHAUSTED
-                    out[slot] = QuotaResult(granted_amount=g,
-                                            valid_duration_s=duration,
-                                            status_code=status)
+                        p._remember(did, g, duration, self.now)
+                    out[slot] = _outcome(g, amount, duration)
                 for slot, (did, duration, amount) in \
                         self.pending_replay.items():
                     hit = p._dedup.get(did)
                     free_exp = p._dedup_free.get(did)
                     if hit is not None and hit[1] > self.now:
-                        status = 0 if hit[0] > 0 or amount == 0 \
-                            else RESOURCE_EXHAUSTED
-                        out[slot] = QuotaResult(
-                            granted_amount=hit[0],
-                            valid_duration_s=duration,
-                            status_code=status)
+                        out[slot] = _outcome(hit[0], amount, duration)
                     elif free_exp is not None and free_exp > self.now:
                         # consuming session committed GATE-OFF: the
                         # serialized outcome is grant-freely (this

@@ -346,6 +346,47 @@ def apa_counters() -> dict:
             "table_rows": int(APA_TABLE_ROWS.value())}
 
 
+# -- the device quota pools (runtime/device_quota.py): the worker's
+# flushes, from the take of a batch of pending rows to the last of its
+# completions handed on (staging, the device trip, grants and the
+# answers' framing), and the rows they answered; and the native
+# front's quota rows by how their response was built: `class`, a
+# response a class of equal bytes (a verdict class and the same
+# grants), or `row`, built from the row's own bag.
+QUOTA_FLUSH_SECONDS = hostmetrics.default_registry.histogram(
+    "mixer_quota_flush_seconds",
+    "wall of a quota pool worker's flush, take to last completion")
+QUOTA_FLUSH_ROWS = hostmetrics.default_registry.counter(
+    "mixer_quota_flush_rows_total", "quota rows the pool flushes answered")
+QUOTA_FLUSH_ROWS.inc(0)
+QUOTA_ANSWER_PATHS = ("class", "row")
+QUOTA_ROWS_ANSWERED = prometheus_client.Counter(
+    "mixer_quota_rows_answered_total",
+    "native front quota rows answered, by how their response was built",
+    ["path"], registry=REGISTRY)
+for _r in QUOTA_ANSWER_PATHS:
+    QUOTA_ROWS_ANSWERED.labels(path=_r)
+
+
+def note_quota_rows_answered(classed: int, row: int) -> None:
+    if classed:
+        QUOTA_ROWS_ANSWERED.labels(path="class").inc(classed)
+    if row:
+        QUOTA_ROWS_ANSWERED.labels(path="row").inc(row)
+
+
+def quota_counters() -> dict:
+    """{"flush_s", "flushes", "flush_rows", "answered": {path: rows}}
+    as one JSON-able dict."""
+    _, flush_s, flushes = QUOTA_FLUSH_SECONDS.state()
+    return {"flush_s": flush_s, "flushes": flushes,
+            "flush_rows": int(QUOTA_FLUSH_ROWS.value()),
+            "answered": {
+                path: int(QUOTA_ROWS_ANSWERED.labels(path=path)
+                          ._value.get())
+                for path in QUOTA_ANSWER_PATHS}}
+
+
 # -- the resident DFA banks of the plan in force, set at plan build
 # (runtime/fused.py) from the compiled ruleset's geometry: what the
 # snapshot's constant-pattern regexes cost on the device and how the

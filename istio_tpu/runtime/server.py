@@ -1234,6 +1234,19 @@ class RuntimeServer:
         Returns a QuotaFuture, a final QuotaResult (no device work
         needed), or None → the caller must take the dispatcher.quota
         fallback (generic path / non-memquota quota handler)."""
+        target = self.quota_target(bag, quota_name, args, check_result)
+        if isinstance(target, tuple):
+            pool, inst_q, instance = target
+            return pool.alloc(inst_q, instance, args)
+        return target
+
+    def quota_target(self, bag: Bag, quota_name: str, args: QuotaArgs,
+                     check_result):
+        """Where quota_fused sends one quota, without sending it:
+        (device pool, instance name, built instance), a final
+        QuotaResult, or None (the dispatcher.quota fallback). The
+        native front submits a batch's pool targets a pool at a time
+        (DeviceQuotaPool.alloc_many)."""
         from istio_tpu.adapters.sdk import QuotaResult
         from istio_tpu.expr.oracle import EvalError
         from istio_tpu.models.policy_engine import INTERNAL
@@ -1273,7 +1286,7 @@ class RuntimeServer:
                                    status_code=INTERNAL,
                                    status_message=f"instance build: "
                                                   f"{exc}")
-            return pool.alloc(inst_q, instance, args)
+            return pool, inst_q, instance
         # no matching active quota rule: grant freely
         return QuotaResult(granted_amount=args.quota_amount)
 
