@@ -44,7 +44,7 @@ from istio_tpu.api.grpc_server import MixerGrpcServer
 from istio_tpu.api.take import CHECK, REPORT, TakenRows
 from istio_tpu.native.build import ensure_httpd_built
 from istio_tpu.runtime import forensics, monitor
-from istio_tpu.runtime.server import RuntimeServer
+from istio_tpu.runtime.server import QUOTA_FREE, RuntimeServer
 
 log = logging.getLogger("istio_tpu.api.native")
 
@@ -119,7 +119,9 @@ class _QuotaGroup:
 
     def add(self, tag: int, bag, result, names: list[str]) -> int:
         """A row and its quotas' names, in the order its response
-        lists them → the row's index in the group."""
+        lists them → the row's index in the group. `bag`: the row's,
+        where its response's bytes read it (NativeMixerServer.
+        _verdict_key is None), else None."""
         self.tags.append(tag)
         self.bags.append(bag)
         self.results.append(result)
@@ -158,6 +160,9 @@ class _QuotaGroup:
                             if left)
         self._front._answer_quota_rows(self, ready, completions)
 
+
+# a quota name a verdict class has not routed yet (_submit_quota_group)
+_UNROUTED = object()
 
 # must mirror Server::kLatBuckets in httpd.cpp: the wire latency
 # histogram's log-bucket count (bucket i covers ≤ 1µs·2^(i/8))
@@ -971,24 +976,35 @@ class NativeMixerServer(MixerGrpcServer):
                             rows: list[int], completions: _Completions,
                             deferred: set) -> None:
         """The batch's OK quota rows through the pools as one group:
-        each quota's target found a row at a time (runtime.quota_target
-        builds the instance from the row's bag, or from the bag
-        runtime.quota_bags binds where a quota instance reads what the
-        step's APA binds), then one alloc_many a pool for every ask
-        bound there. A quota resolved without a pool
-        (rule inactive, an instance error, a non-memquota handler) is
-        in the group as resolved. The rows all of whose quotas resolve
-        here are answered with the batch; the others by the pool
-        worker's flush that completes them — a batch's other rows do
-        not wait out the flush window and its device trip."""
+        each quota's target found a row at a time, then one alloc_many
+        a pool for every ask bound there. Where the check result sends
+        a quota (runtime.quota_route) is read once a verdict class and
+        quota name. A pool ask's key comes from the plan's memo over the
+        batch's ids where the plan keys its instance so
+        (`results.quota_keys`, runtime/quota_keys.py), else from the
+        instance built on the row's bag (runtime.quota_build), or on the
+        bag runtime.quota_bags binds where a quota instance reads what
+        the step's APA binds. A row's bag is made only where something
+        reads it: such a build, the generic path, a response whose bytes
+        depend on it. A quota resolved without a pool (rule inactive,
+        an instance error, a non-memquota handler) is in the group as
+        resolved. The rows all of whose quotas resolve here are answered
+        with the batch; the others by the pool worker's flush that
+        completes them — a batch's other rows do not wait out the flush
+        window and its device trip."""
         group = _QuotaGroup(self)
         asks: dict = {}   # pool → [((row, quota), name, instance, args)]
         early: list = []
         tags = checks.tags
-        bound = self.runtime.quota_bags(bags, results, rows)
+        runtime = self.runtime
+        bound = runtime.quota_bags(bags, results, rows)
+        keys = getattr(results, "quota_keys", None) \
+            if bound is None else None
+        routes: dict = {}     # (id(result), quota name) → its route
+        alone: dict = {}      # id(result) → its bytes read the bag
+        paths = {"memo": 0, "built": 0, "row": 0}
         for k, row in enumerate(rows):
-            tag, result, bag = int(tags[row]), results[row], bags[row]
-            qbag = bag if bound is None else bound[k]
+            tag, result = int(tags[row]), results[row]
             dedup = checks.dedup_id(row)
             targets = []
             try:
@@ -996,18 +1012,41 @@ class NativeMixerServer(MixerGrpcServer):
                     args = QuotaArgs(
                         quota_amount=amount, best_effort=be,
                         dedup_id=dedup + ":" + name if dedup else "")
-                    target = self.runtime.quota_target(qbag, name, args,
-                                                       result)
-                    if target is None:   # generic path / host handler
-                        target = self.runtime.quota(qbag, name, args,
-                                                    preprocessed=True)
+                    route = routes.get((id(result), name), _UNROUTED)
+                    if route is _UNROUTED:
+                        route = routes[id(result), name] = \
+                            runtime.quota_route(name, result)
+                    if route is QUOTA_FREE:
+                        target = QuotaResult(granted_amount=amount)
+                    elif route is None:   # generic path / host handler
+                        target = runtime.quota(
+                            bags[row] if bound is None else bound[k],
+                            name, args, preprocessed=True)
+                    else:
+                        got = None if keys is None \
+                            else keys.key(route[1], row, bags)
+                        if got is None:
+                            paths["row"] += 1
+                            target = runtime.quota_build(
+                                bags[row] if bound is None else bound[k],
+                                result, *route)
+                        else:
+                            paths[got[0]] += 1
+                            target = (*route, got[1])
                     targets.append((name, args, target))
             except Exception as exc:   # row-isolated
                 monitor.DISPATCH_ERRORS.inc()
                 completions.append(
                     (tag, 13, f"quota submit: {exc}".encode()))
                 continue
-            at = group.add(tag, bag, result, [t[0] for t in targets])
+            # the bag goes with the row only where its response's bytes
+            # read it (_answer_quota_rows): a flush may answer the row
+            # after the pump has taken again into the buffer it lies in
+            needs = alone.get(id(result))
+            if needs is None:
+                needs = alone[id(result)] = self._verdict_key(result) is None
+            at = group.add(tag, bags[row] if needs else None, result,
+                           [t[0] for t in targets])
             for q, (_, args, target) in enumerate(targets):
                 if isinstance(target, tuple):
                     pool, inst_q, instance = target
@@ -1015,6 +1054,8 @@ class NativeMixerServer(MixerGrpcServer):
                         ((at, q), inst_q, instance, args))
                 else:
                     early.append(((at, q), target))
+        monitor.note_quota_keys(paths["memo"], paths["built"],
+                                paths["row"])
         for pool, items in asks.items():
             early += pool.alloc_many(items, group)
         group.resolve(early)

@@ -157,6 +157,10 @@ class TakenRows(Sequence):
         """The rows that ask for a quota."""
         return np.flatnonzero(self.index["quota_count"]).tolist()
 
+    def asks_quota(self) -> bool:
+        """Whether a row asks for a quota."""
+        return bool(self.index["quota_count"].any())
+
     def quotas(self, i: int) -> dict[str, tuple[int, bool]]:
         """Row i's quota section → {name: (amount, best_effort)}."""
         row = self.index[i]

@@ -202,7 +202,9 @@ class DeviceQuotaPool:
 
     def alloc_many(self, items: list, sink) -> list:
         """Submit a group of asks under ONE take of the pool's lock.
-        `items`: [(key, quota name, instance, QuotaArgs)]. → [(key,
+        `items`: [(key, quota name, instance, QuotaArgs)], the instance
+        a built one or its memquota key already (runtime/quota_keys.py:
+        the same string dims_key gives). → [(key,
         QuotaResult)] for the asks resolved here without a trip: an
         unknown name, a dedup replay (of a consumed or a gate-off
         outcome), a closed pool, an exhausted keyspace. Every other ask
@@ -221,7 +223,8 @@ class DeviceQuotaPool:
                     granted_amount=0, status_code=RESOURCE_EXHAUSTED,
                     status_message=f"unknown quota {name}")))
             else:
-                asks.append((key, lim, dims_key(instance), args))
+                asks.append((key, lim, instance if isinstance(
+                    instance, str) else dims_key(instance), args))
         with self._lock:
             self._expire_dedup(now)
             was_empty = not self._pending

@@ -97,6 +97,9 @@ class ClassedResponses(list):
     # the batch's runtime/apa.PodRows where the step holds the APA and
     # a quota instance reads what it binds (RuntimeServer.quota_bags)
     apa_pods: Any = None
+    # the batch's runtime/quota_keys.KeyColumns where its plan keys a
+    # quota instance by the batch's ids and a row asks for a quota
+    quota_keys: Any = None
 
 
 # A batch of fewer real rows than this builds its responses a row: the
@@ -770,6 +773,11 @@ class Dispatcher:
                                      observe, deadline, undecided)
         if plan.apa is not None and plan.apa.quota_bindings:
             out.apa_pods = PodRows(plan.apa, *plan.apa.host_rows(batch))
+        # a taken batch the shim decoded, with a row that asks a quota
+        if plan.quota_keys is not None and \
+                getattr(bags, "asks_quota", None) is not None and \
+                bags.asks_quota() and bags.wire_spans() is not None:
+            out.quota_keys = plan.quota_keys.columns(batch, n_real)
         return out
 
     def _fold_respond(self, snap, plan, packed: np.ndarray, batch,

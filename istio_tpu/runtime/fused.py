@@ -205,6 +205,10 @@ class FusedPlan:
     # C++ wire→tensor decoder (istio_tpu/native); None when the
     # toolchain is unavailable — python Tensorizer serves instead
     native: Any = None
+    # the quota instances whose memquota key the batch's interned ids
+    # decide, and the plan's memo of their keys (runtime/quota_keys.py);
+    # None where none is, or without the C++ decoder
+    quota_keys: Any = None
     # the APA the Check step holds (runtime/apa.ApaStep): its table
     # rides in engine.params, `_base_step` applies it before `match`
     # on a batch the host stamped (Dispatcher._tensorize_for_device).
@@ -1236,6 +1240,10 @@ def build_fused_plan(snapshot: Snapshot,
     except Exception as exc:   # toolchain missing → python tensorize
         log.warning("native tensorizer unavailable, serving with the "
                     "python wire decoder: %s", exc)
+    quota_keys = None
+    if native is not None:
+        from istio_tpu.runtime.quota_keys import build_quota_keys
+        quota_keys = build_quota_keys(snapshot, quota_actions, apa)
     monitor.note_dfa_banks(rs.geometry.get("dfa_banks", ()))
     log.info("fused plan: %d deny rules, %d lists, %d rbac actions "
              "(%d pseudo-rules), %d host-overlay rules, native=%s",
@@ -1316,6 +1324,7 @@ def build_fused_plan(snapshot: Snapshot,
         decided_section[np.asarray(sorted(members), np.intp)] = \
             _BY[section]
     return FusedPlan(engine=engine, native=native, mesh=mesh, apa=apa,
+                     quota_keys=quota_keys,
                      telemetry=telemetry,
                      decided_section=decided_section,
                      # AFTER every compile above (engine, report

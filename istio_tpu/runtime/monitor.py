@@ -378,6 +378,33 @@ def note_quota_rows_answered(classed: int, row: int) -> None:
         QUOTA_ROWS_ANSWERED.labels(path="row").inc(row)
 
 
+# the native front's asks of a device pool by where their memquota key
+# came from (runtime/quota_keys.py): `memo`, the plan's memo over the
+# batch's interned ids; `built`, a miss there, the instance built on the
+# row's bag and its key stored; `row`, the per-row build (a plan, an
+# instance or a row whose ids do not decide the key, or a build error)
+QUOTA_KEY_PATHS = ("memo", "built", "row")
+QUOTA_KEYS = prometheus_client.Counter(
+    "mixer_quota_keys_total",
+    "native front quota asks, by where their memquota key came from",
+    ["path"], registry=REGISTRY)
+for _r in QUOTA_KEY_PATHS:
+    QUOTA_KEYS.labels(path=_r)
+
+
+def note_quota_keys(memo: int, built: int, row: int) -> None:
+    """A batch's asks by path: one increment a path that had any."""
+    for path, n in zip(QUOTA_KEY_PATHS, (memo, built, row)):
+        if n:
+            QUOTA_KEYS.labels(path=path).inc(n)
+
+
+def quota_key_counters() -> dict:
+    """{path: asks} as one JSON-able dict."""
+    return {path: int(QUOTA_KEYS.labels(path=path)._value.get())
+            for path in QUOTA_KEY_PATHS}
+
+
 def quota_counters() -> dict:
     """{"flush_s", "flushes", "flush_rows", "answered": {path: rows}}
     as one JSON-able dict."""

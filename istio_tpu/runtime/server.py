@@ -21,6 +21,9 @@ from istio_tpu.runtime.dispatcher import (CheckResponse,
                                           DEFAULT_IDENTITY_ATTR)
 from istio_tpu.runtime.store import Store
 
+# RuntimeServer.quota_route's answer for a quota granted freely
+QUOTA_FREE = "granted freely"
+
 
 @dataclasses.dataclass
 class ServerArgs:
@@ -1283,10 +1286,20 @@ class RuntimeServer:
         QuotaResult, or None (the dispatcher.quota fallback). The
         native front submits a batch's pool targets a pool at a time
         (DeviceQuotaPool.alloc_many)."""
-        from istio_tpu.adapters.sdk import QuotaResult
-        from istio_tpu.expr.oracle import EvalError
-        from istio_tpu.models.policy_engine import INTERNAL
+        route = self.quota_route(quota_name, check_result)
+        if route is QUOTA_FREE:
+            return QuotaResult(granted_amount=args.quota_amount)
+        if route is None:
+            return None
+        return self.quota_build(bag, check_result, *route)
 
+    def quota_route(self, quota_name: str, check_result):
+        """The half of quota_target a check result decides, the same
+        for every row of a verdict class: (device pool, instance name),
+        QUOTA_FREE (granted freely: no quota action existed at check
+        time, or none active matches the name), or None (the
+        dispatcher.quota fallback: the generic path, a non-memquota
+        handler)."""
         if check_result.active_quota_rules is None:
             return None
         # rule indices are positional within the snapshot that served
@@ -1296,12 +1309,11 @@ class RuntimeServer:
         if d is None:
             # no quota actions existed at check time: grant freely
             # (dispatcher.quota tail — the reference returns empty)
-            return QuotaResult(granted_amount=args.quota_amount)
+            return QUOTA_FREE
         plan = d.fused
         if plan is None:
             return None
         active = set(check_result.active_quota_rules)
-        snap = d.snapshot
         for ridx, handler_q, inst_q, names in plan.quota_actions:
             if ridx not in active or quota_name not in names:
                 continue
@@ -1311,20 +1323,32 @@ class RuntimeServer:
             # instance["name"] — see tests/test_runtime.py convention)
             if pool is None or not pool.knows(inst_q):
                 return None   # non-memquota quota handler → host path
-            try:
-                instance = snap.instances[inst_q].build(bag)
-            except EvalError as exc:   # dispatcher.quota parity
-                return QuotaResult(granted_amount=0,
-                                   status_code=INTERNAL,
-                                   status_message=str(exc))
-            except Exception as exc:   # safeDispatch parity
-                return QuotaResult(granted_amount=0,
-                                   status_code=INTERNAL,
-                                   status_message=f"instance build: "
-                                                  f"{exc}")
-            return pool, inst_q, instance
+            return pool, inst_q
         # no matching active quota rule: grant freely
-        return QuotaResult(granted_amount=args.quota_amount)
+        return QUOTA_FREE
+
+    @staticmethod
+    def quota_build(bag: Bag, check_result, pool, inst_q: str):
+        """The instance `inst_q` of a routed quota (quota_route) built
+        on `bag` by the snapshot that served the check: (pool, inst_q,
+        instance), or the INTERNAL QuotaResult of a build that
+        raised."""
+        from istio_tpu.expr.oracle import EvalError
+        from istio_tpu.models.policy_engine import INTERNAL
+
+        snap = check_result.quota_context.snapshot
+        try:
+            instance = snap.instances[inst_q].build(bag)
+        except EvalError as exc:   # dispatcher.quota parity
+            return QuotaResult(granted_amount=0,
+                               status_code=INTERNAL,
+                               status_message=str(exc))
+        except Exception as exc:   # safeDispatch parity
+            return QuotaResult(granted_amount=0,
+                               status_code=INTERNAL,
+                               status_message=f"instance build: "
+                                              f"{exc}")
+        return pool, inst_q, instance
 
     # -- in-step quota (gated: ServerArgs.quota_in_step) ---------------
 

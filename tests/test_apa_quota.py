@@ -229,6 +229,23 @@ def test_grants_through_the_native_front_equal_the_plain_reference(rig,
     assert after["host"] == before["host"]
 
 
+def test_a_key_the_apa_binds_is_built_a_row(rig):
+    """The quota reads what the step's APA binds, so the batch's ids do
+    not hold its key: the plan keys no instance by them, and every OK
+    quota row is built on its bound bag (`path=row`), its grant the
+    plain reference's."""
+    assert rig.front.srv.controller.dispatcher.fused.quota_keys is None
+    requests = rig.requests[2 * BUCKET:3 * BUCKET]
+    before = monitor.quota_key_counters()
+    got = rig.front.ask(requests, "keys")
+    want = [rig.reference.grant(r, QUOTA, 1, True, f"keys-{i}")
+            for i, r in enumerate(requests)]
+    assert got == want
+    after = monitor.quota_key_counters()
+    assert {path: after[path] - before[path] for path in after} == \
+        {"memo": 0, "built": 0, "row": sum(g is not None for g in want)}
+
+
 def test_the_bound_bag_is_the_host_apas_on_every_row(rig):
     """The bag bind_rows makes from the step's pod rows against
     Dispatcher._generate's (the adapter asked, the outputs bound), on
@@ -323,5 +340,8 @@ def test_a_quota_that_reads_no_binding_keeps_its_wire_bag():
             assert quotas["rq"].granted_amount == want
         assert monitor.apa_counters()["rows"] == dict(
             before, step=monitor.apa_counters()["rows"]["step"])
+        # the step's APA binds no column the key reads: the plan keys
+        # rq by the batch's ids
+        assert set(d.fused.quota_keys.at) == {"rq.istio-system"}
     finally:
         front.close()

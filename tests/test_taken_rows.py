@@ -14,6 +14,7 @@ _run_batch, the C++ library's h2srv_complete replaced by a parser of
 the completions.
 """
 import ctypes
+import dataclasses
 import ipaddress
 import struct
 import types
@@ -179,45 +180,63 @@ def test_an_exemplar_asks_for_the_rows_it_keeps():
 
 
 def test_a_quota_row_is_made_alone_and_outlives_the_buffer(mesh):
-    """Rows that ask for a quota and are OK take the quota path with
-    their bag; the bag owns its bytes: it decodes after the pump's
-    buffer is overwritten."""
+    """Rows that ask for a quota and are OK take the quota path, and
+    one is made into a bag only where something reads it: not for a
+    quota granted with no instance built (the store has no rule for
+    `q`) nor for a response whose bytes are its verdict class's; alone
+    where its response's bytes read it (presence incomplete). That bag
+    owns its bytes: it decodes after the pump's buffer is
+    overwritten."""
     requests = _mesh_requests(ROWS, 12)
     asking = {row: {"q": (1, True)} for row in range(0, ROWS, 7)}
-    items = _items(requests, asking, first_tag=7000)
-    blob = encode_take(items)
-    buf = ctypes.create_string_buffer(len(blob))
-    ctypes.memmove(buf, blob, len(blob))
+    want = mesh.reference(requests)
+    ok_asking = [row for row in asking if want[row] == 0]
+    assert 0 < len(ok_asking) < len(asking)
     held = {}
     answer = mesh.native._answer_quota_rows
+    chunked = mesh.native._check_bags_chunked
 
     def spy(group, rows, completions):
         held.update((group.tags[i], group.bags[i]) for i in rows)
         answer(group, rows, completions)
 
+    def incomplete(bags, deadline=None):
+        """The OK quota rows' results with their presence unknown."""
+        results = list(chunked(bags, deadline=deadline))
+        for row in ok_asking:
+            results[row] = dataclasses.replace(results[row],
+                                               referenced_presence=None)
+        return results
+
     patch = pytest.MonkeyPatch()
     try:
         patch.setattr(mesh.native, "_answer_quota_rows", spy)
-        made = mesh.pump(buf)
+        made = {}
+        for first, check in ((7000, None), (7500, incomplete)):
+            if check is not None:
+                patch.setattr(mesh.native, "_check_bags_chunked", check)
+            blob = encode_take(_items(requests, asking, first_tag=first))
+            buf = ctypes.create_string_buffer(len(blob))
+            ctypes.memmove(buf, blob, len(blob))
+            made[first] = mesh.pump(buf)
+            # every row was answered: the rest by their verdict, the
+            # quota rows by the quota path (no rule for `q`: granted
+            # freely, in the bytes, with no trip)
+            assert mesh.statuses(first + row for row in range(ROWS)) \
+                == want
+            for row in ok_asking:
+                _, raw = mesh.answers[first + row]
+                assert pb.CheckResponse.FromString(raw).quotas["q"] \
+                    .granted_amount == 1
     finally:
         patch.undo()
-    want = mesh.reference(requests)
-    ok_asking = [row for row in asking if want[row] == 0]
-    assert 0 < len(ok_asking) < len(asking)
-    assert made == len(ok_asking)
-    assert sorted(held) == [7000 + row for row in ok_asking]
-    # every row was answered: the rest by their verdict, the quota
-    # rows by the quota path (the store has no rule for `q`, so the
-    # ask is granted freely, in the bytes, with no trip)
-    assert mesh.statuses(7000 + row for row in range(ROWS)) == want
-    for row in ok_asking:
-        _, raw = mesh.answers[7000 + row]
-        assert pb.CheckResponse.FromString(raw).quotas["q"] \
-            .granted_amount == 1
+    assert made == {7000: 0, 7500: len(ok_asking)}
+    assert sorted(tag for tag, bag in held.items() if bag is not None) \
+        == [7500 + row for row in ok_asking]
     # the next take lands in the same buffer
     ctypes.memset(buf, 0xFF, len(buf))
     for row in ok_asking:
-        bag = held[7000 + row]
+        bag = held[7500 + row]
         assert {name: bag.get(name)[0] for name in bag.names()} == \
             compressed_to_dict(
                 pb.CompressedAttributes.FromString(_wire(requests[row])))
