@@ -976,15 +976,19 @@ class NativeMixerServer(MixerGrpcServer):
                             rows: list[int], completions: _Completions,
                             deferred: set) -> None:
         """The batch's OK quota rows through the pools as one group:
-        each quota's target found a row at a time, then one alloc_many
-        a pool for every ask bound there. Where the check result sends
-        a quota (runtime.quota_route) is read once a verdict class and
-        quota name. A pool ask's key comes from the plan's memo over the
-        batch's ids where the plan keys its instance so
-        (`results.quota_keys`, runtime/quota_keys.py), else from the
-        instance built on the row's bag (runtime.quota_build), or on the
-        bag runtime.quota_bags binds where a quota instance reads what
-        the step's APA binds. A row's bag is made only where something
+        each quota routed a row at a time, the group's pool asks keyed,
+        then one alloc_many a pool for every ask bound there. Where the
+        check result sends a quota (runtime.quota_route) is read once a
+        verdict class and quota name. A pool ask's key comes from the
+        plan's memo over the ids the batch holds where the plan keys its
+        instance so (`results.quota_keys`, runtime/quota_keys.py), else
+        from the instance built on the row's bag (runtime.quota_build).
+        Where a quota instance reads what the step's APA binds, that bag
+        is the one runtime.quota_bags binds: for a row alone where its
+        key misses the memo, then in one call for the rows whose asks
+        take the per-row path or the generic one. Span `quota.bind`,
+        once a batch that carries its pod rows, covers the keying and
+        those binds. A row's bag is made only where something
         reads it: such a build, the generic path, a response whose bytes
         depend on it. A quota resolved without a pool (rule inactive,
         an instance error, a non-memquota handler) is in the group as
@@ -997,14 +1001,17 @@ class NativeMixerServer(MixerGrpcServer):
         early: list = []
         tags = checks.tags
         runtime = self.runtime
-        bound = runtime.quota_bags(bags, results, rows)
-        keys = getattr(results, "quota_keys", None) \
-            if bound is None else None
+        keys = getattr(results, "quota_keys", None)
         routes: dict = {}     # (id(result), quota name) → its route
-        alone: dict = {}      # id(result) → its bytes read the bag
-        paths = {"memo": 0, "built": 0, "row": 0}
-        for k, row in enumerate(rows):
-            tag, result = int(tags[row]), results[row]
+        # (row, [[quota name, args, route, target or None]]) a row whose
+        # quotas all routed; the target a QuotaResult, a memquota key, a
+        # built (pool, instance name, instance)
+        asked: list = []
+        # instance → ([row, ...], [target, ...]) of its pool asks, where
+        # the plan keys instances
+        wanted: dict = {}
+        for row in rows:
+            result = results[row]
             dedup = checks.dedup_id(row)
             targets = []
             try:
@@ -1016,24 +1023,68 @@ class NativeMixerServer(MixerGrpcServer):
                     if route is _UNROUTED:
                         route = routes[id(result), name] = \
                             runtime.quota_route(name, result)
+                    t = [name, args, route, None]
                     if route is QUOTA_FREE:
-                        target = QuotaResult(granted_amount=amount)
-                    elif route is None:   # generic path / host handler
-                        target = runtime.quota(
-                            bags[row] if bound is None else bound[k],
-                            name, args, preprocessed=True)
-                    else:
-                        got = None if keys is None \
-                            else keys.key(route[1], row, bags)
+                        t[3] = QuotaResult(granted_amount=amount)
+                    elif route is not None and keys is not None:
+                        got = wanted.get(route[1])
                         if got is None:
-                            paths["row"] += 1
-                            target = runtime.quota_build(
-                                bags[row] if bound is None else bound[k],
-                                result, *route)
-                        else:
-                            paths[got[0]] += 1
-                            target = (*route, got[1])
-                    targets.append((name, args, target))
+                            got = wanted[route[1]] = ([], [])
+                        got[0].append(row)
+                        got[1].append(t)
+                    targets.append(t)
+            except Exception as exc:   # row-isolated
+                monitor.DISPATCH_ERRORS.inc()
+                completions.append(
+                    (int(tags[row]), 13, f"quota submit: {exc}".encode()))
+                continue
+            asked.append((row, targets))
+        paths = {"memo": 0, "built": 0, "row": 0}
+        bound: dict = {}      # row → the bag its quota instances read
+
+        def bag_of(row: int):
+            """The bag a miss of row `row` builds on, bound alone."""
+            bag = bound.get(row)
+            if bag is None:
+                got = runtime.quota_bags(bags, results, [row])
+                bag = bound[row] = bags[row] if got is None else got[0]
+            return bag
+
+        pods = getattr(results, "apa_pods", None)
+        with monitor.span("quota.bind", on=pods is not None):
+            for iname, (mine, ts) in wanted.items():
+                found, built = keys.keys(iname, mine, bag_of)
+                for t, key in zip(ts, found):
+                    t[3] = key
+                paths["memo"] += len(found) - found.count(None) - built
+                paths["built"] += built
+            # the rows a build or the generic path still reads, bound in
+            # one call
+            late = list(dict.fromkeys([
+                row for row, targets in asked for t in targets
+                if t[3] is None and row not in bound]))
+            got = runtime.quota_bags(bags, results, late) if late else None
+            if got is not None:
+                bound.update(zip(late, got))
+        if pods is not None:
+            # the rows keyed from the pod rows with no bind (quota_bags
+            # counts the ones it bound)
+            monitor.note_apa_rows("quota", len(rows) - len(bound))
+        alone: dict = {}      # id(result) → its bytes read the bag
+        for row, targets in asked:
+            tag, result = int(tags[row]), results[row]
+            try:
+                for t in targets:
+                    name, args, route, target = t
+                    if target is not None:
+                        continue
+                    bag = bound[row] if row in bound else bags[row]
+                    if route is None:     # generic path / host handler
+                        t[3] = runtime.quota(bag, name, args,
+                                             preprocessed=True)
+                    else:
+                        paths["row"] += 1
+                        t[3] = runtime.quota_build(bag, result, *route)
             except Exception as exc:   # row-isolated
                 monitor.DISPATCH_ERRORS.inc()
                 completions.append(
@@ -1047,8 +1098,11 @@ class NativeMixerServer(MixerGrpcServer):
                 needs = alone[id(result)] = self._verdict_key(result) is None
             at = group.add(tag, bags[row] if needs else None, result,
                            [t[0] for t in targets])
-            for q, (_, args, target) in enumerate(targets):
-                if isinstance(target, tuple):
+            for q, (_, args, route, target) in enumerate(targets):
+                if type(target) is str:         # a memquota key
+                    asks.setdefault(route[0], []).append(
+                        ((at, q), route[1], target, args))
+                elif isinstance(target, tuple):
                     pool, inst_q, instance = target
                     asks.setdefault(pool, []).append(
                         ((at, q), inst_q, instance, args))

@@ -33,10 +33,11 @@ Two homes, one semantics:
     taken by uid and then by IP, the columns gathered, and the bound
     slots' `ids` / `present` written, each binding's default taken
     where its output is missing. Nothing else in the step changes.
-    A quota instance that reads a binding is built on the host, on a
-    bag with EVERY binding applied by `bind` from the pods the stamp
-    found for its row (`ApaStep.bind_rows`, `PodRows`): no adapter
-    call and no second lookup.
+    A quota instance that reads a binding is keyed by the pod rows the
+    stamp found for its row (runtime/quota_keys.py), and built on the
+    host, once a pod pair a plan, on a bag with EVERY binding applied
+    by `bind` from those pods (`ApaStep.bind_rows`, `PodRows`): no
+    adapter call and no second lookup.
 
 A binding whose `$out` output is missing and that has no default
 leaves the attribute as the request sent it (the host path has always
@@ -383,8 +384,8 @@ class ApaStep:
 @dataclasses.dataclass(frozen=True)
 class PodRows:
     """A served batch's pod rows (ApaStep.host_rows on its stamped
-    planes), carried with its responses to the quota rows that build
-    their instances on what the APA binds."""
+    planes), carried with its responses to the quota rows that key and
+    build their instances on what the APA binds."""
     apa: ApaStep
     rows: np.ndarray      # [B, len(STEP_PREFIXES)]
     ok: np.ndarray        # [B]

@@ -777,7 +777,8 @@ class Dispatcher:
         if plan.quota_keys is not None and \
                 getattr(bags, "asks_quota", None) is not None and \
                 bags.asks_quota() and bags.wire_spans() is not None:
-            out.quota_keys = plan.quota_keys.columns(batch, n_real)
+            out.quota_keys = plan.quota_keys.columns(batch, n_real,
+                                                     out.apa_pods)
         return out
 
     def _fold_respond(self, snap, plan, packed: np.ndarray, batch,

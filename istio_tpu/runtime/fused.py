@@ -206,8 +206,9 @@ class FusedPlan:
     # toolchain is unavailable — python Tensorizer serves instead
     native: Any = None
     # the quota instances whose memquota key the batch's interned ids
-    # decide, and the plan's memo of their keys (runtime/quota_keys.py);
-    # None where none is, or without the C++ decoder
+    # or the stamp's pod rows decide, and the plan's memo of their keys
+    # (runtime/quota_keys.py); None where none is, or without the C++
+    # decoder
     quota_keys: Any = None
     # the APA the Check step holds (runtime/apa.ApaStep): its table
     # rides in engine.params, `_base_step` applies it before `match`
@@ -1374,7 +1375,8 @@ def _build_apa(snapshot: Snapshot, lists, host_actions: dict,
         if ridx < rs.n_rules:
             host_attrs |= rs.attr_names[ridx]
     # a quota instance reads a bag bound for it on the host (the quota
-    # loop's ApaStep.bind_rows): what it reads leaves the APA in the step
+    # loop's ApaStep.bind_rows, or its key from the stamp's pod rows):
+    # what it reads leaves the APA in the step
     quota_attrs: set = set()
     for _ridx, _hq, iname, _names in quota_actions:
         quota_attrs |= snapshot.instances[iname].referenced_attrs

@@ -311,8 +311,9 @@ def length_split_counters() -> dict:
 # -- the attribute-generation phase (runtime/apa.py): Check rows whose
 # APA ran in the step (`step`, counted a served batch) or on the host
 # (`host`: Dispatcher.preprocess_batch, a bag a row), OK quota rows
-# whose instance was built on a bag bound from the step's pod rows
-# (`quota`: RuntimeServer.quota_bags), and the device table of the APA
+# of a batch that carries the step's pod rows, keyed from them or built
+# on a bag bound from them (`quota`: the native front's quota group and
+# RuntimeServer.quota_bags), and the device table of the APA
 # the step holds, set at plan build (0 for a plan whose APA is the
 # host's or that has none).
 APA_ROW_PATHS = ("step", "host", "quota")
@@ -380,9 +381,10 @@ def note_quota_rows_answered(classed: int, row: int) -> None:
 
 # the native front's asks of a device pool by where their memquota key
 # came from (runtime/quota_keys.py): `memo`, the plan's memo over the
-# batch's interned ids; `built`, a miss there, the instance built on the
-# row's bag and its key stored; `row`, the per-row build (a plan, an
-# instance or a row whose ids do not decide the key, or a build error)
+# batch's interned ids and the stamp's pod rows; `built`, a miss there,
+# the instance built on the row's bag and its key stored; `row`, the
+# per-row build (a plan, an instance or a row whose ids do not decide
+# the key, or a build error)
 QUOTA_KEY_PATHS = ("memo", "built", "row")
 QUOTA_KEYS = prometheus_client.Counter(
     "mixer_quota_keys_total",

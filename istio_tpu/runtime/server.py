@@ -1264,16 +1264,16 @@ class RuntimeServer:
         """The bags the quota instances of a served batch's `rows` are
         built on, where the step holds the APA and a quota instance
         reads what it binds: each row's bag with every binding applied
-        from the pods the batch's stamp found (`results.apa_pods`, span
-        `quota.bind`, counted as mixer_apa_rows_total{path=quota}), or
-        by the host APA for a batch served without them (in chunks, or
-        by the host oracle). None: the rows' own bags."""
+        from the pods the batch's stamp found (`results.apa_pods`,
+        counted as mixer_apa_rows_total{path=quota}; the native front
+        calls it inside span `quota.bind`), or by the host APA for a
+        batch served without them (in chunks, or by the host oracle).
+        None: the rows' own bags."""
         from istio_tpu.runtime import monitor as _monitor
 
         pods = getattr(results, "apa_pods", None)
         if pods is not None:
-            with _monitor.span("quota.bind"):
-                out = pods.bind(bags, rows)
+            out = pods.bind(bags, rows)
             _monitor.note_apa_rows("quota", len(out))
             return out
         d = self._binds_for_quota()

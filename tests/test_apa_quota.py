@@ -13,6 +13,7 @@ import importlib.util
 import json
 import struct
 import threading
+import time
 import types
 from pathlib import Path
 
@@ -230,11 +231,15 @@ def test_grants_through_the_native_front_equal_the_plain_reference(rig,
 
 
 def test_a_key_the_apa_binds_is_built_a_row(rig):
-    """The quota reads what the step's APA binds, so the batch's ids do
-    not hold its key: the plan keys no instance by them, and every OK
-    quota row is built on its bound bag (`path=row`), its grant the
-    plain reference's."""
-    assert rig.front.srv.controller.dispatcher.fused.quota_keys is None
+    """The quota reads what the step's APA binds: the plan keys it by
+    the pod rows the stamp found (source and destination) and no
+    column, and every OK quota row takes its key from the memo or
+    builds it once (`path=memo|built`, none `row`), its grant the plain
+    reference's."""
+    keys = rig.front.srv.controller.dispatcher.fused.quota_keys
+    assert set(keys.at) == {f"{QUOTA}.istio-system"}
+    assert keys.at[f"{QUOTA}.istio-system"] == ([], 0)
+    assert keys.sides[f"{QUOTA}.istio-system"] == [0, 1]
     requests = rig.requests[2 * BUCKET:3 * BUCKET]
     before = monitor.quota_key_counters()
     got = rig.front.ask(requests, "keys")
@@ -242,8 +247,234 @@ def test_a_key_the_apa_binds_is_built_a_row(rig):
             for i, r in enumerate(requests)]
     assert got == want
     after = monitor.quota_key_counters()
-    assert {path: after[path] - before[path] for path in after} == \
-        {"memo": 0, "built": 0, "row": sum(g is not None for g in want)}
+    moved = {path: after[path] - before[path] for path in after}
+    assert moved["row"] == 0 and moved["built"] > 0
+    assert moved["memo"] + moved["built"] == sum(g is not None
+                                                 for g in want)
+
+
+@pytest.fixture
+def handed(monkeypatch):
+    """{tag: the key the front handed a pool} for every ask of a native
+    quota group."""
+    from istio_tpu.adapters.memquota import _key as dims_key
+    from istio_tpu.api.native_server import _QuotaGroup
+    from istio_tpu.runtime.device_quota import DeviceQuotaPool
+
+    got: dict[int, str] = {}
+    alloc_many = DeviceQuotaPool.alloc_many
+
+    def spy(pool, items, sink):
+        if isinstance(sink, _QuotaGroup):
+            for (at, _q), _name, instance, _args in items:
+                got[sink.tags[at]] = instance if isinstance(
+                    instance, str) else dims_key(instance)
+        return alloc_many(pool, items, sink)
+
+    monkeypatch.setattr(DeviceQuotaPool, "alloc_many", spy)
+    return got
+
+
+def _host_keys(front: Front, requests: list[dict]) -> list[str]:
+    """Each request's quota key as the host builds it: the instance on
+    the bag the host APA binds (Dispatcher._with_apa)."""
+    from istio_tpu.adapters.memquota import _key as dims_key
+
+    d = front.srv.controller.dispatcher
+    instance = d.snapshot.instances[f"{QUOTA}.istio-system"]
+    return [dims_key(instance.build(bag)) for bag in
+            d._with_apa([LazyWireBag(_wire(r)) for r in requests])]
+
+
+def _granted(rig, requests: list[dict], dedup: str) -> list:
+    """One batch through the rig's front, each grant the plain
+    reference's (which counts it too, so later cases stay exact)."""
+    got = rig.front.ask(requests, dedup)
+    assert got == [rig.reference.grant(r, QUOTA, 1, True, f"{dedup}-{i}")
+                   for i, r in enumerate(requests)]
+    return got
+
+
+def _asked(front: Front, requests: list[dict], dedup: str,
+           handed: dict, rig=None) -> tuple[dict, list]:
+    """One batch through the front (the rig's, its grants checked,
+    where `rig` is given) → (the keys counters' delta, the key handed a
+    pool a row: None where the row asked no pool)."""
+    start = next(front._tags)
+    before = monitor.quota_key_counters()
+    if rig is None:
+        front.ask(requests, dedup)
+    else:
+        _granted(rig, requests, dedup)
+    after = monitor.quota_key_counters()
+    tags = range(start + 1, start + 1 + len(requests))
+    return ({path: after[path] - before[path] for path in after},
+            [handed.get(tag) for tag in tags])
+
+
+@pytest.mark.parametrize("kind", [
+    "uid_and_ip", "ip_only", "gone", "no_destination_pod"])
+def test_the_memo_key_is_the_bound_bags_key_on_every_row(rig, handed,
+                                                         kind):
+    """Every row's key, built on a miss and found on the second pass,
+    equals the instance built on the bag the host APA binds for it."""
+    if kind == "no_destination_pod":
+        requests = [dict(r, **{"destination.uid":
+                               f"{RATELIMIT.base.UID_PREFIX}nod-{i}.ns2"})
+                    for i, r in enumerate(rig.requests[BUCKET:2 * BUCKET])]
+    else:
+        requests = [r for r in rig.requests[BUCKET:]
+                    if _source_kind(r, rig.pods) == kind][:BUCKET]
+    want = _host_keys(rig.front, requests)
+    ok = rig.reference.expected_status
+    for n, dedup in enumerate(("memo-a", "memo-b")):
+        moved, got = _asked(rig.front, requests, f"{dedup}-{kind}",
+                            handed, rig)
+        for row, r in enumerate(requests):
+            assert got[row] == (want[row] if ok(r) == 0 else None), row
+        asks = sum(ok(r) == 0 for r in requests)
+        assert moved["row"] == 0 and \
+            moved["memo"] + moved["built"] == asks
+        if n:
+            assert moved == {"memo": asks, "built": 0, "row": 0}
+
+
+def _front_with(**params) -> Front:
+    """A front on the rig's store, the APA instance's params updated."""
+    store = _limited(RATELIMIT.make_store(SIZES))
+    key = ("instance", "istio-system", "attributes")
+    inst = store.get(key)
+    store.set(key, dict(inst, params=dict(inst["params"], **params)))
+    return Front(_server(store))
+
+
+def test_a_default_that_reads_the_request_takes_the_row_path(handed):
+    """destination.labels (no rule reads it) defaulted to the request's
+    headers (a default that evaluates on an empty bag too): what the
+    APA binds there is no function of the pod rows, so the plan keys no
+    instance and every ask takes the per-row path, its key the host
+    APA's."""
+    bindings = dict(RATELIMIT.base.BINDINGS, **{
+        "destination.labels": "$out.destination_labels | "
+                              "request.headers | emptyStringMap()"})
+    front = _front_with(attribute_bindings=bindings)
+    try:
+        plan = front.srv.controller.dispatcher.fused
+        assert plan.apa is not None and plan.apa.quota_bindings
+        assert plan.quota_keys is None
+        requests = RATELIMIT.make_requests(SIZES, BUCKET, 4200000044)
+        moved, got = _asked(front, requests, "dflt", handed)
+        asks = [k for k in got if k is not None]
+        assert moved == {"memo": 0, "built": 0, "row": len(asks)} and asks
+        assert got == [k if g is not None else None for k, g in
+                       zip(_host_keys(front, requests), got)]
+    finally:
+        front.close()
+
+
+def test_a_row_whose_pods_bind_nothing_takes_the_row_path(handed):
+    """source.uid without a default: a request that sends none builds
+    no APA instance (PodRows.ok false), so nothing is bound and its
+    row takes the per-row path; the others are keyed by their pods."""
+    front = _front_with(source_uid="source.uid")
+    try:
+        apa = front.srv.controller.dispatcher.fused.apa
+        assert apa.ok_cols
+        requests = RATELIMIT.make_requests(SIZES, 4 * BUCKET, 4200000045)
+        requests = [r for r in requests if "source.uid" not in r][:16] + \
+            [r for r in requests if "source.uid" in r][:BUCKET - 16]
+        moved, got = _asked(front, requests, "nouid", handed)
+        no_uid = sum(k is not None for k in got[:16])
+        assert no_uid and moved["row"] == no_uid
+        assert moved["memo"] + moved["built"] == \
+            sum(k is not None for k in got[16:])
+        assert [k for k in got if k is not None] == [
+            k for k, g in zip(_host_keys(front, requests), got)
+            if g is not None]
+    finally:
+        front.close()
+
+
+def test_a_batch_without_pod_rows_binds_on_the_host(rig, monkeypatch):
+    """A batch served without its pod rows (in chunks, or by the host
+    oracle: a plain list of responses) binds every OK quota row by the
+    host APA, as before, its grants the plain reference's."""
+    native = rig.front.native
+    chunked = native._check_bags_chunked
+    monkeypatch.setattr(native, "_check_bags_chunked",
+                        lambda bags, deadline=None: list(
+                            chunked(bags, deadline=deadline)))
+    requests = rig.requests[3 * BUCKET:4 * BUCKET]
+    before = monitor.apa_counters()["rows"]
+    keys = monitor.quota_key_counters()
+    asks = sum(g is not None for g in _granted(rig, requests, "plain"))
+    after = monitor.apa_counters()["rows"]
+    assert (after["host"] - before["host"],
+            after["quota"] - before["quota"]) == (asks, 0)
+    assert monitor.quota_key_counters()["row"] - keys["row"] == asks
+
+
+def test_a_memo_row_makes_no_bag(rig, monkeypatch):
+    """Once a batch's keys are in the memo, its OK quota rows are
+    answered with no bag made, none bound, and span `quota.bind`
+    entered once for the batch; every row counted on the quota path."""
+    from istio_tpu.runtime.apa import ApaStep
+
+    requests = rig.requests[4 * BUCKET:5 * BUCKET]
+    _granted(rig, requests, "nobag-warm")
+    bound = []
+    bind_rows = ApaStep.bind_rows
+    monkeypatch.setattr(ApaStep, "bind_rows", lambda self, bags, which,
+                        *a: bound.extend(which) or bind_rows(
+                            self, bags, which, *a))
+    bags = monitor.front_bag_counters()["materialised"]
+    rows = monitor.apa_counters()["rows"]["quota"]
+    token = monitor.stage_baseline()
+    before = monitor.quota_key_counters()
+    asks = sum(g is not None for g in _granted(rig, requests, "nobag"))
+    assert monitor.quota_key_counters()["memo"] - before["memo"] == asks
+    assert monitor.front_bag_counters()["materialised"] == bags
+    assert bound == []
+    assert monitor.apa_counters()["rows"]["quota"] - rows == asks
+    spans = monitor.latency_snapshot(since=token)["spans"]
+    assert spans["quota.bind"]["count"] == 1
+
+
+def test_a_publish_keys_by_the_new_plans_pods(handed):
+    """A publish that relabels every pod's version: the new plan's memo
+    starts empty and keys by the new pod table, never by the old
+    plan's memo."""
+    from istio_tpu.runtime.store import Event
+
+    front = Front(_server(_limited(RATELIMIT.make_store(SIZES))))
+    try:
+        requests = RATELIMIT.make_requests(SIZES, BUCKET, 4200000046)
+        _asked(front, requests, "pub-a", handed)
+        old = front.srv.controller.dispatcher.fused.quota_keys
+        assert old.memo and not any("v9" in k for k in old.memo.values())
+        pods = {uid: dict(pod, labels=dict(pod["labels"], version="v9"))
+                for uid, pod in RATELIMIT.base.make_pods(SIZES).items()}
+        front.srv.controller.store.apply_events([Event(
+            ("handler", "istio-system", "kubernetesenv"),
+            {"adapter": "kubernetesenv", "params": {"pods": pods}})],
+            notify=False)
+        plan = front.srv.controller.rebuild().fused
+        deadline = time.monotonic() + 120
+        while plan._warm_pending:
+            assert time.monotonic() < deadline, "the new plan never warmed"
+            time.sleep(0.05)
+        new = plan.quota_keys
+        assert new is not old and not new.memo
+        moved, got = _asked(front, requests, "pub-b", handed)
+        assert moved["row"] == 0 and moved["built"] > 0
+        asks = [k for k in got if k is not None]
+        assert asks == [k for k, g in zip(_host_keys(front, requests), got)
+                        if g is not None]
+        assert any("v9" in k for k in asks)
+        assert all("v9" in k for k in new.memo.values()
+                   if "unknown" not in k)
+    finally:
+        front.close()
 
 
 def test_the_bound_bag_is_the_host_apas_on_every_row(rig):
